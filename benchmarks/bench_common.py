@@ -6,7 +6,9 @@ bench_stream used to carry three identical private copies).  Sections merge
 into the existing file so e.g. ``--fused`` and ``--sharded`` runs
 accumulate instead of clobbering each other's history, and every write
 stamps a uniform ``meta`` block (git revision, jax version, device kind)
-so a stored number is traceable to the build that produced it.
+so a stored number is traceable to the build that produced it.  A backend
+that cannot be queried is an error: no number is stored without the device
+it ran on.
 """
 
 from __future__ import annotations
@@ -35,17 +37,11 @@ def _git_rev() -> str:
 
 def bench_metadata() -> dict:
     """Uniform provenance block stamped into every BENCH_*.json write."""
-    meta = {"git_rev": _git_rev()}
-    try:
-        import jax
-        meta["jax_version"] = jax.__version__
-        meta["device_kind"] = jax.devices()[0].device_kind
-        meta["device_count"] = jax.device_count()
-    except Exception:                      # jax absent or no backend
-        meta["jax_version"] = "unavailable"
-        meta["device_kind"] = "unknown"
-        meta["device_count"] = 0
-    return meta
+    import jax
+    dev = jax.devices()[0]
+    return {"git_rev": _git_rev(), "jax_version": jax.__version__,
+            "platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
 
 
 def emit_bench_json(payload: dict, path: str) -> str:

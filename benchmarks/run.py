@@ -25,8 +25,12 @@
   bench_packing  — FFD bins applied to the data pipeline
   bench_kernels  — Pallas kernels vs oracles
 
-Prints ``name,us_per_call,derived`` CSV lines plus detailed tables; the
-roofline table lives in benchmarks/roofline_report.py (reads dry-run JSON).
+Each section runs in a child process of its own, one after another; this
+parent never imports JAX, so every child in turn has the accelerator to
+itself (a process that has touched JAX holds the chip).  The sharded and
+coded sections ask for 8 virtual devices, which only the CPU platform
+honours.  Prints a ``name,seconds,rc`` CSV; the roofline table lives in
+benchmarks/roofline_report.py (reads dry-run JSON).
 """
 
 from __future__ import annotations
@@ -36,63 +40,45 @@ import subprocess
 import sys
 import time
 
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_EIGHT = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
 
-def _bench_8dev(script_name: str, *args: str):
-    """Run a bench script in a SUBPROCESS with a forced 8-device CPU
-    mesh: XLA_FLAGS cannot change the device count of this already-
-    initialized process, and a 1-device in-process run would overwrite the
-    committed multi-device sections of the BENCH json with trivial
-    numbers."""
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          script_name)
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
-               PYTHONPATH="src" + (
-                   os.pathsep + os.environ["PYTHONPATH"]
-                   if os.environ.get("PYTHONPATH") else ""))
-    res = subprocess.run([sys.executable, script, *args], env=env)
-    if res.returncode != 0:
-        raise SystemExit(
-            f"{script_name} {' '.join(args)} failed ({res.returncode})")
-    return [res]
-
-
-def _bench_engine_sharded():
-    return _bench_8dev("bench_engine.py", "--sharded")
-
-
-def _bench_coded():
-    return _bench_8dev("bench_coded.py")
+SECTIONS = [
+    ("bench_a2a", "bench_a2a.py", (), {}),
+    ("bench_x2y", "bench_x2y.py", (), {}),
+    ("bench_engine", "bench_engine.py", (), {}),
+    ("bench_engine_fused", "bench_engine.py", ("--fused",), {}),
+    ("bench_engine_sharded", "bench_engine.py", ("--sharded",), _EIGHT),
+    ("bench_coded", "bench_coded.py", (), _EIGHT),
+    ("bench_stream", "bench_stream.py", (), {}),
+    ("bench_obs", "bench_obs.py", (), {}),
+    ("bench_packing", "bench_packing.py", (), {}),
+    ("bench_kernels", "bench_kernels.py", (), {}),
+]
 
 
 def main() -> None:
-    from benchmarks import bench_a2a, bench_engine, bench_kernels, \
-        bench_obs, bench_packing, bench_stream, bench_x2y
-
-    sections = [
-        ("bench_a2a", bench_a2a.main),
-        ("bench_x2y", bench_x2y.main),
-        ("bench_engine", bench_engine.main),
-        ("bench_engine_fused", lambda: [bench_engine.main(["--fused"])]),
-        ("bench_engine_sharded", _bench_engine_sharded),
-        ("bench_coded", _bench_coded),
-        ("bench_stream", lambda: [bench_stream.main([])]),
-        ("bench_obs", lambda: [bench_obs.main([])]),
-        ("bench_packing", bench_packing.main),
-        ("bench_kernels", bench_kernels.main),
-    ]
+    sys.path.insert(0, os.path.join(_HERE, os.pardir, "src"))
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()                  # children inherit the placement
+    src = os.path.join(_HERE, os.pardir, "src")
+    pythonpath = os.pathsep.join(
+        p for p in (src, _HERE, os.path.join(_HERE, os.pardir),
+                    os.environ.get("PYTHONPATH")) if p)
     csv = []
-    for name, fn in sections:
-        print(f"\n===== {name} " + "=" * (60 - len(name)))
+    for name, script, args, env in SECTIONS:
+        print(f"\n===== {name} " + "=" * (60 - len(name)), flush=True)
         t0 = time.perf_counter()
-        rows = fn()
-        dt = (time.perf_counter() - t0) * 1e6
-        derived = len(rows) if rows is not None else 0
-        csv.append(f"{name},{dt:.0f},{derived}")
-    print("\n# name,us_per_call,derived")
+        res = subprocess.run(
+            [sys.executable, os.path.join(_HERE, script), *args],
+            env=dict(os.environ, PYTHONPATH=pythonpath, **env))
+        csv.append(f"{name},{time.perf_counter() - t0:.1f},"
+                   f"{res.returncode}")
+    print("\n# name,seconds,rc")
     for line in csv:
         print(line)
+    if any(not line.endswith(",0") for line in csv):
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

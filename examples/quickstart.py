@@ -53,4 +53,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
 from repro.configs.base import ArchConfig
 from repro.data import PackedLMDataset
 from repro.launch.mesh import make_local_mesh
@@ -84,7 +83,7 @@ def main():
 
     it = iter(ds)
     t_last, losses = time.perf_counter(), []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start, args.steps):
             batch = {k: jnp.asarray(v) for k, v in next(it).items()
                      if k != "segments"}
@@ -106,4 +105,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -1,55 +1,25 @@
-"""JAX version-compat shims (single import site for API drift).
+"""Thin mesh and collective helpers shared by the executors and launchers.
 
-The repo targets the ``jax.sharding.AxisType`` / ``jax.set_mesh`` API
-surface of recent JAX, but must also run on older installs (the container
-pins 0.4.x, where neither exists).  Everything version-dependent goes
-through this module so call sites never probe ``hasattr`` themselves:
+  ``make_mesh(shape, axes)``   — ``jax.make_mesh`` with every axis typed
+                                 ``Auto`` (sharding left to the compiler).
+  ``all_to_all(x, axes)``      — tiled ``jax.lax.all_to_all`` over one
+                                 shard_map axis or a tuple of axes.
 
-  ``make_mesh(shape, axes)``   — ``jax.make_mesh`` with explicit Auto axis
-                                 types when the install supports them.
-  ``set_mesh(mesh)``           — context manager: ``jax.set_mesh`` /
-                                 ``jax.sharding.use_mesh`` / plain
-                                 ``with mesh:`` (oldest API), whichever
-                                 exists.
-  ``AXIS_TYPE_AUTO``           — ``jax.sharding.AxisType.Auto`` or ``None``
-                                 when the enum predates this install.
+Everything else comes straight from ``jax``: ``jax.set_mesh``,
+``jax.shard_map`` and ``jax.lax.pcast``.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["AXIS_TYPE_AUTO", "all_to_all", "make_mesh", "pcast", "set_mesh",
-           "shard_map"]
-
-AXIS_TYPE_AUTO = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
+__all__ = ["all_to_all", "make_mesh"]
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    if AXIS_TYPE_AUTO is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AXIS_TYPE_AUTO,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    # oldest API: Mesh is itself a context manager
-    return contextlib.nullcontext(mesh) if mesh is None else mesh
-
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def all_to_all(x, axis_name, *, split_axis: int = 0, concat_axis: int = 0):
@@ -62,11 +32,3 @@ def all_to_all(x, axis_name, *, split_axis: int = 0, concat_axis: int = 0):
                              and len(axis_name) == 1) else axis_name[0]
     return jax.lax.all_to_all(x, name, split_axis=split_axis,
                               concat_axis=concat_axis, tiled=True)
-
-
-def pcast(x, axis_name, *, to: str = "varying"):
-    """``jax.lax.pcast`` where it exists; identity on older JAX, whose
-    shard_map treats every value as device-varying already."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_name, to=to)
-    return x

@@ -7,23 +7,27 @@ all-pairs block.  For the A2A workload that doubles HBM traffic on the very
 quantity — communication cost — the mapping schema was optimized to
 minimize.  This kernel consumes the plan's index matrix directly:
 
-  * the per-reducer ``idx`` / ``mask`` rows are **scalar-prefetched**
-    (``pltpu.PrefetchScalarGridSpec``) into SMEM, so row ids are available
-    before the kernel body runs;
+  * each reducer's slot ids (the mask folded in as -1) arrive in SMEM as
+    one ``(1, Lp)`` block per grid step — SMEM holds 1 MiB, far less than
+    a real plan, so the plan is streamed rather than prefetched whole;
   * input-table rows are DMA'd straight from the replicated ``(m, d)``
     table (left in ``ANY``/HBM) into two VMEM tiles — the gather *is* the
     DMA, and the padded ``(R, L, d)`` tensor is never written to HBM;
+    masked slots issue no copy;
   * each reducer's ``(L, L)`` Gram block is accumulated tile-by-tile on the
-    MXU with fp32 accumulation; masked slots are zeroed at gather time, so
-    the flushed block is already masked (invalid pairs -> 0, matching
-    ``block_similarity``).
+    MXU with fp32 products and accumulation, and invalid pairs are zeroed
+    as the block leaves the kernel (matching ``block_similarity``).
 
-Grid layout: ``(R, n_t, n_t)`` with ``n_t = ceil(L / bl)`` row tiles.  The
-``i`` tile is gathered once per row of tiles (at ``j == 0``) and reused;
-the ``j`` tile is re-gathered per step — the flash-attention tradeoff:
+The one-row DMA needs 32-bit rows padded to whole 128-lane tiles: the
+wrapper widens packed tables (bf16) to f32 and zero-pads the feature axis.
+
+Grid layout: ``(R, n_tx, n_ty)`` row/column tiles of at most ``bl`` slots.
+The row tile is gathered once per row of tiles (at ``j == 0``) and reused;
+the column tile is re-gathered per step — the flash-attention tradeoff:
 ``n_t·L·d`` extra reads instead of an ``L·d`` HBM round trip, a win
 whenever the slot count fits a few tiles (every capacity bucket of the
-skew-aware plans; see ``fused_traffic_model``).
+skew-aware plans; see ``fused_traffic_model``).  The square kernel is the
+rectangular one with X == Y.
 
 ``fused_gather_gram_streamed`` is the jnp twin with the same tile dataflow
 (per-bucket tiles only, never the dense ``(R, L, d)`` buffer) — it is what
@@ -57,151 +61,90 @@ def _round_up(n: int, mult: int) -> int:
     return -(-max(n, 1) // mult) * mult
 
 
-def _fused_kernel(idx_ref, msk_ref, x_ref, o_ref, xi_ref, xj_ref, sem_ref,
-                  *, bl: int):
-    """One (reducer, i-tile, j-tile) grid step.
+_LANE = 128
+# fp32 products on the MXU: the default precision rounds f32 rows to bf16
+_FP32 = jax.lax.Precision.HIGHEST
 
-    idx_ref/msk_ref — scalar-prefetched (R, Lp) int32 in SMEM;
-    x_ref — the full input table, ANY/HBM (rows DMA'd on demand);
-    xi/xj — (bl, d) VMEM gather tiles; o_ref — (1, bl, bl) output tile.
-    """
-    r = pl.program_id(0)
+
+def _dma_table(x: jax.Array) -> jax.Array:
+    """The table as the one-row DMA needs it.  Packed dtypes (bf16) keep
+    two rows per 32-bit sublane word in HBM, so a single row is not a
+    legal DMA slice: they are widened to f32, which holds every bf16 value
+    exactly.  The feature axis is zero-padded to whole 128-lane tiles;
+    zero columns leave every Gram entry unchanged."""
+    if jnp.dtype(x.dtype).itemsize < 4:
+        x = x.astype(jnp.float32)
+    pad = _round_up(x.shape[1], _LANE) - x.shape[1]
+    return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
+
+
+def _slot_ids(idx: jax.Array, mask: jax.Array, Lp: int) -> jax.Array:
+    """Fold the mask into the index rows: masked and padding slots -> -1.
+    Shaped (R, 1, Lp) so that one reducer's row is a legal block."""
+    ids = jnp.where(mask.astype(bool), idx.astype(jnp.int32), -1)
+    ids = jnp.pad(ids, ((0, 0), (0, Lp - ids.shape[1])), constant_values=-1)
+    return ids[:, None, :]
+
+
+def _ids_spec(Lp: int) -> pl.BlockSpec:
+    """Reducer ``r``'s slot ids, one (1, Lp) row in SMEM per grid step: SMEM
+    holds 1 MiB, so the plan is streamed row by row, never prefetched
+    whole."""
+    return pl.BlockSpec((None, 1, Lp), lambda r, i, j: (r, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def _gather(src_ref, ids_ref, tile: int, bl: int, dst_ref, sem) -> None:
+    """DMA rows ``ids[tile*bl : (tile+1)*bl]`` of the HBM table into the
+    (bl, d) VMEM tile — all copies in flight on one semaphore, then
+    drained.  A negative id (masked slot) issues no copy: its row keeps
+    stale VMEM contents, which only reach output entries the wrapper
+    masks (a Gram entry depends on its own two rows only)."""
+    base = tile * bl
+
+    def copy(t):
+        row = ids_ref[0, base + t]
+        return row, pltpu.make_async_copy(
+            src_ref.at[pl.ds(jnp.maximum(row, 0), 1), :],
+            dst_ref.at[pl.ds(t, 1), :], sem)
+
+    def start(t, c):
+        row, cp = copy(t)
+        pl.when(row >= 0)(cp.start)
+        return c
+
+    def wait(t, c):
+        row, cp = copy(t)
+        pl.when(row >= 0)(cp.wait)
+        return c
+
+    jax.lax.fori_loop(0, bl, start, 0)
+    jax.lax.fori_loop(0, bl, wait, 0)
+
+
+def _fused_kernel(xids_ref, yids_ref, x_ref, y_ref, o_ref, xi_ref, yj_ref,
+                  sem_ref, *, blx: int, bly: int):
+    """One (reducer, x-tile, y-tile) grid step.
+
+    xids/yids — reducer r's (1, Lp) int32 slot ids in SMEM (-1 = masked);
+    x_ref/y_ref — the full X / Y tables, ANY/HBM (rows DMA'd on demand);
+    xi/yj — (blx, d) / (bly, d) VMEM gather tiles; o_ref — the (1, blx,
+    bly) output tile.  The row tile gathers X rows through ``xids``, the
+    column tile Y rows through ``yids``, and the MXU emits their cross
+    block."""
     i = pl.program_id(1)
     j = pl.program_id(2)
-
-    def gather(tile, dst_ref):
-        """DMA rows idx[r, tile*bl : (tile+1)*bl] of the table into VMEM,
-        zeroing masked slots so the Gram block needs no separate mask.
-
-        Row copies are double-buffered (row t+1 starts before waiting on
-        row t, alternating semaphores) so the gather is pipelined rather
-        than a chain of bl sequential round-trip latencies."""
-        def get_cp(t):
-            row = idx_ref[r, tile * bl + t]
-            return pltpu.make_async_copy(
-                x_ref.at[pl.ds(row, 1), :], dst_ref.at[pl.ds(t, 1), :],
-                sem_ref.at[t % 2])
-
-        get_cp(0).start()
-
-        def body(t, _):
-            @pl.when(t + 1 < bl)
-            def _start_next():
-                get_cp(t + 1).start()
-            get_cp(t).wait()
-
-            @pl.when(msk_ref[r, tile * bl + t] == 0)
-            def _zero():
-                dst_ref[pl.ds(t, 1), :] = jnp.zeros_like(
-                    dst_ref[pl.ds(t, 1), :])
-            return 0
-        jax.lax.fori_loop(0, bl, body, 0)
-
-    # the i tile survives the whole j sweep; re-gather only the j tile
-    @pl.when(j == 0)
-    def _():
-        gather(i, xi_ref)
-    gather(j, xj_ref)
-
-    o_ref[0, :, :] = jax.lax.dot_general(
-        xi_ref[...], xj_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),        # Xi @ Xj^T
-        preferred_element_type=jnp.float32,
-    ).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bl", "interpret", "out_dtype"))
-def fused_gather_gram(
-    x: jax.Array,                  # (m, d) replicated input table
-    idx: jax.Array,                # (R, L) int32 plan rows
-    mask: jax.Array,               # (R, L) bool/int32 slot validity
-    *,
-    bl: int = 128,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:                    # (R, L, L) masked per-reducer Gram
-    R, L = idx.shape
-    d = x.shape[1]
-    if R == 0:
-        return jnp.zeros((0, L, L), out_dtype)
-    bl = min(bl, _round_up(L, min_tile_sublanes(x.dtype)))
-    Lp = _round_up(L, bl)
-    n_t = Lp // bl
-    idx = jnp.pad(idx.astype(jnp.int32), ((0, 0), (0, Lp - L)))
-    mask = jnp.pad(mask.astype(jnp.int32), ((0, 0), (0, Lp - L)))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # idx and mask rows
-        grid=(R, n_t, n_t),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],   # table in HBM
-        out_specs=pl.BlockSpec((1, bl, bl), lambda r, i, j, *_: (r, i, j)),
-        scratch_shapes=[
-            pltpu.VMEM((bl, d), x.dtype),      # xi gather tile
-            pltpu.VMEM((bl, d), x.dtype),      # xj gather tile
-            pltpu.SemaphoreType.DMA((2,)),     # double-buffered row copies
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, bl=bl),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Lp, Lp), out_dtype),
-        interpret=interpret,
-    )(idx, mask, x)
-    return out[:, :L, :L]
-
-
-# ---------------------------------------------------------------------------
-# rectangular (X2Y) variant: independent row/column gather maps
-# ---------------------------------------------------------------------------
-def _fused_rect_kernel(xidx_ref, xmsk_ref, yidx_ref, ymsk_ref, x_ref, y_ref,
-                       o_ref, xi_ref, yj_ref, sem_ref, *, blx: int,
-                       bly: int):
-    """One (reducer, x-tile, y-tile) grid step of the rectangular kernel.
-
-    Same dataflow as ``_fused_kernel`` with the two block axes decoupled:
-    the row tile gathers ``blx`` X-table rows through ``xidx``, the column
-    tile gathers ``bly`` Y-table rows through ``yidx``, and the MXU emits
-    the (blx, bly) cross block.  The square kernel is the degenerate
-    X == Y case."""
-    r = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-
-    def gather(src_ref, idx_ref, msk_ref, tile, bl, dst_ref):
-        """DMA rows idx[r, tile*bl : (tile+1)*bl] of ``src_ref`` into VMEM,
-        zeroing masked slots; double-buffered like the square kernel."""
-        def get_cp(t):
-            row = idx_ref[r, tile * bl + t]
-            return pltpu.make_async_copy(
-                src_ref.at[pl.ds(row, 1), :], dst_ref.at[pl.ds(t, 1), :],
-                sem_ref.at[t % 2])
-
-        get_cp(0).start()
-
-        def body(t, _):
-            @pl.when(t + 1 < bl)
-            def _start_next():
-                get_cp(t + 1).start()
-            get_cp(t).wait()
-
-            @pl.when(msk_ref[r, tile * bl + t] == 0)
-            def _zero():
-                dst_ref[pl.ds(t, 1), :] = jnp.zeros_like(
-                    dst_ref[pl.ds(t, 1), :])
-            return 0
-        jax.lax.fori_loop(0, bl, body, 0)
 
     # the x tile survives the whole y sweep; re-gather only the y tile
     @pl.when(j == 0)
     def _():
-        gather(x_ref, xidx_ref, xmsk_ref, i, blx, xi_ref)
-    gather(y_ref, yidx_ref, ymsk_ref, j, bly, yj_ref)
+        _gather(x_ref, xids_ref, i, blx, xi_ref, sem_ref)
+    _gather(y_ref, yids_ref, j, bly, yj_ref, sem_ref)
 
     o_ref[0, :, :] = jax.lax.dot_general(
         xi_ref[...], yj_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),        # Xi @ Yj^T
-        preferred_element_type=jnp.float32,
+        precision=_FP32, preferred_element_type=jnp.float32,
     ).astype(o_ref.dtype)
 
 
@@ -219,46 +162,57 @@ def fused_gather_gram_rect(
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> jax.Array:                    # (R, Lx, Ly) masked per-reducer cross Gram
-    """Rectangular fused gather+Gram: the bipartite shuffle streams into
-    the MXU.  Row and column gathers run through independent index maps
-    over two (possibly distinct) tables; each side pads to its own tile
-    width, so |X| != |Y| plans never pad to a square."""
+    """Fused gather+Gram: the shuffle streams into the MXU.  Row and column
+    gathers run through independent index maps over two (possibly
+    distinct) tables; each side pads to its own tile width, so |X| != |Y|
+    plans never pad to a square."""
     R, Lx = xidx.shape
     Ly = yidx.shape[1]
     assert x.shape[1] == y.shape[1], (x.shape, y.shape)
-    d = x.shape[1]
     if R == 0:
         return jnp.zeros((0, Lx, Ly), out_dtype)
+    x = _dma_table(x)
+    y = _dma_table(y)
+    d = x.shape[1]
     blx = min(bl, _round_up(Lx, min_tile_sublanes(x.dtype)))
     bly = min(bl, _round_up(Ly, min_tile_sublanes(y.dtype)))
     Lxp = _round_up(Lx, blx)
     Lyp = _round_up(Ly, bly)
-    n_tx = Lxp // blx
-    n_ty = Lyp // bly
-    xidx = jnp.pad(xidx.astype(jnp.int32), ((0, 0), (0, Lxp - Lx)))
-    xmask = jnp.pad(xmask.astype(jnp.int32), ((0, 0), (0, Lxp - Lx)))
-    yidx = jnp.pad(yidx.astype(jnp.int32), ((0, 0), (0, Lyp - Ly)))
-    ymask = jnp.pad(ymask.astype(jnp.int32), ((0, 0), (0, Lyp - Ly)))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,                 # xidx, xmask, yidx, ymask
-        grid=(R, n_tx, n_ty),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),    # X table in HBM
-                  pl.BlockSpec(memory_space=pltpu.ANY)],   # Y table in HBM
-        out_specs=pl.BlockSpec((1, blx, bly), lambda r, i, j, *_: (r, i, j)),
+    xids, yids = _slot_ids(xidx, xmask, Lxp), _slot_ids(yidx, ymask, Lyp)
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, blx=blx, bly=bly),
+        grid=(R, Lxp // blx, Lyp // bly),
+        in_specs=[_ids_spec(Lxp), _ids_spec(Lyp),
+                  pl.BlockSpec(memory_space=pl.ANY),    # X table in HBM
+                  pl.BlockSpec(memory_space=pl.ANY)],   # Y table in HBM
+        out_specs=pl.BlockSpec((1, blx, bly), lambda r, i, j: (r, i, j)),
+        out_shape=jax.ShapeDtypeStruct((R, Lxp, Lyp), out_dtype),
         scratch_shapes=[
             pltpu.VMEM((blx, d), x.dtype),     # xi gather tile
             pltpu.VMEM((bly, d), y.dtype),     # yj gather tile
-            pltpu.SemaphoreType.DMA((2,)),     # double-buffered row copies
+            pltpu.SemaphoreType.DMA(()),       # row copies of one tile
         ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_fused_rect_kernel, blx=blx, bly=bly),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Lxp, Lyp), out_dtype),
         interpret=interpret,
-    )(xidx, xmask, yidx, ymask, x, y)
-    return out[:, :Lx, :Ly]
+    )(xids, yids, x, y)
+    valid = xmask.astype(bool)[:, :, None] & ymask.astype(bool)[:, None, :]
+    return jnp.where(valid, out[:, :Lx, :Ly], 0).astype(out_dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("bl", "interpret", "out_dtype"))
+def fused_gather_gram(
+    x: jax.Array,                  # (m, d) replicated input table
+    idx: jax.Array,                # (R, L) int32 plan rows
+    mask: jax.Array,               # (R, L) bool/int32 slot validity
+    *,
+    bl: int = 128,
+    interpret: bool = False,
+    out_dtype=jnp.float32,
+) -> jax.Array:                    # (R, L, L) masked per-reducer Gram
+    """The square (X == Y) case of :func:`fused_gather_gram_rect`."""
+    return fused_gather_gram_rect(x, x, idx, mask, idx, mask, bl=bl,
+                                  interpret=interpret, out_dtype=out_dtype)
 
 
 def fused_gather_gram_rect_ref(x, y, xidx, xmask, yidx, ymask):
@@ -273,7 +227,7 @@ def fused_gather_gram_rect_ref(x, y, xidx, xmask, yidx, ymask):
 
 def fused_gather_gram_rect_streamed(x, y, xidx, xmask, yidx, ymask, *,
                                     bl: int = 128):
-    """jnp twin of the rectangular kernel's tile dataflow.
+    """jnp twin of the kernel's tile dataflow.
 
     Gathers (R, bl, d) tiles per side only; the y tile is re-gathered per
     (i, j) step exactly like the kernel, so lowered HLO traffic mirrors
@@ -293,7 +247,7 @@ def fused_gather_gram_rect_streamed(x, y, xidx, xmask, yidx, ymask, *,
     if Lx <= bl and Ly <= bl:
         gx = jnp.take(x, xidx, axis=0) * xmaskf
         gy = jnp.take(y, yidx, axis=0) * ymaskf
-        return jax.lax.dot_general(gx, gy, dims,
+        return jax.lax.dot_general(gx, gy, dims, precision=_FP32,
                                    preferred_element_type=jnp.float32)
 
     def widths_of(L):
@@ -307,6 +261,7 @@ def fused_gather_gram_rect_streamed(x, y, xidx, xmask, yidx, ymask, *,
         gi = tile(x, xidx, xmaskf, i, wi)
         rows.append(jnp.concatenate(
             [jax.lax.dot_general(gi, tile(y, yidx, ymaskf, j, wj), dims,
+                                 precision=_FP32,
                                  preferred_element_type=jnp.float32)
              for j, wj in enumerate(yw)], axis=2))
     return jnp.concatenate(rows, axis=1)
@@ -314,44 +269,16 @@ def fused_gather_gram_rect_streamed(x, y, xidx, xmask, yidx, ymask, *,
 
 def fused_gather_gram_ref(x, idx, mask):
     """Materializing oracle: gather -> mask -> batched Gram (fp32)."""
-    g = jnp.take(x, idx, axis=0) * mask.astype(x.dtype)[..., None]
-    return jax.lax.dot_general(
-        g, g, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    return fused_gather_gram_rect_ref(x, x, idx, mask, idx, mask)
 
 
 def fused_gather_gram_streamed(x, idx, mask, *, bl: int = 128):
-    """jnp twin of the kernel's tile dataflow (non-TPU fused executor).
-
-    Gathers (R, bl, d) tiles only — a multi-tile width never materializes
-    its full (R, L, d) gather, and a bucketed plan never materializes the
-    dense one.  The j tile is re-gathered per (i, j) step exactly like the
-    kernel, so lowered HLO traffic mirrors the kernel's DMA schedule.
-    """
-    R, L = idx.shape
-    maskf = mask.astype(x.dtype)[..., None]
-    dims = (((2,), (2,)), ((0,), (0,)))      # batched Xi @ Xj^T
-
-    def tile(t, width):
-        g = jnp.take(x, jax.lax.dynamic_slice_in_dim(idx, t * bl, width, 1),
-                     axis=0)
-        return g * jax.lax.dynamic_slice_in_dim(maskf, t * bl, width, 1)
-
-    if L <= bl:
-        g = jnp.take(x, idx, axis=0) * maskf
-        return jax.lax.dot_general(g, g, dims,
-                                   preferred_element_type=jnp.float32)
-
-    n_t = L // bl
-    widths = [bl] * n_t + ([L - n_t * bl] if L % bl else [])
-    rows = []
-    for i, wi in enumerate(widths):
-        gi = tile(i, wi)
-        rows.append(jnp.concatenate(
-            [jax.lax.dot_general(gi, tile(j, wj), dims,
-                                 preferred_element_type=jnp.float32)
-             for j, wj in enumerate(widths)], axis=2))
-    return jnp.concatenate(rows, axis=1)
+    """jnp twin of the square kernel (non-TPU fused executor): the X == Y
+    case of :func:`fused_gather_gram_rect_streamed`.  A multi-tile width
+    never materializes its full (R, L, d) gather, and a bucketed plan
+    never materializes the dense one."""
+    return fused_gather_gram_rect_streamed(x, x, idx, mask, idx, mask,
+                                           bl=bl)
 
 
 def fused_traffic_model(buckets, d: int, itemsize: int,

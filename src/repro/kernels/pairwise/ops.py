@@ -1,8 +1,8 @@
 """Jitted wrapper for the pairwise kernel with metric post-processing.
 
-On CPU (this container) the Pallas kernel runs in interpret mode only when
-explicitly requested; by default we dispatch to the jnp reference, keeping
-the public API identical so the engine can flip `use_kernel` freely.
+``pairwise(use_kernel=False)`` dispatches to the jnp reference, keeping the
+public API identical so the engine can flip ``use_kernel`` freely.  The
+kernel compiles for the TPU by default; CPU callers pass ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -32,7 +32,7 @@ def _finish(g, metric: str):
 
 @functools.partial(jax.jit, static_argnames=("metric", "interpret", "bm",
                                              "bn", "bk"))
-def pairwise_kernel(x, *, metric: str = "dot", interpret: bool = True,
+def pairwise_kernel(x, *, metric: str = "dot", interpret: bool = False,
                     bm: int = 128, bn: int = 128, bk: int = 512):
     """All-pairs similarity of rows of x via the Pallas kernel."""
     g = pairwise_gram(x, x, bm=bm, bn=bn, bk=bk, interpret=interpret)

@@ -47,9 +47,13 @@ def _gram_kernel(x_ref, y_ref, o_ref, acc_ref, *, n_k: int, m: int, n: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # f32 operands need fp32 products (the default rounds them to bf16);
+    # bf16 operands multiply exactly and do not accept the fp32 mode
+    fp32 = x_ref.dtype == jnp.float32
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),     # X @ Y^T
+        precision=jax.lax.Precision.HIGHEST if fp32 else None,
         preferred_element_type=jnp.float32,
     )
 

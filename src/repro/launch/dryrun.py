@@ -25,10 +25,9 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.configs.base import SHAPES, get_config, list_archs
 from repro.launch.mesh import make_production_mesh, mesh_axis_sizes
-from repro.launch.roofline import analyze_compiled
+from repro.launch.roofline import DRYRUN_TARGET, analyze_compiled, hw_for
 from repro.launch.rules import cache_logical_axes, rules_for
 from repro.launch.specs import default_flags, input_specs, shape_applicable
 from repro.models import build_model
@@ -64,7 +63,7 @@ def lower_cell(arch: str, shape: str, multi_pod: bool, flags=None,
     model = build_model(cfg, flags, rules)
     specs = input_specs(cfg, shape, flags)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             opt_cfg = AdamWConfig(
                 moment_dtype="bfloat16" if cfg.param_count() > 100e9
@@ -142,7 +141,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
                                    opt_overrides=opt_overrides)
         rep = analyze_compiled(
             compiled, arch=arch, shape=shape, mesh_name=mesh_name,
-            num_devices=ctx["n_dev"], cfg=ctx["cfg"])
+            num_devices=ctx["n_dev"], hw=hw_for(DRYRUN_TARGET),
+            cfg=ctx["cfg"])
         rec = {"status": "ok", "compile_s": round(time.time() - t0, 1),
                "flags": dataclasses.asdict(ctx["flags"]),
                **rep.row()}

@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.core import naive_pairs, plan_a2a
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import HW, combine_hlo_stats
+from repro.launch.roofline import DRYRUN_TARGET, combine_hlo_stats, hw_for
 from repro.launch.hlo_analysis import analyze_hlo_text
 from repro.kernels.pairwise.fused_gather_gram import fused_traffic_model
 from repro.mapreduce.allpairs import _block_fn
@@ -45,7 +45,7 @@ RESULTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 
 
 def _stats_rec(plan, name, stats, padded_elements, extra=None):
-    hw = HW()
+    hw = hw_for(DRYRUN_TARGET)
     rec = {
         "name": name,
         "reducers": plan.num_reducers,

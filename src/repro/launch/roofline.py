@@ -1,6 +1,8 @@
 """Roofline terms from a compiled dry-run artifact (no hardware needed).
 
-Targets TPU v5e:  197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+Peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``; the dry-runs
+model the chip named by ``DRYRUN_TARGET``.  A device kind without published
+peaks is an error, never a default.
 
   compute    = HLO_FLOPs_per_device / peak_FLOPS
   memory     = HLO_bytes_per_device / HBM_bw
@@ -21,15 +23,35 @@ import dataclasses
 import re
 from typing import Optional
 
-__all__ = ["HW", "RooflineReport", "analyze_compiled", "collective_bytes",
-           "combine_hlo_stats", "model_flops"]
+__all__ = ["DRYRUN_TARGET", "HW", "PEAKS", "RooflineReport",
+           "analyze_compiled", "collective_bytes", "combine_hlo_stats",
+           "hw_for", "model_flops"]
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12          # bf16 / chip
-    hbm_bw: float = 819e9               # B/s
-    link_bw: float = 50e9               # B/s per ICI link
+    peak_flops: float                   # bf16 FLOP/s per chip
+    hbm_bw: float                       # B/s
+    link_bw: float                      # B/s per ICI link
+
+
+# Published per-chip peaks.  TPU v5e: Google Cloud documentation, "TPU
+# v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# interchip interconnect over four links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+DRYRUN_TARGET = "TPU v5 lite"           # the chip the dry-run meshes model
+
+
+def hw_for(device_kind: str) -> HW:
+    """Peaks of ``device_kind``; unknown kinds raise ``KeyError``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}: add them to roofline.PEAKS with "
+                       f"their source") from None
 
 
 _DTYPE_BYTES = {
@@ -169,7 +191,7 @@ class RooflineReport:
 
 
 def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
-                     num_devices: int, cfg=None, hw: HW = HW()) -> RooflineReport:
+                     num_devices: int, hw: HW, cfg=None) -> RooflineReport:
     from .hlo_analysis import analyze_hlo_text
 
     # XLA's cost_analysis counts while (lax.scan) bodies once; our HLO-text

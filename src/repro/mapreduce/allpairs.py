@@ -29,7 +29,7 @@ from repro.core.schema import MappingSchema
 
 from .engine import (ReducerPlan, SparsePlan, build_plan,
                      build_sparse_plan, build_x2y_plan)
-from .executors import get_executor
+from .executors import _fill_source_map, get_executor
 
 __all__ = [
     "pairwise_similarity",
@@ -44,21 +44,28 @@ __all__ = [
 ]
 
 
+def _gram(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a @ b.T with fp32 products: the TPU's default matmul precision
+    rounds f32 operands to bf16."""
+    return jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def block_similarity(block: jax.Array, mask: jax.Array, *,
-                     metric: str = "dot", use_kernel: bool = False):
+                     metric: str = "dot", use_kernel: bool = False,
+                     interpret: bool = False):
     """(L, d), (L,) -> (L, L) similarity of the valid rows; invalid -> 0."""
     if use_kernel:
         from repro.kernels.pairwise.ops import pairwise_kernel
-        sims = pairwise_kernel(block, metric=metric, interpret=True)
+        sims = pairwise_kernel(block, metric=metric, interpret=interpret)
     else:
         if metric == "dot":
-            sims = block @ block.T
+            sims = _gram(block, block)
         elif metric == "l2":
             n2 = jnp.sum(block * block, axis=-1)
-            sims = n2[:, None] + n2[None, :] - 2.0 * (block @ block.T)
+            sims = n2[:, None] + n2[None, :] - 2.0 * _gram(block, block)
         elif metric == "cosine":
             nrm = jnp.sqrt(jnp.sum(block * block, axis=-1) + 1e-9)
-            sims = (block @ block.T) / (nrm[:, None] * nrm[None, :])
+            sims = _gram(block, block) / (nrm[:, None] * nrm[None, :])
         else:
             raise ValueError(metric)
     valid = mask[:, None] & mask[None, :]
@@ -66,16 +73,16 @@ def block_similarity(block: jax.Array, mask: jax.Array, *,
 
 
 @functools.lru_cache(maxsize=None)
-def _block_fn(metric: str, use_kernel: bool):
-    """Memoized reducer: the same (metric, use_kernel) must map to the
-    *same* function object so the engine's jit cache is hit across calls
-    instead of re-tracing every request.  The ``fused_metric`` tag is what
+def _block_fn(metric: str, use_kernel: bool, interpret: bool = False):
+    """Memoized reducer: the same (metric, use_kernel, interpret) must map
+    to the *same* function object so the engine's jit cache is hit across
+    calls instead of re-tracing every request.  The ``fused_metric`` tag is what
     lets the fused executor recognize this reducer as a Gram block and
     compute it without materializing the gather (non-tagged reducers fall
     back to the bucketed path)."""
     def fn(block, mask):
         return block_similarity(block, mask, metric=metric,
-                                use_kernel=use_kernel)
+                                use_kernel=use_kernel, interpret=interpret)
     fn.__name__ = f"block_similarity_{metric}"
     fn.fused_metric = metric
     return fn
@@ -88,15 +95,15 @@ def block_similarity_x2y(xblock: jax.Array, xmask: jax.Array,
     valid rows; invalid pairs -> 0.  The rectangular analogue of
     :func:`block_similarity` (which is the degenerate X == Y case)."""
     if metric == "dot":
-        sims = xblock @ yblock.T
+        sims = _gram(xblock, yblock)
     elif metric == "l2":
         n2x = jnp.sum(xblock * xblock, axis=-1)
         n2y = jnp.sum(yblock * yblock, axis=-1)
-        sims = n2x[:, None] + n2y[None, :] - 2.0 * (xblock @ yblock.T)
+        sims = n2x[:, None] + n2y[None, :] - 2.0 * _gram(xblock, yblock)
     elif metric == "cosine":
         nx = jnp.sqrt(jnp.sum(xblock * xblock, axis=-1) + 1e-9)
         ny = jnp.sqrt(jnp.sum(yblock * yblock, axis=-1) + 1e-9)
-        sims = (xblock @ yblock.T) / (nx[:, None] * ny[None, :])
+        sims = _gram(xblock, yblock) / (nx[:, None] * ny[None, :])
     else:
         raise ValueError(metric)
     valid = xmask[:, None] & ymask[None, :]
@@ -159,21 +166,13 @@ def _pair_source_map_rect(plan: ReducerPlan, mx: int,
     if cached is not None and cached[0] == (mx, my):
         return cached[1]
     srcmap = np.zeros((mx, my), np.int32)
-    base = 1
-    for b in plan.buckets:
-        Rb, Lx = b.idx.shape
-        Ly = b.yidx.shape[1]
-        rows = np.broadcast_to(b.idx[:, :, None], (Rb, Lx, Ly))
-        cols = np.broadcast_to(b.yidx[:, None, :], (Rb, Lx, Ly))
-        valid = b.mask[:, :, None] & b.ymask[:, None, :]
-        pos = np.arange(base, base + Rb * Lx * Ly,
-                        dtype=np.int64).reshape(Rb, Lx, Ly)
-        srcmap[rows[valid], cols[valid]] = pos[valid]
-        base += Rb * Lx * Ly
+    _fill_source_map(srcmap, ((b.idx, b.mask, b.yidx, b.ymask)
+                              for b in plan.buckets))
     object.__setattr__(plan, "_pair_srcmap_rect", ((mx, my), srcmap))
     return srcmap
 
 
+@jax.jit
 def _scatter_blocks_x2y(out: jax.Array, blocks: jax.Array, xidx: jax.Array,
                         xmask: jax.Array, yidx: jax.Array,
                         ymask: jax.Array) -> jax.Array:
@@ -190,6 +189,7 @@ def _scatter_blocks_x2y(out: jax.Array, blocks: jax.Array, xidx: jax.Array,
     return out.at[rows.reshape(-1), cols.reshape(-1)].max(flat_vals)
 
 
+@jax.jit
 def _finish_x2y_matrix(out: jax.Array) -> jax.Array:
     """Uncovered / invalidated cells -> 0 (no diagonal to zero: an (x, y)
     pair is never a self-pair)."""
@@ -240,16 +240,8 @@ def _pair_source_map(plan: ReducerPlan, m: int) -> np.ndarray:
     if cached is not None and cached[0] == m:
         return cached[1]
     srcmap = np.zeros((m, m), np.int32)
-    base = 1
-    for b in plan.buckets:
-        Rb, Lb = b.idx.shape
-        rows = np.broadcast_to(b.idx[:, :, None], (Rb, Lb, Lb))
-        cols = np.broadcast_to(b.idx[:, None, :], (Rb, Lb, Lb))
-        valid = b.mask[:, :, None] & b.mask[:, None, :]
-        pos = np.arange(base, base + Rb * Lb * Lb,
-                        dtype=np.int64).reshape(Rb, Lb, Lb)
-        srcmap[rows[valid], cols[valid]] = pos[valid]
-        base += Rb * Lb * Lb
+    _fill_source_map(srcmap, ((b.idx, b.mask, b.idx, b.mask)
+                              for b in plan.buckets))
     np.fill_diagonal(srcmap, 0)
     object.__setattr__(plan, "_pair_srcmap", (m, srcmap))
     return srcmap
@@ -322,7 +314,7 @@ def pairwise_similarity(
             pad_reducers_to=(mesh.devices.size if mesh is not None else 1),
             pad_slots_to=pad_slots_to,
         )
-    fn = _block_fn(metric, use_kernel)
+    fn = _block_fn(metric, use_kernel, interpret)
     with _obs_span("execute", workload="pairs",
                    reducers=plan.num_reducers):
         sims = _run_and_assemble(x, plan, fn, m, mesh, executor,
@@ -415,7 +407,7 @@ def some_pairs_similarity(
             pad_reducers_to=(mesh.devices.size if mesh is not None else 1),
             pad_slots_to=pad_slots_to,
         )
-    fn = _block_fn(metric, use_kernel)
+    fn = _block_fn(metric, use_kernel, interpret)
     with _obs_span("execute", workload="some_pairs",
                    reducers=plan.num_reducers):
         sims = _run_and_assemble(x, plan, fn, m, mesh, executor,
@@ -474,11 +466,15 @@ def x2y_similarity(
     return sims, plan, schema
 
 
+@jax.jit
 def _scatter_blocks(out: jax.Array, blocks: jax.Array, idx: jax.Array,
                     mask: jax.Array) -> jax.Array:
     """max-scatter (R, L, L) reducer blocks into the running (m, m) matrix
     (initialized to -inf).  A pair may meet at several reducers; values
-    agree, so `max` combine is deterministic."""
+    agree, so `max` combine is deterministic.  One program per block
+    shape: the streaming delta path and its load-time warm-up call this
+    once per sub-plan shape, and op-by-op dispatch would compile every
+    primitive of it separately."""
     L = idx.shape[1]
     rows = jnp.repeat(idx[:, :, None], L, axis=2)     # (R, L, L) row ids
     cols = jnp.repeat(idx[:, None, :], L, axis=1)     # (R, L, L) col ids
@@ -487,6 +483,7 @@ def _scatter_blocks(out: jax.Array, blocks: jax.Array, idx: jax.Array,
     return out.at[rows.reshape(-1), cols.reshape(-1)].max(flat_vals)
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def _finish_pair_matrix(out: jax.Array, m: int) -> jax.Array:
     out = jnp.where(jnp.isneginf(out), 0.0, out)
     return out * (1.0 - jnp.eye(m, dtype=out.dtype))

@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 from repro.core.planner import PlanPartition, partition_plan
 from repro.obs import EVENTS as _EVENTS
 from repro.obs import LEDGER as _LEDGER
@@ -714,6 +714,13 @@ def _shard_mesh(mesh, shard_axes):
     return mesh, axes, num_shards
 
 
+def _put(a, mesh, spec) -> jax.Array:
+    """Place a host array straight into its mesh sharding.  ``jnp.asarray``
+    would stage the whole array on the default device first: the first
+    device would hold every shard's maps at once."""
+    return jax.device_put(a, jax.sharding.NamedSharding(mesh, spec))
+
+
 def _stacked_groups(plan: ReducerPlan, part: PlanPartition,
                     rows_by_shard=None):
     """Stack the partition into uniform per-width device arrays.
@@ -769,24 +776,47 @@ def _stacked_groups(plan: ReducerPlan, part: PlanPartition,
     return groups
 
 
+_SRCMAP_CHUNK = 1 << 24          # block cells expanded at once on the host
+# Per-shard bodies call the Pallas kernels, whose outputs carry no
+# varying-mesh-axis type: shard_map's vma check cannot trace them
+_CHECK_VMA = False
+
+
+def _fill_source_map(srcmap: np.ndarray, blocks) -> None:
+    """Point ``srcmap[row, col]`` at every valid cell's position in the
+    concatenation ``[0.0, blocks_0.ravel(), blocks_1.ravel(), ...]``.
+
+    ``blocks`` yields ``(xidx (R, Lx), xmask, yidx (R, Ly), ymask)`` per
+    stack of reducer blocks (a square plan passes its idx/mask twice).
+    Reducers are expanded a chunk at a time, so host memory stays bounded
+    however many cells the plan has; a cell covered twice keeps its last
+    writer, exactly as one whole-stack assignment would."""
+    base = 1
+    for xidx, xmask, yidx, ymask in blocks:
+        R, Lx = xidx.shape
+        cells = Lx * yidx.shape[1]
+        if base + R * cells - 1 > np.iinfo(np.int32).max:
+            raise ValueError("plan has more block cells than an int32 "
+                             "source map can address")
+        step = max(1, _SRCMAP_CHUNK // max(cells, 1))
+        for r0 in range(0, R, step):
+            sl = slice(r0, r0 + step)
+            valid = xmask[sl, :, None] & ymask[sl, None, :]
+            rows = np.broadcast_to(xidx[sl, :, None], valid.shape)[valid]
+            cols = np.broadcast_to(yidx[sl, None, :], valid.shape)[valid]
+            srcmap[rows, cols] = base + r0 * cells + np.flatnonzero(valid)
+        base += R * cells
+
+
 def _sharded_srcmap(groups, m: int) -> np.ndarray:
     """Inverse-shuffle map for the cross-shard assembly gather: (m, m)
     int32 positions into ``[0.0, group_0.ravel(), group_1.ravel(), ...]``
     of the stacked per-width Gram outputs (each ``(S, Rw, w, w)``).
     Uncovered cells and the diagonal point at slot 0 (-> 0.0)."""
     srcmap = np.zeros((m, m), np.int32)
-    base = 1
-    for idx, mask, _rows in groups:
-        S, Rw, w = idx.shape
-        flat_idx = idx.reshape(S * Rw, w)
-        flat_mask = mask.reshape(S * Rw, w)
-        rows = np.broadcast_to(flat_idx[:, :, None], (S * Rw, w, w))
-        cols = np.broadcast_to(flat_idx[:, None, :], (S * Rw, w, w))
-        valid = flat_mask[:, :, None] & flat_mask[:, None, :]
-        pos = np.arange(base, base + S * Rw * w * w,
-                        dtype=np.int64).reshape(S * Rw, w, w)
-        srcmap[rows[valid], cols[valid]] = pos[valid]
-        base += S * Rw * w * w
+    flat = [(idx.reshape(-1, idx.shape[2]), mask.reshape(-1, idx.shape[2]))
+            for idx, mask, _rows in groups]
+    _fill_source_map(srcmap, ((i, k, i, k) for i, k in flat))
     np.fill_diagonal(srcmap, 0)
     return srcmap
 
@@ -850,23 +880,10 @@ def _sharded_rect_srcmap(groups, shape: tuple[int, int]) -> np.ndarray:
     ``[0.0, group_0.ravel(), ...]`` of the stacked per-(wx, wy) cross-Gram
     outputs (each ``(S, Rw, wx, wy)``).  No diagonal to zero — an (x, y)
     pair is never a self-pair; uncovered cells point at slot 0."""
-    mx, my = shape
-    srcmap = np.zeros((mx, my), np.int32)
-    base = 1
-    for xidx, xmask, yidx, ymask, _rows in groups:
-        S, Rw, wx = xidx.shape
-        wy = yidx.shape[2]
-        fx = xidx.reshape(S * Rw, wx)
-        fxm = xmask.reshape(S * Rw, wx)
-        fy = yidx.reshape(S * Rw, wy)
-        fym = ymask.reshape(S * Rw, wy)
-        rows = np.broadcast_to(fx[:, :, None], (S * Rw, wx, wy))
-        cols = np.broadcast_to(fy[:, None, :], (S * Rw, wx, wy))
-        valid = fxm[:, :, None] & fym[:, None, :]
-        pos = np.arange(base, base + S * Rw * wx * wy,
-                        dtype=np.int64).reshape(S * Rw, wx, wy)
-        srcmap[rows[valid], cols[valid]] = pos[valid]
-        base += S * Rw * wx * wy
+    srcmap = np.zeros(shape, np.int32)
+    _fill_source_map(srcmap, (
+        tuple(a.reshape(-1, a.shape[2]) for a in (xi, xm, yi, ym))
+        for xi, xm, yi, ym, _rows in groups))
     return srcmap
 
 
@@ -892,9 +909,10 @@ def _make_sharded_jitted(metric, combine, mesh, axes, use_kernel,
     def run(x, groups, srcmap, R, L):
         outs = []
         for idx, msk, rows in groups:
-            g = shard_map(per_shard_fn, mesh=mesh,
-                          in_specs=(P(), P(axes), P(axes)),
-                          out_specs=P(axes))(x, idx, msk)
+            g = jax.shard_map(per_shard_fn, mesh=mesh,
+                              in_specs=(P(), P(axes), P(axes)),
+                              out_specs=P(axes),
+                              check_vma=_CHECK_VMA)(x, idx, msk)
             outs.append((rows, g))
         if combine == "pairs":
             # ONE cross-shard assembly gather: concatenate the sharded
@@ -942,10 +960,10 @@ def _make_sharded_rect_jitted(metric, mesh, axes, use_kernel, interpret,
         n2y = jnp.sum(yt.astype(jnp.float32) ** 2, axis=-1)
         vals = [jnp.zeros((1,), jnp.float32)]
         for xidx, xmsk, yidx, ymsk, _rows in groups:
-            g = shard_map(per_shard_fn, mesh=mesh,
-                          in_specs=(P(), P(), P(), P(), P(axes), P(axes),
-                                    P(axes), P(axes)),
-                          out_specs=P(axes))(
+            g = jax.shard_map(per_shard_fn, mesh=mesh,
+                              in_specs=(P(), P(), P(), P(), P(axes),
+                                        P(axes), P(axes), P(axes)),
+                              out_specs=P(axes), check_vma=_CHECK_VMA)(
                 xt, yt, n2x, n2y, xidx, xmsk, yidx, ymsk)
             vals.append(g.reshape(-1))
         # ONE cross-shard assembly gather of the (mx, my) matrix — the
@@ -1071,9 +1089,10 @@ class ShardedExecutor(Executor):
                 measured_slots=_group_valid_slots(
                     plan, ("sharded", S), groups, count_y=False),
                 assembled_bytes=assembled, meta=meta)
+        P = jax.sharding.PartitionSpec
         if combine == "pairs":
-            srcmap = jnp.asarray(
-                self._srcmap_for(plan, groups, S, srcmap_m))
+            srcmap = _put(self._srcmap_for(plan, groups, S, srcmap_m), mesh,
+                          P())
         else:
             srcmap = jnp.zeros((1,), jnp.int32)      # unused placeholder
         if use_kernel is None:
@@ -1083,8 +1102,8 @@ class ShardedExecutor(Executor):
              bool(interpret), bl),
             lambda: _make_sharded_jitted(metric, combine, mesh, axes,
                                          use_kernel, interpret, bl))
-        jgroups = tuple((jnp.asarray(i), jnp.asarray(k), jnp.asarray(r))
-                        for i, k, r in groups)
+        jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in g)
+                        for g in groups)
         return fn(x, jgroups, srcmap, plan.R, plan.L)
 
     # -- protocol ----------------------------------------------------------
@@ -1157,16 +1176,17 @@ class ShardedExecutor(Executor):
                 assembled_bytes=S * per_shard,
                 meta={"num_shards": S,
                       "assembly_bytes_per_shard": per_shard})
-        srcmap = jnp.asarray(
-            self._rect_srcmap_for(plan, groups, S, tuple(shape)))
+        P = jax.sharding.PartitionSpec
+        srcmap = _put(self._rect_srcmap_for(plan, groups, S, tuple(shape)),
+                      mesh, P())
         uk = True if use_kernel else jax.default_backend() == "tpu"
         fn = _cache_get(
             ("sharded-x2y", metric, mesh, axes, bool(uk), bool(interpret),
              bl),
             lambda: _make_sharded_rect_jitted(metric, mesh, axes, uk,
                                               interpret, bl))
-        jgroups = tuple(
-            tuple(jnp.asarray(a) for a in grp) for grp in groups)
+        jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in grp)
+                        for grp in groups)
         xt, yt = _as_tables(tables)
         return fn(xt, yt, jgroups, srcmap)
 
@@ -1347,10 +1367,10 @@ def _make_coded_jitted(metric, mesh, axes, use_kernel, interpret, bl):
     def run(xt, yt, groups, sendmap, srcmap):
         n2x = jnp.sum(xt.astype(jnp.float32) ** 2, axis=-1)
         n2y = jnp.sum(yt.astype(jnp.float32) ** 2, axis=-1)
-        out = shard_map(per_shard_fn, mesh=mesh,
-                        in_specs=(P(), P(), P(), P(), P(axes), P(axes),
-                                  P(axes)),
-                        out_specs=P(axes))(
+        out = jax.shard_map(per_shard_fn, mesh=mesh,
+                            in_specs=(P(), P(), P(), P(), P(axes), P(axes),
+                                      P(axes)),
+                            out_specs=P(axes), check_vma=_CHECK_VMA)(
             xt, yt, n2x, n2y, groups, sendmap, srcmap)
         return out.reshape(-1, out.shape[-1])   # (S*rb, my); caller trims
 
@@ -1488,12 +1508,11 @@ class CodedExecutor(ShardedExecutor):
              bool(interpret), bl),
             lambda: _make_coded_jitted(metric, mesh, axes, use_kernel,
                                        interpret, bl))
-        jgroups = tuple(
-            (jnp.asarray(xi), jnp.asarray(xm), jnp.asarray(yi),
-             jnp.asarray(ym))
-            for xi, xm, yi, ym, _rows in groups)
-        out = fn(xt, yt, jgroups, jnp.asarray(sendmap),
-                 jnp.asarray(srcmap))
+        P = jax.sharding.PartitionSpec
+        jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in g[:4])
+                        for g in groups)
+        out = fn(xt, yt, jgroups, _put(sendmap, mesh, P(axes)),
+                 _put(srcmap, mesh, P(axes)))
         return out[:shape[0]]
 
     # -- protocol ----------------------------------------------------------

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import pcast, shard_map
 
 __all__ = ["pipeline_apply", "bubble_fraction"]
 
@@ -50,8 +49,8 @@ def pipeline_apply(
         params_one = jax.tree.map(lambda a: a[0], params_local)
         sid = jax.lax.axis_index(stage_axis)
         # mark carries as stage-varying up front (scan requires stable vma)
-        buf = pcast(jnp.zeros_like(xs[0]), stage_axis, to="varying")
-        outs = pcast(jnp.zeros_like(xs), stage_axis, to="varying")
+        buf = jax.lax.pcast(jnp.zeros_like(xs[0]), stage_axis, to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), stage_axis, to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -79,7 +78,7 @@ def pipeline_apply(
         return jax.lax.psum(outs, stage_axis)
 
     params_spec = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(params_spec, P()),
         out_specs=P(),

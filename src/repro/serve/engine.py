@@ -139,12 +139,11 @@ class PairwiseService:
     weight profiles hit ``repro.core.PLAN_CACHE`` and skip planning — and
     executes it on any executor-registry entry ("dense" / "bucketed" /
     "fused" / "sharded" / "coded" / "streaming"); the default bucketed
-    path keeps
-    skewed profiles from paying the dense global-max padding.  The
-    service holds a
-    *private* executor instance (``make_executor``), so its dispatch
-    telemetry is isolated from concurrent callers.  Responses carry the
-    plan provenance (winning strategy, communication cost, optimality gap)
+    path keeps skewed profiles from paying the dense global-max padding.
+    The service holds a *private* executor instance (``make_executor``,
+    given ``executor_options`` — e.g. ``{"replication": 1}`` for
+    "coded"), so its dispatch telemetry is isolated from concurrent
+    callers.  Responses carry the plan provenance (winning strategy, communication cost, optimality gap)
     and the bucket/shard telemetry the dashboards chart; the service
     accumulates the same numbers across requests in ``self.stats``.
     """
@@ -152,7 +151,8 @@ class PairwiseService:
     def __init__(self, q: float, *, metric: str = "dot", mesh=None,
                  executor: str = "bucketed", max_buckets: int = 8,
                  use_kernel: bool = False, interpret: bool = False,
-                 tenant: str = "default"):
+                 tenant: str = "default",
+                 executor_options: Optional[dict] = None):
         from repro.mapreduce import make_executor
         self.q = q
         self.metric = metric
@@ -162,7 +162,7 @@ class PairwiseService:
         # a PRIVATE executor instance: dispatch counters are scoped to this
         # service, so concurrent services (or other callers of the default
         # registry objects) can't pollute each other's telemetry
-        self._executor = make_executor(executor)
+        self._executor = make_executor(executor, **(executor_options or {}))
         self.max_buckets = max_buckets
         self.use_kernel = use_kernel
         self.interpret = interpret
@@ -430,7 +430,7 @@ class PairwiseService:
     # ------------------------------------------------------------- streaming
     def _reducer_fn(self):
         from repro.mapreduce.allpairs import _block_fn
-        return _block_fn(self.metric, self.use_kernel)
+        return _block_fn(self.metric, self.use_kernel, self.interpret)
 
     def _require_streaming(self):
         from repro.stream import StreamingExecutor

@@ -4,7 +4,8 @@ The fused gather+Gram path (kernel, streamed twin, and the one-program
 executor with inverse-shuffle assembly) must be a pure execution-plan
 change: identical outputs on random, Zipf-skewed, and degenerate schemas.
 All Pallas paths run in ``interpret=True`` mode (CPU CI); the same
-``pallas_call`` lowers to the real scalar-prefetch kernel on TPU.
+``pallas_call`` lowers to the TPU kernel (``tests/test_chip_compile.py``
+compiles it for a described chip).
 """
 
 import jax.numpy as jnp
@@ -97,6 +98,25 @@ class TestFusedGatherGramKernel:
         mask = jnp.zeros((3, 6), bool)
         got = fused_gather_gram(x, idx, mask, bl=8, interpret=True)
         assert float(jnp.abs(got).max()) == 0.0
+
+    @pytest.mark.parametrize("R,L,m,d,dtype", [
+        (2100, 8, 64, 8, jnp.float32),     # whole-plan SMEM prefetch > 1 MiB
+        (6, 20, 40, 64, jnp.float32),      # rows narrower than 128 lanes
+        (5, 24, 40, 16, jnp.bfloat16),     # packed dtype, masked slots
+    ], ids=["smem-overflow-R", "d64", "bf16-masked"])
+    def test_chip_layout_repairs(self, R, L, m, d, dtype):
+        """Plans the chip's compiler once refused.  Masked slots carry
+        out-of-range ids: the kernel must issue no copy for them."""
+        rng = np.random.default_rng(R + d)
+        x = _rand(rng, (m, d), dtype)
+        idx = rng.integers(0, m, (R, L)).astype(np.int32)
+        mask = rng.uniform(size=(R, L)) > 0.3
+        got = fused_gather_gram(x, jnp.asarray(np.where(mask, idx, m + 7)),
+                                jnp.asarray(mask), bl=8, interpret=True)
+        ref = fused_gather_gram_ref(x, jnp.asarray(idx), jnp.asarray(mask))
+        assert got.shape == (R, L, L) and got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------- executor

@@ -4,8 +4,10 @@ validated on (a) synthetic HLO text and (b) a real compiled jax program."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.launch.hlo_analysis import analyze_hlo_text
+from repro.launch.roofline import hw_for
 
 SYNTH = """
 %cond.1 (p: (s32[], f32[8,8])) -> pred[] {
@@ -81,3 +83,14 @@ class TestRealProgram:
         stats = analyze_hlo_text(compiled.as_text())
         want = 2 * 32 * 16 * 8
         assert want <= stats.flops <= want * 1.2
+
+
+class TestPeaks:
+    def test_known_kind_has_published_peaks(self):
+        hw = hw_for("TPU v5 lite")
+        assert (hw.peak_flops, hw.hbm_bw) == (197e12, 819e9)
+
+    def test_unknown_kind_is_an_error(self):
+        """No default peaks: a roofline against the wrong chip is wrong."""
+        with pytest.raises(KeyError, match="cpu"):
+            hw_for("cpu")

@@ -42,9 +42,32 @@ class TestAllPairs:
         x = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
         s_ref, _, sch = pairwise_similarity(x, q=5.0, metric="dot")
         s_k, _, _ = pairwise_similarity(x, q=5.0, metric="dot",
-                                        schema=sch, use_kernel=True)
+                                        schema=sch, use_kernel=True,
+                                        interpret=True)
         np.testing.assert_allclose(np.asarray(s_ref), np.asarray(s_k),
                                    rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("interpret", [False, True])
+    def test_kernel_flag_reaches_pairwise_gram(self, monkeypatch, interpret):
+        """``use_kernel=True`` compiles the real kernel unless the caller
+        asks for the interpreter: its flag, not a hard-wired one, reaches
+        ``pairwise_gram``."""
+        from repro.kernels.pairwise import ops
+        from repro.mapreduce.allpairs import block_similarity
+        seen = []
+
+        def fake_gram(x, y, *, interpret, **kw):
+            seen.append(interpret)
+            return x @ y.T
+
+        monkeypatch.setattr(ops, "pairwise_gram", fake_gram)
+        rng = np.random.default_rng(4)
+        x = jnp.asarray(rng.normal(size=(13, 7)).astype(np.float32))
+        got = block_similarity(x, jnp.ones(13, bool), use_kernel=True,
+                               interpret=interpret)
+        assert seen == [interpret]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(x @ x.T),
+                                   rtol=1e-5, atol=1e-5)
 
     def test_comm_cost_equals_gather_volume(self):
         rng = np.random.default_rng(2)

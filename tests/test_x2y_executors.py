@@ -100,6 +100,24 @@ class TestRectKernel:
                                      interpret=True)
         assert got.shape == (0, 4, 4)
 
+    @pytest.mark.parametrize("R,Lx,Ly,d,dtype", [
+        (2100, 8, 4, 8, np.float32),       # whole-plan SMEM prefetch > 1 MiB
+        (5, 19, 11, 64, np.float32),       # rows narrower than 128 lanes
+        (4, 12, 20, 16, jnp.bfloat16),     # packed dtype, masked slots
+    ], ids=["smem-overflow-R", "d64", "bf16-masked"])
+    def test_chip_layout_repairs(self, R, Lx, Ly, d, dtype):
+        """Plans the chip's compiler once refused.  Masked slots carry
+        out-of-range ids: the kernel must issue no copy for them."""
+        x, y, xidx, xmask, yidx, ymask = _rect_case(
+            R, Lx, Ly, mx=40, my=30, d=d, seed=R + d)
+        x, y = x.astype(dtype), y.astype(dtype)
+        ref = fused_gather_gram_rect_ref(x, y, xidx, xmask, yidx, ymask)
+        got = fused_gather_gram_rect(
+            x, y, jnp.where(xmask, xidx, 40 + 7), xmask,
+            jnp.where(ymask, yidx, 30 + 7), ymask, bl=8, interpret=True)
+        assert got.shape == (R, Lx, Ly) and got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **TOL)
+
 
 class TestRectPartition:
     """``partition_plan`` on rectangular plans: coverage, both-side
