@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device's operation intervals) / window, averaged over
+the chips."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if not t or not t["chips"] or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
