@@ -60,6 +60,7 @@ from .engine import (
     build_plan,
     build_sparse_plan,
     build_x2y_plan,
+    compile_count,
     configure_block_cache,
     configure_jit_cache,
     fused_stats,
@@ -97,7 +98,7 @@ __all__ = [
     "run_reducers_x2y_bucketed",
     "Executor", "get_executor", "make_executor", "register_executor",
     "list_executors",
-    "fused_stats", "jit_cache_stats", "configure_jit_cache",
+    "fused_stats", "jit_cache_stats", "configure_jit_cache", "compile_count",
     "block_cache_stats", "configure_block_cache",
     "pairwise_similarity", "pairwise_similarity_block",
     "some_pairs_similarity", "x2y_similarity",

@@ -124,7 +124,8 @@ def _block_fn_x2y(metric: str):
 
 
 def _plan_for(schema, *, pad_reducers_to: int, pad_slots_to: int):
-    """``build_plan`` memoized on the schema object.
+    """``build_plan`` memoized on the schema object, under a ``lower``
+    span (``cached``: the schema held the plan).
 
     Plans are pure functions of (schema, padding); caching them on the
     schema keeps the per-request host work O(1) for repeated profiles —
@@ -132,10 +133,11 @@ def _plan_for(schema, *, pad_reducers_to: int, pad_slots_to: int):
     key = (pad_reducers_to, pad_slots_to)
     cache = schema.__dict__.setdefault("_reducer_plan_cache", {})
     plan = cache.get(key)
-    if plan is None:
-        plan = build_plan(schema, pad_reducers_to=pad_reducers_to,
-                          pad_slots_to=pad_slots_to)
-        cache[key] = plan
+    with _obs_span("lower", cached=plan is not None):
+        if plan is None:
+            plan = cache[key] = build_plan(
+                schema, pad_reducers_to=pad_reducers_to,
+                pad_slots_to=pad_slots_to)
     return plan
 
 
@@ -146,11 +148,11 @@ def _x2y_plan_for(schema, num_x: int, *, pad_reducers_to: int,
     key = ("x2y", num_x, pad_reducers_to, pad_slots_to)
     cache = schema.__dict__.setdefault("_reducer_plan_cache", {})
     plan = cache.get(key)
-    if plan is None:
-        plan = build_x2y_plan(schema, num_x,
-                              pad_reducers_to=pad_reducers_to,
-                              pad_slots_to=pad_slots_to)
-        cache[key] = plan
+    with _obs_span("lower", cached=plan is not None):
+        if plan is None:
+            plan = cache[key] = build_x2y_plan(
+                schema, num_x, pad_reducers_to=pad_reducers_to,
+                pad_slots_to=pad_slots_to)
     return plan
 
 
@@ -161,14 +163,17 @@ def _pair_source_map_rect(plan: ReducerPlan, mx: int,
     stacks.  Like :func:`_pair_source_map` with decoupled axes — rows come
     from each bucket's X-side ids, columns from its Y-side ids, and there
     is no diagonal to zero (an (x, y) pair is never a self-pair).
-    Uncovered cells point at slot 0 (-> 0.0).  Cached on the plan."""
+    Uncovered cells point at slot 0 (-> 0.0).  Cached on the plan; looked
+    up and built under a ``maps`` span."""
     cached = plan.__dict__.get("_pair_srcmap_rect")
-    if cached is not None and cached[0] == (mx, my):
-        return cached[1]
-    srcmap = np.zeros((mx, my), np.int32)
-    _fill_source_map(srcmap, ((b.idx, b.mask, b.yidx, b.ymask)
-                              for b in plan.buckets))
-    object.__setattr__(plan, "_pair_srcmap_rect", ((mx, my), srcmap))
+    hit = cached is not None and cached[0] == (mx, my)
+    with _obs_span("maps", what="srcmap", cached=hit):
+        if hit:
+            return cached[1]
+        srcmap = np.zeros((mx, my), np.int32)
+        _fill_source_map(srcmap, ((b.idx, b.mask, b.yidx, b.ymask)
+                                  for b in plan.buckets))
+        object.__setattr__(plan, "_pair_srcmap_rect", ((mx, my), srcmap))
     return srcmap
 
 
@@ -234,16 +239,19 @@ def _pair_source_map(plan: ReducerPlan, m: int) -> np.ndarray:
     duplicate block values agree exactly, so assembly becomes a gather
     instead of the bucketed path's max-combine scatter.  Uncovered cells
     and the diagonal point at slot 0 (-> 0.0).  Cached on the plan: like
-    the index matrix itself, it is a static artifact reused across waves.
+    the index matrix itself, it is a static artifact reused across waves;
+    looked up and built under a ``maps`` span.
     """
     cached = plan.__dict__.get("_pair_srcmap")
-    if cached is not None and cached[0] == m:
-        return cached[1]
-    srcmap = np.zeros((m, m), np.int32)
-    _fill_source_map(srcmap, ((b.idx, b.mask, b.idx, b.mask)
-                              for b in plan.buckets))
-    np.fill_diagonal(srcmap, 0)
-    object.__setattr__(plan, "_pair_srcmap", (m, srcmap))
+    hit = cached is not None and cached[0] == m
+    with _obs_span("maps", what="srcmap", cached=hit):
+        if hit:
+            return cached[1]
+        srcmap = np.zeros((m, m), np.int32)
+        _fill_source_map(srcmap, ((b.idx, b.mask, b.idx, b.mask)
+                                  for b in plan.buckets))
+        np.fill_diagonal(srcmap, 0)
+        object.__setattr__(plan, "_pair_srcmap", (m, srcmap))
     return srcmap
 
 

@@ -53,6 +53,7 @@ from __future__ import annotations
 import dataclasses
 import os
 from collections import OrderedDict
+from contextlib import contextmanager
 from functools import partial
 from typing import Callable, Optional
 
@@ -88,6 +89,7 @@ __all__ = [
     "configure_jit_cache",
     "block_cache_stats",
     "configure_block_cache",
+    "compile_count",
     "fused_stats",
     "reset_fused_stats",
 ]
@@ -496,14 +498,32 @@ def block_subplan(sparse: SparsePlan, i0: int, i1: int, j0: int, j1: int,
     if cache is None:
         cache = OrderedDict()
         object.__setattr__(sparse, "_block_cache", cache)
-    if key in cache:
-        cache.move_to_end(key)
-        _BLOCK_CACHE_STATS["hits"] += 1
-        _OBS_REGISTRY.counter("cache.hits", cache="block").inc()
-        return cache[key]
-    _BLOCK_CACHE_STATS["misses"] += 1
-    _OBS_REGISTRY.counter("cache.misses", cache="block").inc()
+    hit = key in cache
+    with _obs_span("lower", cached=hit):
+        if hit:
+            cache.move_to_end(key)
+            _BLOCK_CACHE_STATS["hits"] += 1
+            _OBS_REGISTRY.counter("cache.hits", cache="block").inc()
+            return cache[key]
+        _BLOCK_CACHE_STATS["misses"] += 1
+        _OBS_REGISTRY.counter("cache.misses", cache="block").inc()
+        plan = _build_block_subplan(sparse, i0, i1, j0, j1, pad_reducers_to,
+                                    pad_slots_to, max_buckets)
+        cache[key] = plan
+        while len(cache) > cache_size:
+            evicted, _ = cache.popitem(last=False)
+            _BLOCK_CACHE_STATS["evictions"] += 1
+            _OBS_REGISTRY.counter("cache.evictions", cache="block").inc()
+            _OBS_EVENTS.emit("cache_eviction", cache="block",
+                             key=str(evicted))
+    return plan
 
+
+def _build_block_subplan(sparse: SparsePlan, i0: int, i1: int, j0: int,
+                         j1: int, pad_reducers_to: int, pad_slots_to: int,
+                         max_buckets: int) -> Optional[ReducerPlan]:
+    """The sub-plan :func:`block_subplan` caches: the reducers hosting a
+    row bin and a column bin of the block, in block-local ids."""
     row_bins = np.unique(sparse.bin_of[i0:i1])
     col_bins = np.unique(sparse.bin_of[j0:j1])
     row_bins = row_bins[row_bins >= 0]
@@ -526,23 +546,13 @@ def block_subplan(sparse: SparsePlan, i0: int, i1: int, j0: int, j1: int,
             xs.append(xr)
             ys.append(yr)
     if not xs:
-        plan = None
-    else:
-        plan = build_x2y_plan_arrays(
-            xs, ys, num_x=i1 - i0, num_y=j1 - j0,
-            comm_cost=float(sum(len(a) + len(b)
-                                for a, b in zip(xs, ys))),
-            algorithm=f"block+{sparse.algorithm}",
-            pad_reducers_to=pad_reducers_to, pad_slots_to=pad_slots_to,
-            max_buckets=max_buckets)
-    cache[key] = plan
-    while len(cache) > cache_size:
-        evicted, _ = cache.popitem(last=False)
-        _BLOCK_CACHE_STATS["evictions"] += 1
-        _OBS_REGISTRY.counter("cache.evictions", cache="block").inc()
-        _OBS_EVENTS.emit("cache_eviction", cache="block",
-                         key=str(evicted))
-    return plan
+        return None
+    return build_x2y_plan_arrays(
+        xs, ys, num_x=i1 - i0, num_y=j1 - j0,
+        comm_cost=float(sum(len(a) + len(b) for a, b in zip(xs, ys))),
+        algorithm=f"block+{sparse.algorithm}",
+        pad_reducers_to=pad_reducers_to, pad_slots_to=pad_slots_to,
+        max_buckets=max_buckets)
 
 
 def build_x2y_plan(schema: MappingSchema, num_x: int, *,
@@ -659,8 +669,7 @@ def _cache_get(key, factory):
     if fn is None:
         _JIT_CACHE_STATS["misses"] += 1
         _OBS_REGISTRY.counter("cache.misses", cache="jit").inc()
-        with _obs_span("compile", cache="jit", key=_key_label(key)):
-            fn = factory()
+        fn = factory()
         _JIT_CACHE[key] = fn
         _JIT_CACHE_HITS[key] = 0
         while len(_JIT_CACHE) > _JIT_CACHE_MAX:
@@ -671,6 +680,52 @@ def _cache_get(key, factory):
         _JIT_CACHE_HITS[key] = _JIT_CACHE_HITS.get(key, 0) + 1
         _JIT_CACHE.move_to_end(key)
     return fn
+
+
+# Backend compiles, counted from ``jax.monitoring``: every XLA compile (or
+# persistent-cache read) of a program ``jax.jit`` builds for a new function or
+# argument shape.  A jit-cache miss above only builds the ``jax.jit`` wrapper;
+# the compile happens on the wrapper's first call with each shape.
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_COMPILES = {"count": 0}
+
+
+def _on_compile_event(event: str, secs: float, **_kw) -> None:
+    if event == _BACKEND_COMPILE:
+        _COMPILES["count"] += 1
+        _OBS_REGISTRY.counter("jit.compiles").inc()
+        _OBS_REGISTRY.counter("jit.compile_seconds").inc(secs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def compile_count() -> int:
+    """Backend compiles in this process so far (whatever the obs switch)."""
+    return _COMPILES["count"]
+
+
+@contextmanager
+def upload_span(host):
+    """An ``upload`` span around one site's host-to-device puts of the
+    arrays in ``host``; ``bytes`` is the total of its NumPy arrays, what
+    the puts copy (arrays already on the device count nothing)."""
+    with _obs_span("upload") as s:
+        if s is not None:
+            s.attrs["bytes"] = sum(a.nbytes for a in jax.tree.leaves(host)
+                                   if isinstance(a, np.ndarray))
+        yield
+
+
+def launch(fn, *args):
+    """Call a jitted program under a ``launch`` span: the enqueue, plus any
+    trace and compile (``compiles``); the device runs on after it."""
+    c0 = _COMPILES["count"]
+    with _obs_span("launch") as s:
+        out = fn(*args)
+        if s is not None:
+            s.attrs["compiles"] = _COMPILES["count"] - c0
+    return out
 
 
 def _key_label(key) -> str:

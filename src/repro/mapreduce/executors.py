@@ -62,6 +62,7 @@ from repro.obs import EVENTS as _EVENTS
 from repro.obs import LEDGER as _LEDGER
 from repro.obs import REGISTRY as _REGISTRY_OBS
 from repro.obs import _config as _obs_config
+from repro.obs import span as _obs_span
 
 from . import engine as _engine
 from .engine import (
@@ -70,10 +71,12 @@ from .engine import (
     _as_tables,
     _cache_get,
     _shardings,
+    launch,
     run_reducers,
     run_reducers_bucketed,
     run_reducers_x2y,
     run_reducers_x2y_bucketed,
+    upload_span,
 )
 
 __all__ = [
@@ -156,21 +159,29 @@ class Executor:
         reuse their inverse-shuffle srcmap machinery restricted to the
         block), then zeroes global-diagonal cells to match the dense pair
         matrix's convention.  Works for every registry executor; override
-        only to specialize the routing."""
+        only to specialize the routing.  The sub-plan is taken under a
+        ``plan`` span, the rest under ``execute``, as ``pairwise_similarity``
+        does."""
         bx, by = i1 - i0, j1 - j0
-        sub = _engine.block_subplan(
-            sparse, i0, i1, j0, j1, pad_reducers_to=pad_reducers_to,
-            pad_slots_to=pad_slots_to, max_buckets=max_buckets)
-        if sub is None or bx == 0 or by == 0:
-            out = jnp.zeros((max(bx, 0), max(by, 0)), jnp.float32)
-        else:
-            out = self.run_x2y((x[i0:i1], x[j0:j1]), sub, reducer_fn,
-                               (bx, by), mesh=mesh, use_kernel=use_kernel,
-                               interpret=interpret)
-        lo, hi = max(i0, j0), min(i1, j1)
-        if lo < hi:  # the block crosses the global diagonal: zero it
-            d = jnp.arange(lo, hi)
-            out = out.at[d - i0, d - j0].set(0.0)
+        with _obs_span("plan", workload="block"):
+            sub = _engine.block_subplan(
+                sparse, i0, i1, j0, j1, pad_reducers_to=pad_reducers_to,
+                pad_slots_to=pad_slots_to, max_buckets=max_buckets)
+        with _obs_span("execute", workload="block",
+                       reducers=0 if sub is None else sub.num_reducers):
+            if sub is None or bx == 0 or by == 0:
+                out = jnp.zeros((max(bx, 0), max(by, 0)), jnp.float32)
+            else:
+                tables = launch(lambda: (x[i0:i1], x[j0:j1]))
+                out = self.run_x2y(tables, sub, reducer_fn, (bx, by),
+                                   mesh=mesh, use_kernel=use_kernel,
+                                   interpret=interpret)
+            lo, hi = max(i0, j0), min(i1, j1)
+            if lo < hi:  # the block crosses the global diagonal: zero it
+                def zero_diagonal(o):
+                    d = jnp.arange(lo, hi)
+                    return o.at[d - i0, d - j0].set(0.0)
+                out = launch(zero_diagonal, out)
         self._count("block_calls")
         return out
 
@@ -305,6 +316,22 @@ def _group_gram_entries(plan, cache_key, groups) -> int:
                 n += int(np.prod(i.shape[:2])) * i.shape[2] ** 2
         cache[cache_key] = n
     return n
+
+
+def _derived(plan, attr: str, key, what: str, build: Callable):
+    """A host artefact derived from ``plan``, built once and kept on the
+    plan (dict ``attr``, under ``key``): a static artifact reused across
+    waves, like the index matrix.  Looked up and built under a ``maps``
+    span (``what``, ``cached``)."""
+    cache = plan.__dict__.get(attr)
+    if cache is None:
+        cache = {}
+        object.__setattr__(plan, attr, cache)
+    value = cache.get(key)
+    with _obs_span("maps", what=what, cached=value is not None):
+        if value is None:
+            value = cache[key] = build()
+    return value
 
 
 _REGISTRY: dict[str, Executor] = {}
@@ -617,11 +644,11 @@ class FusedExecutor(Executor):
             lambda: _make_fused_jitted(metric, combine, mesh, shard_axes,
                                        use_kernel, interpret, bl,
                                        postprocess))
-        buckets = tuple(
-            (jnp.asarray(b.idx), jnp.asarray(b.mask),
-             jnp.asarray(_scatter_rows(b, plan.R)))
-            for b in plan.buckets)
-        return fn(inputs, buckets, postprocess_arg, plan.R, plan.L)
+        host = tuple((b.idx, b.mask, _scatter_rows(b, plan.R))
+                     for b in plan.buckets)
+        with upload_span(host):
+            buckets = jax.tree.map(jnp.asarray, host)
+        return launch(fn, inputs, buckets, postprocess_arg, plan.R, plan.L)
 
     def run_pairs(self, x, plan, reducer_fn, m, *, mesh=None,
                   use_kernel=False, interpret=False):
@@ -630,7 +657,9 @@ class FusedExecutor(Executor):
         # double-record the request
         self._reconcile(plan, "pairs", x,
                         measured_slots=_bucket_valid_slots(plan))
-        srcmap = jnp.asarray(_pair_source_map(plan, m))
+        host = _pair_source_map(plan, m)
+        with upload_span(host):
+            srcmap = jnp.asarray(host)
         return self.run(
             x, plan, reducer_fn, mesh=mesh,
             postprocess=_assemble_from_srcmap, postprocess_arg=srcmap,
@@ -659,18 +688,18 @@ class FusedExecutor(Executor):
             return assemble_x2y_matrix_bucketed(per_bucket, shape)
         uk = True if use_kernel else jax.default_backend() == "tpu"
         self._count("kernel" if uk else "streamed")
-        srcmap = jnp.asarray(_pair_source_map_rect(plan, *shape))
+        host_srcmap = _pair_source_map_rect(plan, *shape)
         fn = _cache_get(
             ("fused-x2y", metric, mesh, None, bool(uk), bool(interpret),
              bl),
             lambda: _make_fused_rect_jitted(metric, mesh, None, uk,
                                             interpret, bl))
-        buckets = tuple(
-            (jnp.asarray(b.idx), jnp.asarray(b.mask),
-             jnp.asarray(b.yidx), jnp.asarray(b.ymask))
-            for b in plan.buckets)
+        host = (tuple((b.idx, b.mask, b.yidx, b.ymask)
+                      for b in plan.buckets), host_srcmap)
+        with upload_span(host):
+            buckets, srcmap = jax.tree.map(jnp.asarray, host)
         xt, yt = _as_tables(tables)
-        return fn(xt, yt, buckets, srcmap)
+        return launch(fn, xt, yt, buckets, srcmap)
 
     def lower(self, input_shape, plan, *, reducer_fn=None, metric=None,
               mesh=None, dtype=jnp.float32, shard_axes=None,
@@ -1005,59 +1034,24 @@ class ShardedExecutor(Executor):
                   num_shards: int) -> PlanPartition:
         """The plan's LPT partition for ``num_shards`` (cached on the plan
         like the index matrix: a static artifact reused across waves)."""
-        cache = plan.__dict__.get("_shard_partition_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_shard_partition_cache", cache)
-        part = cache.get(num_shards)
-        if part is None:
-            part = partition_plan(plan, num_shards)
-            cache[num_shards] = part
-        return part
+        return _derived(plan, "_shard_partition_cache", num_shards,
+                        "partition", lambda: partition_plan(plan, num_shards))
 
     def _groups_for(self, plan, part):
-        cache = plan.__dict__.get("_shard_groups_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_shard_groups_cache", cache)
-        groups = cache.get(part.num_shards)
-        if groups is None:
-            groups = _stacked_groups(plan, part)
-            cache[part.num_shards] = groups
-        return groups
+        return _derived(plan, "_shard_groups_cache", part.num_shards,
+                        "groups", lambda: _stacked_groups(plan, part))
 
     def _srcmap_for(self, plan, groups, num_shards: int, m: int):
-        cache = plan.__dict__.get("_shard_srcmap_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_shard_srcmap_cache", cache)
-        srcmap = cache.get((num_shards, m))
-        if srcmap is None:
-            srcmap = _sharded_srcmap(groups, m)
-            cache[(num_shards, m)] = srcmap
-        return srcmap
+        return _derived(plan, "_shard_srcmap_cache", (num_shards, m),
+                        "srcmap", lambda: _sharded_srcmap(groups, m))
 
     def _rect_groups_for(self, plan, part):
-        cache = plan.__dict__.get("_shard_rect_groups_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_shard_rect_groups_cache", cache)
-        groups = cache.get(part.num_shards)
-        if groups is None:
-            groups = _stacked_rect_groups(plan, part)
-            cache[part.num_shards] = groups
-        return groups
+        return _derived(plan, "_shard_rect_groups_cache", part.num_shards,
+                        "groups", lambda: _stacked_rect_groups(plan, part))
 
     def _rect_srcmap_for(self, plan, groups, num_shards: int, shape):
-        cache = plan.__dict__.get("_shard_rect_srcmap_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_shard_rect_srcmap_cache", cache)
-        srcmap = cache.get((num_shards, shape))
-        if srcmap is None:
-            srcmap = _sharded_rect_srcmap(groups, shape)
-            cache[(num_shards, shape)] = srcmap
-        return srcmap
+        return _derived(plan, "_shard_rect_srcmap_cache", (num_shards, shape),
+                        "srcmap", lambda: _sharded_rect_srcmap(groups, shape))
 
     def _note(self, part: PlanPartition) -> None:
         self._stats["num_shards"] = part.num_shards
@@ -1090,11 +1084,8 @@ class ShardedExecutor(Executor):
                     plan, ("sharded", S), groups, count_y=False),
                 assembled_bytes=assembled, meta=meta)
         P = jax.sharding.PartitionSpec
-        if combine == "pairs":
-            srcmap = _put(self._srcmap_for(plan, groups, S, srcmap_m), mesh,
-                          P())
-        else:
-            srcmap = jnp.zeros((1,), jnp.int32)      # unused placeholder
+        host_srcmap = (self._srcmap_for(plan, groups, S, srcmap_m)
+                       if combine == "pairs" else None)
         if use_kernel is None:
             use_kernel = jax.default_backend() == "tpu"
         fn = _cache_get(
@@ -1102,9 +1093,12 @@ class ShardedExecutor(Executor):
              bool(interpret), bl),
             lambda: _make_sharded_jitted(metric, combine, mesh, axes,
                                          use_kernel, interpret, bl))
-        jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in g)
-                        for g in groups)
-        return fn(x, jgroups, srcmap, plan.R, plan.L)
+        with upload_span((groups, host_srcmap)):
+            jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in g)
+                            for g in groups)
+            srcmap = (_put(host_srcmap, mesh, P()) if combine == "pairs"
+                      else jnp.zeros((1,), jnp.int32))  # unused placeholder
+        return launch(fn, x, jgroups, srcmap, plan.R, plan.L)
 
     # -- protocol ----------------------------------------------------------
     def run(self, inputs, plan, reducer_fn, *, mesh=None, shard_axes=None,
@@ -1177,18 +1171,19 @@ class ShardedExecutor(Executor):
                 meta={"num_shards": S,
                       "assembly_bytes_per_shard": per_shard})
         P = jax.sharding.PartitionSpec
-        srcmap = _put(self._rect_srcmap_for(plan, groups, S, tuple(shape)),
-                      mesh, P())
+        host_srcmap = self._rect_srcmap_for(plan, groups, S, tuple(shape))
         uk = True if use_kernel else jax.default_backend() == "tpu"
         fn = _cache_get(
             ("sharded-x2y", metric, mesh, axes, bool(uk), bool(interpret),
              bl),
             lambda: _make_sharded_rect_jitted(metric, mesh, axes, uk,
                                               interpret, bl))
-        jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in grp)
-                        for grp in groups)
+        with upload_span((groups, host_srcmap)):
+            srcmap = _put(host_srcmap, mesh, P())
+            jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in grp)
+                            for grp in groups)
         xt, yt = _as_tables(tables)
-        return fn(xt, yt, jgroups, srcmap)
+        return launch(fn, xt, yt, jgroups, srcmap)
 
     def lower(self, input_shape, plan, *, reducer_fn=None, metric=None,
               mesh=None, dtype=jnp.float32, shard_axes=None,
@@ -1417,45 +1412,28 @@ class CodedExecutor(ShardedExecutor):
                         replication: Optional[int] = None) -> PlanPartition:
         r = min(self.replication if replication is None else int(replication),
                 num_shards)
-        cache = plan.__dict__.get("_coded_partition_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_coded_partition_cache", cache)
-        part = cache.get((num_shards, r))
-        if part is None:
-            part = partition_plan(plan, num_shards, replication=r)
-            cache[(num_shards, r)] = part
-        return part
+        return _derived(
+            plan, "_coded_partition_cache", (num_shards, r), "partition",
+            lambda: partition_plan(plan, num_shards, replication=r))
 
     def _coded_groups_for(self, plan, part, rect: bool):
-        cache = plan.__dict__.get("_coded_groups_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_coded_groups_cache", cache)
-        key = (part.num_shards, part.replication, rect)
-        groups = cache.get(key)
-        if groups is None:
+        def build():
             if rect:
-                groups = _stacked_rect_groups(
+                return _stacked_rect_groups(
                     plan, part, rows_by_shard=part.replica_rows)
-            else:
-                groups = [(i, k, i, k, r) for i, k, r in _stacked_groups(
-                    plan, part, rows_by_shard=part.replica_rows)]
-            cache[key] = groups
-        return groups
+            return [(i, k, i, k, r) for i, k, r in _stacked_groups(
+                plan, part, rows_by_shard=part.replica_rows)]
+        return _derived(plan, "_coded_groups_cache",
+                        (part.num_shards, part.replication, rect), "groups",
+                        build)
 
     def _coded_maps_for(self, plan, groups, part, shape, zero_diag: bool):
-        cache = plan.__dict__.get("_coded_maps_cache")
-        if cache is None:
-            cache = {}
-            object.__setattr__(plan, "_coded_maps_cache", cache)
-        key = (part.num_shards, part.replication, tuple(shape), zero_diag)
-        maps = cache.get(key)
-        if maps is None:
-            rb = -(-shape[0] // part.num_shards)
-            maps = _coded_maps(groups, tuple(shape), rb, zero_diag)
-            cache[key] = maps
-        return maps
+        rb = -(-shape[0] // part.num_shards)
+        return _derived(
+            plan, "_coded_maps_cache",
+            (part.num_shards, part.replication, tuple(shape), zero_diag),
+            "coded_maps",
+            lambda: _coded_maps(groups, tuple(shape), rb, zero_diag))
 
     def _note_coded(self, part: PlanPartition, mstats: dict) -> None:
         self._note(part)
@@ -1509,10 +1487,13 @@ class CodedExecutor(ShardedExecutor):
             lambda: _make_coded_jitted(metric, mesh, axes, use_kernel,
                                        interpret, bl))
         P = jax.sharding.PartitionSpec
-        jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in g[:4])
-                        for g in groups)
-        out = fn(xt, yt, jgroups, _put(sendmap, mesh, P(axes)),
-                 _put(srcmap, mesh, P(axes)))
+        host = (tuple(g[:4] for g in groups), sendmap, srcmap)
+        with upload_span(host):
+            jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in g)
+                            for g in host[0])
+            jsend = _put(sendmap, mesh, P(axes))
+            jsrc = _put(srcmap, mesh, P(axes))
+        out = launch(fn, xt, yt, jgroups, jsend, jsrc)
         return out[:shape[0]]
 
     # -- protocol ----------------------------------------------------------

@@ -2,20 +2,31 @@
 (DESIGN.md 1j).
 
 ``span("plan")`` / ``span("execute", executor="fused")`` wrap the phases of
-a request — plan -> compile -> gather/kernel -> assemble — with parent
-nesting tracked per thread, so a ``PairwiseService.similarity`` call
-produces a small tree: the request span at the root, the planner and
-executor phases under it, jit-cache compiles under those.  Completed spans
-land in a bounded ring buffer (serving loops never grow memory);
-``chrome_trace()`` renders them in the Chrome trace-event format, so
-``export_chrome_trace("trace.json")`` loads directly in ``chrome://tracing``
-or https://ui.perfetto.dev.
+a request with parent nesting tracked per thread, so a
+``PairwiseService.similarity`` call produces a small tree (DESIGN.md 1j)::
+
+    request                      compiles
+    ├── upload                   bytes       (the table)
+    ├── plan
+    │   └── lower                cached      (the reducer plan / sub-plan)
+    └── execute
+        ├── maps                 what, cached (source map, partition, ...)
+        ├── upload               bytes       (plan arrays, source map)
+        └── launch               compiles    (the jitted program's call)
+
+Spans time host work only: none of them waits for the device.  Completed
+spans land in a bounded ring buffer (serving loops never grow memory);
+spans the full ring pushes out are counted in ``dropped`` and the
+``obs.spans_dropped`` counter.  ``chrome_trace()`` renders the ring in the
+Chrome trace-event format, so ``export_chrome_trace("trace.json")`` loads
+directly in ``chrome://tracing`` or https://ui.perfetto.dev.
 
 ``Tracer(annotate=True)`` (or ``REPRO_OBS_XPROF=1``) additionally enters a
-``jax.profiler.TraceAnnotation`` for every span, so the host-side phases
-line up with XLA device traces when a jax profile is being captured.  The
-jax import is lazy and optional — the obs layer stays importable without
-jax (zero-dependency contract).
+``jax.profiler.TraceAnnotation`` for every span, its attributes attached as
+metadata when it closes, so the host-side phases line up with XLA device
+traces when a jax profile is being captured.  The jax import is lazy and
+optional — the obs layer stays importable without jax (zero-dependency
+contract).
 
 Overhead: a span is two ``perf_counter`` calls, a dataclass, and a deque
 append; disabled (``repro.obs.configure(enabled=False)``) it is a single
@@ -35,6 +46,7 @@ from contextlib import contextmanager
 from typing import Optional
 
 from . import _config
+from .metrics import REGISTRY
 
 __all__ = ["Span", "Tracer", "TRACER", "span"]
 
@@ -55,11 +67,13 @@ class Span:
 class Tracer:
     """Ring-buffered span collector with per-thread parent nesting."""
 
-    def __init__(self, capacity: int = 4096, annotate: Optional[bool] = None):
+    def __init__(self, capacity: int = 65536,
+                 annotate: Optional[bool] = None):
         if annotate is None:
             annotate = os.environ.get("REPRO_OBS_XPROF", "") not in ("", "0")
         self.annotate = bool(annotate)
         self._spans: deque = deque(maxlen=capacity)
+        self.dropped = 0             # spans the full ring pushed out
         self._ids = itertools.count(1)
         self._local = threading.local()
 
@@ -95,10 +109,16 @@ class Tracer:
         try:
             yield s
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
             s.duration = time.perf_counter() - s.start
+            if ann is not None:
+                if s.attrs:
+                    ann.set_metadata(**{k: _jsonable(v)
+                                        for k, v in s.attrs.items()})
+                ann.__exit__(None, None, None)
             stack.pop()
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
+                REGISTRY.counter("obs.spans_dropped").inc()
             self._spans.append(s)
 
     # ------------------------------------------------------------- queries
@@ -107,7 +127,9 @@ class Tracer:
         return list(self._spans)
 
     def clear(self) -> None:
+        """Empty the ring and zero ``dropped``."""
         self._spans.clear()
+        self.dropped = 0
 
     def chrome_trace(self) -> dict:
         """The ring as a Chrome trace-event JSON object (``ph: "X"``

@@ -32,6 +32,7 @@ recompute-fraction, dirty-reducer, and gap-drift telemetry per edit
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Optional
@@ -239,8 +240,8 @@ class PairwiseService:
             "anomaly": rec.anomaly,
         }
 
-    def _info(self, plan, dt: float, snap: dict,
-              workload: str = "pairs") -> dict:
+    def _info(self, plan, dt: float, snap: dict, workload: str,
+              compiles: int) -> dict:
         after = self._snap()
         delta = {k: after[k] - snap[k] for k in snap}
         from repro.mapreduce import jit_cache_stats
@@ -273,6 +274,7 @@ class PairwiseService:
             "plan_cache_hit": delta["plan_hits"] > 0,
             "fused_path": fused_path,
             "jit_cache": jit_cache_stats(),
+            "compiles": compiles,
             "wall_s": dt,
         }
         comm = self._comm_info(snap)
@@ -298,37 +300,57 @@ class PairwiseService:
             }
         return info
 
+    @contextlib.contextmanager
+    def _request(self, workload: str):
+        """The ``request`` span around one request.  Yields a dict that
+        holds, once the block exits, the backend compiles the request
+        caused (``compiles``); the span carries them too."""
+        from repro.mapreduce import compile_count
+        c0 = compile_count()
+        req: dict = {}
+        with _obs_span("request", workload=workload,
+                       executor=self.executor, tenant=self.tenant) as s:
+            yield req
+            req["compiles"] = compile_count() - c0
+            if s is not None:
+                s.attrs["compiles"] = req["compiles"]
+
+    @staticmethod
+    def _upload(x):
+        """A request's table on the device, under an ``upload`` span."""
+        from repro.mapreduce.engine import upload_span
+        with upload_span(x):
+            return jnp.asarray(x)
+
     def similarity(self, x, weights=None):
         """All-pairs similarity for one query table.  Returns (sims, info)."""
         from repro.mapreduce.allpairs import pairwise_similarity
         snap = self._snap()
         t0 = time.perf_counter()
-        with _obs_span("request", workload="pairs",
-                       executor=self.executor, tenant=self.tenant):
+        with self._request("pairs") as req:
             sims, plan, _schema = pairwise_similarity(
-                jnp.asarray(x), q=self.q, weights=weights,
+                self._upload(x), q=self.q, weights=weights,
                 metric=self.metric, mesh=self.mesh,
                 executor=self._executor, use_kernel=self.use_kernel,
                 interpret=self.interpret)
             sims = jax.block_until_ready(sims)
         return sims, self._info(plan, time.perf_counter() - t0, snap,
-                                workload="pairs")
+                                "pairs", req["compiles"])
 
     def some_pairs(self, x, pairs, weights=None):
         """Similarity restricted to an explicit required-pair set."""
         from repro.mapreduce.allpairs import some_pairs_similarity
         snap = self._snap()
         t0 = time.perf_counter()
-        with _obs_span("request", workload="some_pairs",
-                       executor=self.executor, tenant=self.tenant):
+        with self._request("some_pairs") as req:
             sims, plan, _schema = some_pairs_similarity(
-                jnp.asarray(x), pairs, q=self.q, weights=weights,
+                self._upload(x), pairs, q=self.q, weights=weights,
                 metric=self.metric, mesh=self.mesh,
                 executor=self._executor, use_kernel=self.use_kernel,
                 interpret=self.interpret)
             sims = jax.block_until_ready(sims)
         return sims, self._info(plan, time.perf_counter() - t0, snap,
-                                workload="some_pairs")
+                                "some_pairs", req["compiles"])
 
     def x2y(self, x, y, wx=None, wy=None):
         """Cross similarity of an X table against a Y table through the
@@ -339,16 +361,15 @@ class PairwiseService:
         from repro.mapreduce.allpairs import x2y_similarity
         snap = self._snap()
         t0 = time.perf_counter()
-        with _obs_span("request", workload="x2y",
-                       executor=self.executor, tenant=self.tenant):
+        with self._request("x2y") as req:
             sims, plan, _schema = x2y_similarity(
-                jnp.asarray(x), jnp.asarray(y), q=self.q, wx=wx, wy=wy,
+                self._upload(x), self._upload(y), q=self.q, wx=wx, wy=wy,
                 metric=self.metric, mesh=self.mesh,
                 executor=self._executor, use_kernel=self.use_kernel,
                 interpret=self.interpret)
             sims = jax.block_until_ready(sims)
         return sims, self._info(plan, time.perf_counter() - t0, snap,
-                                workload="x2y")
+                                "x2y", req["compiles"])
 
     @property
     def padding_savings(self) -> float:
@@ -403,10 +424,9 @@ class PairwiseService:
         assert getattr(self, "_block_table", None) is not None, \
             "call load_block_table() first"
         t0 = time.perf_counter()
-        with _obs_span("request", workload="block",
-                       executor=self.executor, tenant=self.tenant):
+        with self._request("block") as req:
             blk = self._executor.run_block(
-                jnp.asarray(self._block_table), self._block_sparse,
+                self._upload(self._block_table), self._block_sparse,
                 _block_fn_x2y(self.metric), int(i0), int(i1), int(j0),
                 int(j1), mesh=self.mesh, use_kernel=self.use_kernel,
                 interpret=self.interpret)
@@ -424,6 +444,7 @@ class PairwiseService:
             "executor": self.executor,
             "block": (int(i0), int(i1), int(j0), int(j1)),
             "block_calls": self._executor.stats().get("block_calls", 0),
+            "compiles": req["compiles"],
             "wall_s": dt,
         }
 
@@ -471,9 +492,8 @@ class PairwiseService:
             pad_reducers_to=(self.mesh.devices.size
                              if self.mesh is not None else 1))
         plan = self._planner.plan()
-        with _obs_span("request", workload="load_table",
-                       executor=self.executor, tenant=self.tenant):
-            sims = ex.run_pairs(jnp.asarray(self._table), plan,
+        with self._request("load_table"):
+            sims = ex.run_pairs(self._upload(self._table), plan,
                                 self._reducer_fn(), m, mesh=self.mesh,
                                 use_kernel=self.use_kernel,
                                 interpret=self.interpret)
@@ -517,7 +537,7 @@ class PairwiseService:
                        tenant=self.tenant):
             delta = getattr(self._planner, op)(*args)
             sims = ex.apply_delta(
-                jnp.asarray(self._table), delta, self._reducer_fn(),
+                self._upload(self._table), delta, self._reducer_fn(),
                 self._table.shape[0], plan_provider=self._planner.plan,
                 mesh=self.mesh, use_kernel=self.use_kernel,
                 interpret=self.interpret)

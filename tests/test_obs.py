@@ -173,6 +173,227 @@ def test_service_request_trace_exports(tmp_path):
         assert ev["ph"] == "X" and ev["dur"] >= 0
 
 
+# ------------------------------------------------------- host-path spans
+def _tree(spans):
+    """``{span_id: "request > execute > maps", ...}`` paths of the ring."""
+    byid = {s.span_id: s for s in spans}
+
+    def path(s):
+        names = []
+        while s is not None:
+            names.append(s.name)
+            s = byid.get(s.parent_id)
+        return " > ".join(reversed(names))
+    return {s.span_id: path(s) for s in spans}
+
+
+def _by_path(spans):
+    paths = _tree(spans)
+    out: dict = {}
+    for s in spans:
+        out.setdefault(paths[s.span_id], []).append(s)
+    return out
+
+
+def _block_service():
+    from repro.serve import PairwiseService
+    rng = np.random.default_rng(3)
+    table = rng.normal(size=(600, 8)).astype(np.float32)
+    svc = PairwiseService(q=1.0, executor="fused")
+    svc.load_block_table(table, weights=np.full(600, 0.02))
+    return svc, table
+
+
+@pytest.mark.parametrize("kind", ["similarity", "block"])
+def test_request_span_tree(kind):
+    """A fused request splits into request > plan > lower and request >
+    execute > {maps, upload, launch}, with the documented attributes."""
+    from repro.serve import PairwiseService
+    if kind == "similarity":
+        x, w = _zipf_table()
+        svc = PairwiseService(q=1.0, executor="fused")
+        TRACER.clear()
+        svc.similarity(x, weights=w)
+        what = "pairs"
+    else:
+        svc, _table = _block_service()
+        TRACER.clear()
+        svc.block(0, 96, 200, 296)
+        what = "block"
+    got = _by_path(TRACER.spans())
+    (req,) = got["request"]
+    assert req.attrs["workload"] == what
+    assert isinstance(req.attrs["compiles"], int)
+    (table_up,) = got["request > upload"]
+    assert table_up.attrs["bytes"] > 0
+    (lower,) = got["request > plan > lower"]
+    assert lower.attrs["cached"] is False
+    assert len(got["request > execute"]) == 1
+    (maps,) = got["request > execute > maps"]
+    assert maps.attrs == {"what": "srcmap", "cached": False}
+    assert got["request > execute > upload"]
+    assert all(s.attrs["bytes"] > 0
+               for s in got["request > execute > upload"])
+    # the kernel's program; a block's slices of the table are one more
+    launches = got["request > execute > launch"]
+    assert len(launches) == (1 if kind == "similarity" else 2)
+    assert all(isinstance(s.attrs["compiles"], int) for s in launches)
+    # every span of the request is in the tree above, and at most ten
+    assert set(got) == {
+        "request", "request > upload", "request > plan",
+        "request > plan > lower", "request > execute",
+        "request > execute > maps", "request > execute > upload",
+        "request > execute > launch"}
+    assert len(TRACER.spans()) <= 10
+    # spans time host work: children fit inside their parent
+    parent = {s.span_id: s for s in TRACER.spans()}
+    for s in TRACER.spans():
+        if s.parent_id is not None:
+            assert s.duration <= parent[s.parent_id].duration
+
+
+def test_same_schema_twice_reads_cached():
+    """Passing one ``schema`` object twice finds its plan and source map
+    on the second request: ``lower`` and ``maps`` read ``cached=True``."""
+    x, w = _zipf_table()
+    schema = plan_a2a(w, 1.0)
+    for want in (False, True):
+        TRACER.clear()
+        pairwise_similarity(x, q=1.0, schema=schema, executor="fused")
+        got = _by_path(TRACER.spans())
+        (lower,) = got["plan > lower"]
+        (maps,) = got["execute > maps"]
+        assert lower.attrs["cached"] is want
+        assert maps.attrs["cached"] is want
+
+
+def test_block_repeat_reads_cached():
+    """A block served twice takes its sub-plan and source map from the
+    caches the second time."""
+    svc, _table = _block_service()
+    svc.block(0, 96, 200, 296)
+    TRACER.clear()
+    svc.block(0, 96, 200, 296)
+    got = _by_path(TRACER.spans())
+    assert got["request > plan > lower"][0].attrs["cached"] is True
+    assert got["request > execute > maps"][0].attrs["cached"] is True
+
+
+def test_upload_bytes_equal_the_arrays_put():
+    """The ``upload`` spans' ``bytes`` add up to the host arrays a request
+    puts, counted here from the table and the plan: the table, each
+    bucket's slot ids, masks and scatter rows (int32, bool, int32), and
+    the (m, m) int32 source map."""
+    from repro.serve import PairwiseService
+    x, w = _zipf_table()
+    m, d = x.shape
+    svc = PairwiseService(q=1.0, executor="fused")
+    TRACER.clear()
+    _sims, info = svc.similarity(x, weights=w)
+    plan = mr_engine.build_plan(plan_a2a(w, 1.0))
+    want = m * d * 4 + m * m * 4 + sum(
+        b.idx.shape[0] * b.idx.shape[1] * (4 + 1) + b.idx.shape[0] * 4
+        for b in plan.buckets)
+    got = [s.attrs["bytes"] for s in TRACER.spans() if s.name == "upload"]
+    assert len(got) == 3
+    assert sum(got) == want
+
+    # a block: the table, each rect bucket's X and Y ids and masks, and
+    # the (bx, by) int32 source map
+    svc, table = _block_service()
+    i0, i1, j0, j1 = 0, 96, 200, 296
+    TRACER.clear()
+    svc.block(i0, i1, j0, j1)
+    sub = mr_engine.block_subplan(svc._block_sparse, i0, i1, j0, j1)
+    want = table.size * 4 + (i1 - i0) * (j1 - j0) * 4 + sum(
+        b.idx.size * 5 + b.yidx.size * 5 for b in sub.buckets)
+    got = [s.attrs["bytes"] for s in TRACER.spans() if s.name == "upload"]
+    assert len(got) == 2
+    assert sum(got) == want
+
+
+def test_coded_spans_name_each_map():
+    """The coded executor's host maps each get a ``maps`` span, and its
+    one upload site puts the groups, the send map and the source map."""
+    from repro.mapreduce import make_executor
+    x, w = _zipf_table()
+    m = x.shape[0]
+    TRACER.clear()
+    _sims, plan, _schema = pairwise_similarity(
+        x, q=1.0, weights=w, executor=make_executor("coded", replication=1))
+    got = _by_path(TRACER.spans())
+    whats = [s.attrs["what"] for s in got["execute > maps"]]
+    assert whats == ["partition", "groups", "coded_maps"]
+    (groups,) = plan.__dict__["_coded_groups_cache"].values()
+    ((sendmap, srcmap, _stats),) = plan.__dict__["_coded_maps_cache"].values()
+    assert srcmap.nbytes == m * m * 4          # one shard owns every row
+    (up,) = got["execute > upload"]
+    assert up.attrs["bytes"] == sendmap.nbytes + srcmap.nbytes + sum(
+        a.nbytes for g in groups for a in g[:4])
+    assert len(got["execute > launch"]) == 1
+
+
+def test_request_compiles_first_shape_then_none():
+    """``compiles`` on the request span (and in ``info``) counts backend
+    compiles: above 0 for a table shape this process has not served, 0
+    when the same request comes again."""
+    from repro.serve import PairwiseService
+    x, w = _zipf_table(m=43, d=13)
+    svc = PairwiseService(q=1.0, executor="fused")
+    seen = []
+    for _ in range(2):
+        TRACER.clear()
+        _sims, info = svc.similarity(x, weights=w)
+        (req,) = [s for s in TRACER.spans() if s.name == "request"]
+        assert req.attrs["compiles"] == info["compiles"]
+        seen.append(info["compiles"])
+    assert seen[0] > 0 and seen[1] == 0
+    assert REGISTRY.counter_total("jit.compiles") >= seen[0]
+
+
+def test_tracer_counts_dropped_spans():
+    """A full ring counts what it pushes out (``dropped`` and the
+    ``obs.spans_dropped`` counter); ``clear`` starts the count anew."""
+    tr = obs.Tracer(capacity=3, annotate=False)
+    for i in range(5):
+        with tr.span("s", i=i):
+            pass
+    assert [s.attrs["i"] for s in tr.spans()] == [2, 3, 4]
+    assert tr.dropped == 2
+    assert REGISTRY.counter_total("obs.spans_dropped") == 2
+    tr.clear()
+    assert tr.dropped == 0
+    assert obs.Tracer()._spans.maxlen == 65536
+
+
+def test_span_attributes_reach_the_profiler(monkeypatch):
+    """Under ``annotate=True`` each span enters a ``TraceAnnotation`` and
+    hands it the span's attributes, late ones included, as metadata."""
+    import jax.profiler
+    made = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            self.name, self.meta = name, dict(kw)
+            made.append(self)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kw):
+            self.meta.update(kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    tr = obs.Tracer(annotate=True)
+    with tr.span("upload", bytes=12) as s:
+        s.attrs["cached"] = True
+    assert [(r.name, r.meta) for r in made] == \
+        [("upload", {"bytes": 12, "cached": True})]
+
+
 # ------------------------------------------------------------- comm ledger
 def test_reconciler_dense_exact():
     """Dense executor: measured == planned shuffle exactly (ratio 1.0,
