@@ -12,7 +12,8 @@ Public planner API
     All-pairs mapping schema.  ``method='auto'`` runs the strategy-registry
     portfolio: every applicable strategy is costed with an exact closed-form
     estimate and only the argmin winner is materialized.  Results are
-    memoized in ``PLAN_CACHE`` by the (sorted-weights, q, method) profile.
+    memoized in ``PLAN_CACHE`` by the (sorted-weights, q, method) profile;
+    a repeat of the same weight vector returns the same schema object.
 ``plan_x2y(wx, wy, q)``
     Bipartite (X-to-Y) mapping schema, Section 10.
 ``plan_some_pairs(weights, q, pairs)``

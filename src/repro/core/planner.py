@@ -29,7 +29,8 @@ so plans self-report their optimality gap.
 
 Results are memoized in ``strategies.PLAN_CACHE`` keyed by the
 (sorted-weights, q, method) profile; permutations of the same weight
-multiset share one cache entry.
+multiset share one cache entry.  A small memo beside it hands a repeat of
+the same literal weight vector the same remapped schema object.
 """
 
 from __future__ import annotations
@@ -114,7 +115,10 @@ def plan_a2a(weights: Sequence[float], q: float, method: str = "auto",
 
     Treat the returned schema as immutable: cache hits share their reducer
     lists with the ``PLAN_CACHE`` entry (copying them would defeat the O(m)
-    hit path), so mutating ``schema.reducers``/``schema.bins`` in place
+    hit path), and a repeat of the same weight vector (same values in the
+    same order, same ``q`` and ``method``) returns the very same schema
+    object, so that the plan and maps memoized on it are found again.
+    Mutating ``schema.bins``/``schema.reducers``/``schema.meta`` in place
     would poison every future plan for the same weight profile.  Pass
     ``use_cache=False`` to get a schema with no shared state.
     """
@@ -134,7 +138,17 @@ def plan_a2a(weights: Sequence[float], q: float, method: str = "auto",
         schema_s = _plan_a2a_sorted(ws, q, method, use_cache)
         if use_cache:
             PLAN_CACHE.put(key, schema_s)
-    return _remap_schema(schema_s, order, w)
+    if not use_cache:
+        return _remap_schema(schema_s, order, w)
+    # the same literal weights get the same remapped object back
+    literal = PlanCache.key(w, q, method)
+    schema = PLAN_CACHE.get_schema(literal)
+    if schema is None:
+        w = w.copy()                    # the memo must not alias the caller
+        w.flags.writeable = False
+        schema = _remap_schema(schema_s, order, w)
+        PLAN_CACHE.put_schema(literal, key, schema)
+    return schema
 
 
 def _remap_schema(schema: MappingSchema, order: np.ndarray,
@@ -382,8 +396,11 @@ def plan_some_pairs(weights: Sequence[float], q: float, pairs,
     winner, est = min(candidates, key=lambda c: c[1])
 
     if winner == "a2a":
-        schema = plan_a2a(w, q)
-        schema.algorithm = f"some-pairs:a2a:{schema.algorithm}"
+        # a copy: plan_a2a's schema may be shared, and its fields change
+        base = plan_a2a(w, q)
+        schema = dataclasses.replace(
+            base, algorithm=f"some-pairs:a2a:{base.algorithm}",
+            meta=dict(base.meta))
     elif winner == "sparse":
         bins, cross, lone, _ = sparse
         reducers = [[int(a), int(b)] for a, b in cross]

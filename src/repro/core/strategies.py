@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import math
 from collections import OrderedDict
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, is_dataclass
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -60,12 +60,54 @@ __all__ = [
     "A2AProfile",
     "PlanCache",
     "PLAN_CACHE",
+    "SCHEMA_MEMO_SIZE",
+    "SCHEMA_MEMO_BYTES",
+    "schema_host_bytes",
 ]
 
 
 # ===========================================================================
 # plan cache
 # ===========================================================================
+# Remapped schemas kept by literal weight order (``PlanCache.get_schema``).
+# Each keeps its lowered plan and derived host maps alive (~170 MB at
+# m=4096, over 3.8 GB coded at m=10240 on 4 shards), so the memo is bounded
+# both in schemas and in the host bytes memoized on them.  The schema
+# used last is kept whatever it holds: it holds one request's maps.
+SCHEMA_MEMO_SIZE = 8
+SCHEMA_MEMO_BYTES = 1 << 30
+
+
+def _host_nbytes(obj, seen: set) -> int:
+    """Bytes of the NumPy arrays reachable from ``obj`` through dicts,
+    lists, tuples and dataclass instances, each object counted once."""
+    if isinstance(obj, np.ndarray):
+        if id(obj) in seen:
+            return 0
+        seen.add(id(obj))
+        return obj.nbytes
+    if isinstance(obj, dict):
+        items = obj.values()
+    elif isinstance(obj, (list, tuple)):
+        items = obj
+    elif is_dataclass(obj) and not isinstance(obj, type):
+        items = vars(obj).values()
+    else:
+        return 0
+    if id(obj) in seen:
+        return 0
+    seen.add(id(obj))
+    return sum(_host_nbytes(v, seen) for v in items)
+
+
+def schema_host_bytes(schema) -> int:
+    """Host bytes memoized on ``schema``: the arrays under its private
+    attributes (``_plan_for``'s plans and the maps derived on them)."""
+    seen: set = set()
+    return sum(_host_nbytes(v, seen) for k, v in vars(schema).items()
+               if k.startswith("_"))
+
+
 class PlanCache:
     """LRU cache keyed by the (sorted-weights, q, method) profile.
 
@@ -73,11 +115,21 @@ class PlanCache:
     schema in canonical (descending-weight) order and the cache stores that
     canonical schema, so permutations of the same weights hit the same entry
     and are remapped to the caller's input order in O(m).
+
+    A second, smaller LRU keeps the remapped schema itself, keyed by the
+    caller's literal weights: a repeat of one weight vector gets the same
+    object back, so everything memoized on it (the lowered plan, source and
+    coded maps) is found again.  After each lookup it keeps at most
+    ``SCHEMA_MEMO_SIZE`` schemas holding at most ``SCHEMA_MEMO_BYTES`` of
+    host arrays, evicting the least recently used; the schema just looked
+    up stays whatever it holds.  Each remapped schema belongs to its
+    canonical entry and leaves with it.
     """
 
     def __init__(self, maxsize: int = 128):
         self.maxsize = maxsize
         self._store: OrderedDict = OrderedDict()
+        self._schemas: OrderedDict = OrderedDict()  # literal -> (key, schema)
         self.hits = 0
         self.misses = 0
         self.evictions = 0
@@ -101,13 +153,58 @@ class PlanCache:
         self._store[key] = value
         self._store.move_to_end(key)
         while len(self._store) > self.maxsize:
-            self._store.popitem(last=False)
+            old, _ = self._store.popitem(last=False)
+            self._drop_schemas(old)
             self.evictions += 1
             _OBS_REGISTRY.counter("cache.evictions", cache="plan").inc()
             _OBS_EVENTS.emit("cache_eviction", cache="plan")
 
+    def get_schema(self, literal_key: tuple):
+        """The remapped schema kept for these literal weights, or None."""
+        entry = self._schemas.get(literal_key)
+        if entry is None:
+            _OBS_REGISTRY.counter("cache.misses", cache="schema").inc()
+            return None
+        _OBS_REGISTRY.counter("cache.hits", cache="schema").inc()
+        self._schemas.move_to_end(literal_key)
+        self._trim_schemas()
+        return entry[1]
+
+    def put_schema(self, literal_key: tuple, key: tuple, schema) -> None:
+        """Keep ``schema``, remapped from canonical entry ``key``, under
+        the caller's literal weights; no-op when ``key`` is not cached."""
+        if key not in self._store:
+            return
+        self._schemas[literal_key] = (key, schema)
+        self._schemas.move_to_end(literal_key)
+        self._trim_schemas()
+
+    def _trim_schemas(self) -> None:
+        """Evict the least recently used schemas until the memo is within
+        its count and its host bytes, keeping the most recent one."""
+        if len(self._schemas) <= 1:
+            return
+        held = OrderedDict((lk, schema_host_bytes(s))
+                           for lk, (_, s) in self._schemas.items())
+        total = sum(held.values())
+        while len(held) > 1 and (len(held) > SCHEMA_MEMO_SIZE
+                                 or total > SCHEMA_MEMO_BYTES):
+            lk, nbytes = held.popitem(last=False)
+            del self._schemas[lk]
+            total -= nbytes
+            _OBS_REGISTRY.counter("cache.evictions", cache="schema").inc()
+
+    def schema_bytes(self) -> int:
+        """Host bytes memoized on the schemas the memo keeps."""
+        return sum(schema_host_bytes(s) for _, s in self._schemas.values())
+
+    def _drop_schemas(self, key: tuple) -> None:
+        for lk in [lk for lk, (k, _) in self._schemas.items() if k == key]:
+            del self._schemas[lk]
+
     def invalidate(self, key: tuple) -> bool:
-        """Drop one entry (the streaming gap-drift re-plan path: a serving
+        """Drop one entry and the remapped schemas kept for it (the
+        streaming gap-drift re-plan path: a serving
         stream that re-plans has permanently moved off its previous weight
         profile, so that profile's entry is dead weight in the LRU and would
         otherwise push live request-serving profiles out).  Returns whether
@@ -115,20 +212,24 @@ class PlanCache:
         tracks capacity pressure only."""
         if self._store.pop(key, None) is None:
             return False
+        self._drop_schemas(key)
         self.invalidations += 1
         _OBS_REGISTRY.counter("cache.invalidations", cache="plan").inc()
         return True
 
     def stats(self) -> dict:
         """Counter snapshot: hits / misses / capacity evictions / explicit
-        invalidations, plus current size and cap."""
+        invalidations, plus current size and cap, and the number of
+        remapped schemas kept."""
         return {"hits": self.hits, "misses": self.misses,
                 "evictions": self.evictions,
                 "invalidations": self.invalidations,
-                "size": len(self._store), "maxsize": self.maxsize}
+                "size": len(self._store), "maxsize": self.maxsize,
+                "schemas": len(self._schemas)}
 
     def clear(self) -> None:
         self._store.clear()
+        self._schemas.clear()
         self.hits = self.misses = 0
         self.evictions = self.invalidations = 0
 

@@ -137,7 +137,8 @@ class PairwiseService:
 
     Each query brings its own input table (and optionally per-input sizes);
     the service plans a mapping schema via the registry planner — repeated
-    weight profiles hit ``repro.core.PLAN_CACHE`` and skip planning — and
+    weight profiles hit ``repro.core.PLAN_CACHE`` and skip planning, and a
+    repeated weight vector also finds its lowered plan and maps — and
     executes it on any executor-registry entry ("dense" / "bucketed" /
     "fused" / "sharded" / "coded" / "streaming"); the default bucketed
     path keeps skewed profiles from paying the dense global-max padding.

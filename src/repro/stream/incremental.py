@@ -152,10 +152,12 @@ class IncrementalPlanner(StreamPlannerBase):
         if old_key is not None and old_key != self._cache_key:
             # this stream has permanently moved off its previous profile
             PLAN_CACHE.invalidate(old_key)
-        # bins from _remap_schema are fresh lists; the outer reducers list
-        # is shallow-copied so appends stay private, and existing inner
-        # reducer lists are never mutated (repairs touch bins, or append
-        # brand-new reducer lists) — the PLAN_CACHE entry stays clean.
+        # plan_a2a's schema is shared (a repeat of these weights returns
+        # the same object), so both levels are copied here: bins into new
+        # lists over table ids, the outer reducers list shallow-copied so
+        # appends stay private; existing inner reducer lists are never
+        # mutated (repairs touch bins, or append brand-new reducer lists)
+        # — the PLAN_CACHE entry and the shared schema stay clean.
         self._adopt_schema_state(
             schema, [[int(ids[i]) for i in b] for b in schema.bins],
             list(schema.reducers))

@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 import repro.obs as obs
-from repro.core import plan_a2a
+from repro.core import PLAN_CACHE, plan_a2a
 from repro.mapreduce import engine as mr_engine
 from repro.mapreduce import get_executor, pairwise_similarity
 from repro.obs import EVENTS, LEDGER, REGISTRY, TRACER
@@ -46,6 +46,12 @@ def _fresh_obs():
     yield
     obs.reset_all()
     obs.configure(enabled=True)
+
+
+def _cold_plans():
+    """Drop the plan cache and its remapped schemas, so a request on a
+    profile an earlier test served plans and lowers anew."""
+    PLAN_CACHE.clear()
 
 
 def _zipf_table(m=64, d=8, q=1.0, seed=0):
@@ -209,6 +215,7 @@ def test_request_span_tree(kind):
     """A fused request splits into request > plan > lower and request >
     execute > {maps, upload, launch}, with the documented attributes."""
     from repro.serve import PairwiseService
+    _cold_plans()
     if kind == "similarity":
         x, w = _zipf_table()
         svc = PairwiseService(q=1.0, executor="fused")
@@ -255,6 +262,7 @@ def test_request_span_tree(kind):
 def test_same_schema_twice_reads_cached():
     """Passing one ``schema`` object twice finds its plan and source map
     on the second request: ``lower`` and ``maps`` read ``cached=True``."""
+    _cold_plans()
     x, w = _zipf_table()
     schema = plan_a2a(w, 1.0)
     for want in (False, True):
@@ -265,6 +273,97 @@ def test_same_schema_twice_reads_cached():
         (maps,) = got["execute > maps"]
         assert lower.attrs["cached"] is want
         assert maps.attrs["cached"] is want
+
+
+def test_repeat_weights_read_cached():
+    """A second request with the same weight vector gets the same schema
+    from ``plan_a2a``: ``lower`` and ``maps`` read ``cached=True``, and the
+    answer is the first one's and the float64 reference's."""
+    from repro.serve import PairwiseService
+    _cold_plans()
+    x, w = _zipf_table()
+    m = x.shape[0]
+    svc = PairwiseService(q=1.0, executor="fused")
+    answers = []
+    for want in (False, True):
+        TRACER.clear()
+        sims, _info = svc.similarity(x, weights=w.copy())
+        answers.append(np.asarray(sims))
+        got = _by_path(TRACER.spans())
+        (lower,) = got["request > plan > lower"]
+        (maps,) = got["request > execute > maps"]
+        assert lower.attrs["cached"] is want
+        assert maps.attrs["cached"] is want
+    np.testing.assert_array_equal(answers[1], answers[0])
+    x64 = x.astype(np.float64)
+    ref = x64 @ x64.T * (1 - np.eye(m))
+    np.testing.assert_allclose(answers[1], ref, rtol=1e-5, atol=1e-5)
+
+
+def test_schema_memo_counters():
+    """``cache.hits``/``cache.misses`` with ``cache="schema"`` count the
+    remapped-schema memo beside the ``cache="plan"`` series."""
+    _cold_plans()
+    _x, w = _zipf_table()
+    plan_a2a(w, 1.0)                              # plan miss, schema miss
+    plan_a2a(w, 1.0)                              # plan hit, schema hit
+    plan_a2a(w[::-1], 1.0)                        # plan hit, schema miss
+    plan_a2a(w, 1.0, use_cache=False)             # neither
+    assert REGISTRY.counter_total("cache.misses", cache="schema") == 2
+    assert REGISTRY.counter_total("cache.hits", cache="schema") == 1
+    assert REGISTRY.counter_total("cache.misses", cache="plan") == 1
+    assert REGISTRY.counter_total("cache.hits", cache="plan") == 2
+
+
+CODED_REPEAT_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    assert len(jax.devices()) == 4, jax.devices()
+    from repro.obs import TRACER
+    from repro.serve import PairwiseService
+
+    rng = np.random.default_rng(0)
+    m = 64
+    w = np.clip(rng.zipf(1.7, m) / 24.0, 0.02, 0.45)
+    x = rng.normal(size=(m, 8)).astype(np.float32)
+    svc = PairwiseService(q=1.0, executor="coded",
+                          executor_options={"replication": 1})
+    answers, cached = [], []
+    for _ in range(2):
+        TRACER.clear()
+        sims, _ = svc.similarity(x, weights=w)
+        answers.append(np.asarray(sims))
+        cached.append(sorted((s.name, s.attrs.get("what", ""),
+                              s.attrs["cached"])
+                             for s in TRACER.spans()
+                             if s.name in ("lower", "maps")))
+    print("FIRST", cached[0])
+    print("SECOND", cached[1])
+    assert [c for *_, c in cached[0]] == [False] * 4, cached[0]
+    assert cached[1] == [("lower", "", True), ("maps", "coded_maps", True),
+                         ("maps", "groups", True),
+                         ("maps", "partition", True)], cached[1]
+    np.testing.assert_array_equal(answers[1], answers[0])
+    ref = x.astype(np.float64) @ x.astype(np.float64).T * (1 - np.eye(m))
+    np.testing.assert_allclose(answers[1], ref, rtol=1e-5, atol=1e-5)
+    print("CODED_REPEAT_OK")
+""")
+
+
+def test_coded_repeat_reads_cached_on_4_devices():
+    """On four CPU devices, a repeat request to the coded executor finds
+    its partition, groups and coded maps on the shared plan: each ``maps``
+    span reads ``cached=True`` (subprocess: the main test process keeps
+    its default device count)."""
+    res = subprocess.run(
+        [sys.executable, "-c", CODED_REPEAT_SCRIPT],
+        capture_output=True, text=True, timeout=600,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
+             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+             "HOME": os.environ.get("HOME", "/tmp")},
+    )
+    assert "CODED_REPEAT_OK" in res.stdout, res.stdout + res.stderr
 
 
 def test_block_repeat_reads_cached():
@@ -316,6 +415,7 @@ def test_coded_spans_name_each_map():
     """The coded executor's host maps each get a ``maps`` span, and its
     one upload site puts the groups, the send map and the source map."""
     from repro.mapreduce import make_executor
+    _cold_plans()
     x, w = _zipf_table()
     m = x.shape[0]
     TRACER.clear()

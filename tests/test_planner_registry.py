@@ -26,6 +26,7 @@ from repro.core.strategies import (
     A2AProfile,
     PlanCache,
     a2a_portfolio,
+    schema_host_bytes,
     unit_estimates,
 )
 
@@ -191,6 +192,174 @@ class TestPlanCache:
             assert len(PLAN_CACHE) == 0            # stale plans dropped
         finally:
             A2A_REGISTRY.pop()
+
+
+# ------------------------------------------------- remapped-schema memo
+class TestSchemaMemo:
+    """A repeat of one literal weight vector gets the same schema object
+    back, so whatever is memoized on it is found again."""
+
+    def _w(self, seed=5, m=25):
+        return np.random.default_rng(seed).uniform(0.02, 0.4, m)
+
+    def test_same_weights_same_schema(self):
+        w = self._w()
+        s1 = plan_a2a(w, 1.0)
+        hits = PLAN_CACHE.hits
+        s2 = plan_a2a(w.copy(), 1.0)       # equal values, another array
+        assert s2 is s1
+        assert PLAN_CACHE.hits == hits + 1  # the plan cache counts as before
+        assert plan_a2a(w, 1.0, method="binpack-k2") is not s1
+        assert plan_a2a(w, 2.0) is not s1
+
+    def test_permutation_gets_new_schema_from_plan_hit(self):
+        w = self._w()
+        s1 = plan_a2a(w, 1.0)
+        misses = PLAN_CACHE.misses
+        perm = np.random.default_rng(6).permutation(len(w))
+        s2 = plan_a2a(w[perm], 1.0)
+        assert s2 is not s1
+        assert PLAN_CACHE.misses == misses   # canonical entry hit
+        s2.validate("a2a")
+        np.testing.assert_allclose(s2.weights, w[perm])
+        assert plan_a2a(w[perm], 1.0) is s2
+        assert plan_a2a(w, 1.0) is s1
+
+    def test_use_cache_false_stays_out(self):
+        w = self._w()
+        a = plan_a2a(w, 1.0, use_cache=False)
+        b = plan_a2a(w, 1.0, use_cache=False)
+        assert a is not b
+        assert PLAN_CACHE.stats()["schemas"] == 0
+        kept = plan_a2a(w, 1.0)
+        assert plan_a2a(w, 1.0, use_cache=False) is not kept
+        assert PLAN_CACHE.stats()["schemas"] == 1
+
+    @pytest.mark.parametrize("drop", ["clear", "invalidate", "register"])
+    def test_dropped_with_its_canonical_entry(self, drop):
+        from repro.core import A2A_REGISTRY, register_a2a_strategy
+        w = self._w()
+        s1 = plan_a2a(w, 1.0)
+        other = plan_a2a(self._w(seed=9), 1.0)
+        if drop == "clear":
+            PLAN_CACHE.clear()
+        elif drop == "invalidate":
+            order = np.argsort(-w, kind="stable")
+            assert PLAN_CACHE.invalidate(PlanCache.key(w[order], 1.0, "auto"))
+            # only the invalidated profile's schema went
+            assert plan_a2a(self._w(seed=9), 1.0) is other
+        else:
+            register_a2a_strategy(lambda prof: [])
+            A2A_REGISTRY.pop()
+        s2 = plan_a2a(w, 1.0)
+        assert s2 is not s1
+        s2.validate("a2a")
+
+    def test_evicts_at_its_bound(self):
+        from repro.core.strategies import SCHEMA_MEMO_SIZE
+        profiles = [self._w(seed=100 + i) for i in range(SCHEMA_MEMO_SIZE + 1)]
+        first = [plan_a2a(w, 1.0) for w in profiles]
+        assert PLAN_CACHE.stats()["schemas"] == SCHEMA_MEMO_SIZE
+        assert len(PLAN_CACHE) == SCHEMA_MEMO_SIZE + 1  # canonical: all kept
+        assert plan_a2a(profiles[-1], 1.0) is first[-1]
+        assert plan_a2a(profiles[0], 1.0) is not first[0]   # evicted, oldest
+
+    def _with_maps(self, schema, pad=1):
+        """Lower ``schema`` and build its source map, as the fused path
+        does; return the host bytes now memoized on it."""
+        from repro.mapreduce.allpairs import _pair_source_map, _plan_for
+        plan = _plan_for(schema, pad_reducers_to=pad, pad_slots_to=pad)
+        _pair_source_map(plan, schema.m)
+        return schema_host_bytes(schema)
+
+    def _perms(self, n):
+        """``n`` orders of one weight vector: distinct memo keys whose
+        plans and maps hold the same bytes."""
+        w = self._w(m=40)
+        return [w[np.random.default_rng(300 + i).permutation(len(w))]
+                for i in range(n)]
+
+    def test_bytes_bound_evicts_oldest(self, monkeypatch):
+        import repro.core.strategies as strategies
+        ws = self._perms(4)
+        nb = self._with_maps(plan_a2a(ws[0], 1.0))
+        PLAN_CACHE.clear()
+        assert nb >= 40 * 40 * 4                  # the (m, m) source map
+        bound = 2 * nb + nb // 2                  # room for two entries
+        monkeypatch.setattr(strategies, "SCHEMA_MEMO_BYTES", bound)
+        schemas = []
+        for i, w in enumerate(ws):
+            schemas.append(plan_a2a(w, 1.0))
+            assert PLAN_CACHE.schema_bytes() <= bound
+            if i < 3:
+                assert self._with_maps(schemas[-1]) == nb
+        # the fourth insert found 3 * nb kept and evicted the oldest
+        assert PLAN_CACHE.stats()["schemas"] == 3
+        assert PLAN_CACHE.schema_bytes() == 2 * nb
+        assert plan_a2a(ws[3], 1.0) is schemas[3]
+        assert plan_a2a(ws[1], 1.0) is schemas[1]
+        assert plan_a2a(ws[0], 1.0) is not schemas[0]
+
+    def test_bytes_bound_keeps_the_last_used(self, monkeypatch):
+        """A schema holding more than the bound is kept while it is the
+        one in use, and goes when another is looked up."""
+        import repro.core.strategies as strategies
+        monkeypatch.setattr(strategies, "SCHEMA_MEMO_BYTES", 1)
+        ws = self._perms(2)
+        s0 = plan_a2a(ws[0], 1.0)
+        self._with_maps(s0)
+        assert plan_a2a(ws[0], 1.0) is s0         # alone: kept
+        s1 = plan_a2a(ws[1], 1.0)                 # s0's maps exceed the bound
+        assert PLAN_CACHE.stats()["schemas"] == 1
+        assert PLAN_CACHE.schema_bytes() == 0
+        self._with_maps(s1)
+        assert plan_a2a(ws[1], 1.0) is s1
+        assert plan_a2a(ws[0], 1.0) is not s0
+
+    def test_bytes_bound_counts_growth_on_hits(self, monkeypatch):
+        """Maps built on a kept schema after its insert (another padding)
+        count at the next lookup, a hit included."""
+        import repro.core.strategies as strategies
+        ws = self._perms(2)
+        s0, s1 = plan_a2a(ws[0], 1.0), plan_a2a(ws[1], 1.0)
+        nb = self._with_maps(s0)
+        assert self._with_maps(s1) == nb
+        monkeypatch.setattr(strategies, "SCHEMA_MEMO_BYTES", 2 * nb + nb // 2)
+        assert plan_a2a(ws[0], 1.0) is s0         # 2 * nb kept: within
+        assert plan_a2a(ws[1], 1.0) is s1
+        assert self._with_maps(s0, pad=8) > nb    # s0 grows on its own
+        assert plan_a2a(ws[1], 1.0) is s1         # a hit: s0 goes
+        assert PLAN_CACHE.stats()["schemas"] == 1
+        assert PLAN_CACHE.schema_bytes() == nb
+
+    def test_canonical_eviction_drops_its_schemas(self):
+        cache = PlanCache(maxsize=1)
+        cache.put(("a",), 1)
+        cache.put_schema(("la",), ("a",), "schema-a")
+        cache.put_schema(("lz",), ("z",), "never kept")   # no entry "z"
+        assert cache.get_schema(("la",)) == "schema-a"
+        assert cache.get_schema(("lz",)) is None
+        cache.put(("b",), 2)                              # evicts "a"
+        assert cache.get_schema(("la",)) is None
+        assert cache.stats()["schemas"] == 0
+
+    def test_kept_schema_does_not_alias_the_caller(self):
+        w = self._w()
+        s = plan_a2a(w, 1.0)
+        w_before = w.copy()
+        w[:] = 0.1                            # the caller reuses its buffer
+        np.testing.assert_array_equal(s.weights, w_before)
+        assert not s.weights.flags.writeable
+
+    def test_some_pairs_leaves_the_shared_schema_alone(self):
+        w = self._w()
+        pairs = [(i, j) for i in range(len(w)) for j in range(i + 1, len(w))]
+        s = plan_a2a(w, 1.0)
+        algorithm, meta = s.algorithm, dict(s.meta)
+        sp = plan_some_pairs(w, 1.0, pairs, method="a2a")
+        assert sp.algorithm.startswith("some-pairs:a2a:")
+        assert plan_a2a(w, 1.0) is s
+        assert s.algorithm == algorithm and s.meta == meta
 
 
 # -------------------------------------------------------------- some pairs
