@@ -28,9 +28,11 @@ communication lower bound (``schema.lower_bound``, from ``repro.core.bounds``)
 so plans self-report their optimality gap.
 
 Results are memoized in ``strategies.PLAN_CACHE`` keyed by the
-(sorted-weights, q, method) profile; permutations of the same weight
-multiset share one cache entry.  A small memo beside it hands a repeat of
-the same literal weight vector the same remapped schema object.
+(sorted-weights, q, method) profile (``plan_x2y``: each side sorted);
+permutations of the same weight multiset share one cache entry.  A small
+memo beside it hands a repeat of the same literal weight vector the same
+remapped schema object.  Each lookup runs under a ``schema`` span
+(``family``: ``a2a`` or ``x2y``; ``cached``: the memo held the schema).
 """
 
 from __future__ import annotations
@@ -41,6 +43,8 @@ import math
 from typing import Optional, Sequence
 
 import numpy as np
+
+from repro.obs import span as _obs_span
 
 from .binpack import pack
 from .bounds import (
@@ -128,26 +132,48 @@ def plan_a2a(weights: Sequence[float], q: float, method: str = "auto",
         return MappingSchema(w, q, [], [], algorithm="empty", lower_bound=0.0)
     _check_a2a_feasible(w, q)
 
-    # canonicalize to descending weights: plans depend only on the weight
-    # multiset, so permutations share one cache entry and one computation.
-    order = np.argsort(-w, kind="stable")
-    ws = w[order]
-    key = PlanCache.key(ws, q, method)
-    schema_s = PLAN_CACHE.get(key) if use_cache else None
-    if schema_s is None:
-        schema_s = _plan_a2a_sorted(ws, q, method, use_cache)
-        if use_cache:
+    return _cached_schema(
+        "a2a", w, (m,), lambda v: PlanCache.key(v, q, method),
+        lambda ws: _plan_a2a_sorted(ws, q, method, use_cache), use_cache)
+
+
+def _cached_schema(family: str, w: np.ndarray, sides: Sequence[int],
+                   key_of, build, use_cache: bool) -> MappingSchema:
+    """The schema for weights ``w``, planned in canonical order.
+
+    ``w`` is the concatenation of ``len(sides)`` sides of those sizes; the
+    canonical order sorts each side by descending weight (plans depend only
+    on each side's weight multiset, so permutations share one cache entry
+    and one computation).  ``build(ws)`` plans the canonical weights
+    ``ws``; ``key_of(v)`` is the ``PLAN_CACHE`` key of a weight vector
+    ``v`` (canonical: the shared entry; literal: the remapped-schema
+    memo).  With ``use_cache`` the
+    canonical schema is kept in ``PLAN_CACHE`` and a repeat of the same
+    literal ``w`` gets the same remapped object back; without it nothing
+    shared is read or written.  Runs under a ``schema`` span (``family``;
+    ``cached``: the memo held the schema).
+    """
+    with _obs_span("schema", family=family, cached=False) as s:
+        starts = np.cumsum([0, *sides[:-1]])
+        order = np.concatenate([a + np.argsort(-w[a:a + n], kind="stable")
+                                for a, n in zip(starts, sides)])
+        ws = w[order]
+        if not use_cache:
+            return _remap_schema(build(ws), order, w)
+        key = key_of(ws)
+        schema_s = PLAN_CACHE.get(key)
+        if schema_s is None:
+            schema_s = build(ws)
             PLAN_CACHE.put(key, schema_s)
-    if not use_cache:
-        return _remap_schema(schema_s, order, w)
-    # the same literal weights get the same remapped object back
-    literal = PlanCache.key(w, q, method)
-    schema = PLAN_CACHE.get_schema(literal)
-    if schema is None:
-        w = w.copy()                    # the memo must not alias the caller
-        w.flags.writeable = False
-        schema = _remap_schema(schema_s, order, w)
-        PLAN_CACHE.put_schema(literal, key, schema)
+        literal = key_of(w)
+        schema = PLAN_CACHE.get_schema(literal)
+        if schema is None:
+            w = w.copy()                # the memo must not alias the caller
+            w.flags.writeable = False
+            schema = _remap_schema(schema_s, order, w)
+            PLAN_CACHE.put_schema(literal, key, schema)
+        elif s is not None:
+            s.attrs["cached"] = True
     return schema
 
 
@@ -470,7 +496,7 @@ def estimate_x2y(wx: Sequence[float], wy: Sequence[float], q: float,
 
 
 def plan_x2y(wx: Sequence[float], wy: Sequence[float], q: float,
-             num_splits: int = 8) -> MappingSchema:
+             num_splits: int = 8, use_cache: bool = True) -> MappingSchema:
     """Bipartite schema: X ids are 0..m-1, Y ids are m..m+n-1.
 
     Paper: pack X into bins of size b, Y into bins of q - b, cross product.
@@ -479,6 +505,14 @@ def plan_x2y(wx: Sequence[float], wy: Sequence[float], q: float,
     runs on ``estimate_x2y``'s closed-form costs; only the winning split is
     materialized, and ``meta['estimated_cost']`` records the estimate (==
     the built schema's measured cost).
+
+    Same plan-reuse contract as ``plan_a2a``: the plan is computed in
+    canonical (descending-weight) order on each side and kept in
+    ``PLAN_CACHE``, so permutations of either side share one entry, and a
+    repeat of the same literal ``(wx, wy, q, num_splits)`` returns the very
+    same schema object, with the plan and maps memoized on it.  Treat the
+    returned schema as immutable.  Pass ``use_cache=False`` to get a schema
+    with no shared state.
     """
     wx = np.asarray(wx, dtype=np.float64)
     wy = np.asarray(wy, dtype=np.float64)
@@ -486,6 +520,18 @@ def plan_x2y(wx: Sequence[float], wy: Sequence[float], q: float,
     if m == 0 or n == 0:
         return MappingSchema(np.concatenate([wx, wy]), q, [], [],
                              algorithm="empty", lower_bound=0.0)
+    return _cached_schema(
+        "x2y", np.concatenate([wx, wy]), (m, n),
+        lambda v: PlanCache.x2y_key(v[:m], v[m:], q, num_splits),
+        lambda ws: _plan_x2y_sorted(ws[:m], ws[m:], q, num_splits),
+        use_cache)
+
+
+def _plan_x2y_sorted(wx: np.ndarray, wy: np.ndarray, q: float,
+                     num_splits: int) -> MappingSchema:
+    """X2Y plan for descending-sorted weights on each side (canonical
+    cache order)."""
+    m = len(wx)
     b, est = estimate_x2y(wx, wy, q, num_splits)
     w_all = np.concatenate([wx, wy])
     lb = x2y_comm_lower_bound(wx, wy, q)

@@ -139,6 +139,13 @@ class PlanCache:
     def key(sorted_w: np.ndarray, q: float, method: str) -> tuple:
         return (sorted_w.tobytes(), float(q), method)
 
+    @staticmethod
+    def x2y_key(wx: np.ndarray, wy: np.ndarray, q: float,
+                num_splits: int) -> tuple:
+        """Key of an X2Y profile: both sides' weights, ``q`` and the
+        split grid's size."""
+        return ("x2y", wx.tobytes(), wy.tobytes(), float(q), int(num_splits))
+
     def get(self, key: tuple):
         if key in self._store:
             self.hits += 1

@@ -61,6 +61,7 @@ from repro.core.planner import PlanPartition, partition_plan
 from repro.obs import EVENTS as _EVENTS
 from repro.obs import LEDGER as _LEDGER
 from repro.obs import REGISTRY as _REGISTRY_OBS
+from repro.obs import TRACER as _TRACER
 from repro.obs import _config as _obs_config
 from repro.obs import span as _obs_span
 
@@ -214,13 +215,21 @@ class Executor:
     def _reconcile(self, plan, workload: str, table, *,
                    measured_slots: int, replication: float = 1.0,
                    assembled_bytes: int = 0, local_bytes: int = 0,
-                   residual_bytes: int = 0, meta: Optional[dict] = None
-                   ) -> None:
+                   residual_bytes: int = 0, meta: Optional[dict] = None,
+                   stacks=None) -> None:
         """Record this execution's comm reconciliation (no-op when obs is
-        disabled).  ``table`` supplies the input row size (d, itemsize)."""
+        disabled).  ``table`` supplies the input row size (d, itemsize);
+        ``stacks`` are the (X-side, Y-side) gather rows of the reducer
+        blocks the programs write (default: the plan's buckets, else its
+        dense rows).  Their float32 bytes go to the ledger, whose
+        ``ledger.block_bytes`` counter sums them, and to the innermost
+        open span as ``block_bytes``."""
         if not _obs_config.ENABLED:
             return
         d, itemsize = _row_bytes(table)
+        if stacks is None:
+            stacks = _plan_stacks(plan, dense=not plan.buckets)
+        block_bytes = 4 * _block_entries(stacks)
         _LEDGER.record(
             executor=self.name, workload=workload,
             predicted_rows=float(plan.comm_cost),
@@ -229,7 +238,35 @@ class Executor:
             measured_slots=int(measured_slots), d=d, itemsize=itemsize,
             replication=replication, assembled_bytes=assembled_bytes,
             local_bytes=local_bytes, residual_bytes=residual_bytes,
-            meta=meta)
+            block_bytes=block_bytes, meta=meta)
+        s = _TRACER.current()
+        if s is not None:
+            s.attrs["block_bytes"] = s.attrs.get("block_bytes", 0) \
+                + block_bytes
+
+
+def _plan_stacks(plan, dense: bool) -> list:
+    """(X-side, Y-side) gather rows of each stack of reducer blocks: the
+    plan's capacity buckets, or its dense rows (``dense``).  A square
+    stack's rows serve both sides."""
+    if dense:
+        return [(plan.idx, plan.idx if plan.yidx is None else plan.yidx)]
+    return [(b.idx, b.idx if b.yidx is None else b.yidx)
+            for b in plan.buckets]
+
+
+def _group_stacks(groups) -> list:
+    """(X-side, Y-side) gather rows of stacked shard groups: 5-tuples
+    (xi, xm, yi, ym, rows) or square 3-tuples (idx, mask, rows)."""
+    return [(g[0], g[2] if len(g) >= 5 else g[0]) for g in groups]
+
+
+def _block_entries(stacks) -> int:
+    """Cells of the reducer blocks written: per stack, its reducers
+    (padding rows and every shard included) times the X width times the
+    Y width."""
+    return sum(int(np.prod(xi.shape[:-1])) * int(xi.shape[-1])
+               * int(yi.shape[-1]) for xi, yi in stacks)
 
 
 def _row_bytes(table) -> tuple[int, int]:
@@ -292,28 +329,6 @@ def _group_valid_slots(plan, cache_key, groups, count_y: bool) -> int:
                     n += int(np.asarray(grp[3]).sum())
             else:                       # (idx, mask, rows) square stack
                 n += int(np.asarray(grp[1]).sum())
-        cache[cache_key] = n
-    return n
-
-
-def _group_gram_entries(plan, cache_key, groups) -> int:
-    """Gram entries the stacked shard groups produce — what the sharded
-    all-gather assembly ships.  Cached on the plan (same cache as the slot
-    sums, disjoint keys)."""
-    cache = plan.__dict__.get("_obs_group_slots")
-    if cache is None:
-        cache = {}
-        object.__setattr__(plan, "_obs_group_slots", cache)
-    n = cache.get(cache_key)
-    if n is None:
-        n = 0
-        for grp in groups:
-            if len(grp) >= 5:            # rect: (xi, xm, yi, ym, rows)
-                xi, yi = grp[0], grp[2]
-                n += int(np.prod(xi.shape[:2])) * xi.shape[2] * yi.shape[2]
-            else:                        # square: (idx, mask, rows)
-                i = grp[0]
-                n += int(np.prod(i.shape[:2])) * i.shape[2] ** 2
         cache[cache_key] = n
     return n
 
@@ -394,7 +409,8 @@ class DenseExecutor(Executor):
         from .allpairs import assemble_pair_matrix
         self._count("calls")
         self._reconcile(plan, "pairs", x,
-                        measured_slots=_plan_valid_slots(plan))
+                        measured_slots=_plan_valid_slots(plan),
+                        stacks=_plan_stacks(plan, dense=True))
         blocks = run_reducers(x, plan, reducer_fn, mesh=mesh)  # (R, L, L)
         return assemble_pair_matrix(blocks, plan, m)
 
@@ -403,7 +419,8 @@ class DenseExecutor(Executor):
         from .allpairs import assemble_x2y_matrix_bucketed
         self._count("calls")
         self._reconcile(plan, "x2y", _as_tables(tables)[0],
-                        measured_slots=_plan_valid_slots(plan))
+                        measured_slots=_plan_valid_slots(plan),
+                        stacks=_plan_stacks(plan, dense=True))
         blocks = run_reducers_x2y(tables, plan, reducer_fn, mesh=mesh)
         # the plan's dense idx/mask/yidx/ymask rows are bucket-shaped, so
         # the whole plan assembles as a single "bucket"
@@ -1072,17 +1089,17 @@ class ShardedExecutor(Executor):
         if _obs_config.ENABLED:
             assembled = 0
             meta = {"num_shards": S, "combine": combine}
+            stacks = _group_stacks(groups)
             if combine == "pairs":
                 _d, isz = _row_bytes(x)
-                per_shard = int(_group_gram_entries(
-                    plan, ("gram", S), groups) * isz * (S - 1) / S)
+                per_shard = int(_block_entries(stacks) * isz * (S - 1) / S)
                 assembled = S * per_shard
                 meta["assembly_bytes_per_shard"] = per_shard
             self._reconcile(
                 plan, workload, x,
                 measured_slots=_group_valid_slots(
                     plan, ("sharded", S), groups, count_y=False),
-                assembled_bytes=assembled, meta=meta)
+                assembled_bytes=assembled, meta=meta, stacks=stacks)
         P = jax.sharding.PartitionSpec
         host_srcmap = (self._srcmap_for(plan, groups, S, srcmap_m)
                        if combine == "pairs" else None)
@@ -1161,15 +1178,16 @@ class ShardedExecutor(Executor):
         if _obs_config.ENABLED:
             xt0 = _as_tables(tables)[0]
             _d, isz = _row_bytes(xt0)
-            per_shard = int(_group_gram_entries(
-                plan, ("gram_rect", S), groups) * isz * (S - 1) / S)
+            stacks = _group_stacks(groups)
+            per_shard = int(_block_entries(stacks) * isz * (S - 1) / S)
             self._reconcile(
                 plan, "x2y", xt0,
                 measured_slots=_group_valid_slots(
                     plan, ("sharded_rect", S), groups, count_y=True),
                 assembled_bytes=S * per_shard,
                 meta={"num_shards": S,
-                      "assembly_bytes_per_shard": per_shard})
+                      "assembly_bytes_per_shard": per_shard},
+                stacks=stacks)
         P = jax.sharding.PartitionSpec
         host_srcmap = self._rect_srcmap_for(plan, groups, S, tuple(shape))
         uk = True if use_kernel else jax.default_backend() == "tpu"
@@ -1478,7 +1496,8 @@ class CodedExecutor(ShardedExecutor):
                 meta={"num_shards": S,
                       "replication": int(part.replication),
                       "assembly_bytes_per_shard": per_shard,
-                      "lane_max": mstats["lane_max"]})
+                      "lane_max": mstats["lane_max"]},
+                stacks=_group_stacks(groups))
         if use_kernel is None:
             use_kernel = jax.default_backend() == "tpu"
         fn = _cache_get(

@@ -15,7 +15,10 @@ moved —
   (``d * itemsize`` — the byte convention ``dryrun_engine`` uses);
 * ``assembled_bytes`` / ``local_bytes`` / ``residual_bytes``: cross-shard
   assembly traffic (the sharded all-gather, the coded residual
-  all-to-all) and the coded replica-local vs residual split —
+  all-to-all) and the coded replica-local vs residual split;
+* ``block_bytes``: the float32 bytes of the reducer blocks the programs
+  write, padding included (per stack: reducers x X width x Y width x 4,
+  over every shard) —
 
 against the plan's booked cost.  The headline ratios:
 
@@ -68,6 +71,7 @@ class CommRecord:
     assembled_bytes: int = 0       # cross-shard assembly traffic (cluster)
     local_bytes: int = 0           # coded: replica-local served bytes
     residual_bytes: int = 0        # coded: cross-shard residual bytes
+    block_bytes: int = 0           # f32 reducer blocks the programs write
     anomaly: bool = False
     meta: dict = dataclasses.field(default_factory=dict)
 
@@ -118,6 +122,7 @@ class CommRecord:
             "assembled_bytes": self.assembled_bytes,
             "local_bytes": self.local_bytes,
             "residual_bytes": self.residual_bytes,
+            "block_bytes": self.block_bytes,
             "anomaly": self.anomaly,
         }
 
@@ -144,7 +149,7 @@ class CommLedger:
                plan_slots: int, measured_slots: int, d: int,
                itemsize: int = 4, replication: float = 1.0,
                assembled_bytes: int = 0, local_bytes: int = 0,
-               residual_bytes: int = 0,
+               residual_bytes: int = 0, block_bytes: int = 0,
                meta: Optional[dict] = None) -> Optional[CommRecord]:
         """Reconcile one execution; returns the record (None when obs is
         disabled)."""
@@ -162,7 +167,8 @@ class CommLedger:
             replication=float(replication),
             assembled_bytes=int(assembled_bytes),
             local_bytes=int(local_bytes),
-            residual_bytes=int(residual_bytes), meta=dict(meta or {}))
+            residual_bytes=int(residual_bytes),
+            block_bytes=int(block_bytes), meta=dict(meta or {}))
         ratio = rec.measured_over_predicted
         expected = max(rec.replication, 1e-12)
         if abs(ratio - expected) > self.tolerance * expected:
@@ -182,6 +188,8 @@ class CommLedger:
                          executor=rec.executor).inc(rec.gathered_bytes)
         REGISTRY.counter("ledger.assembled_bytes",
                          executor=rec.executor).inc(rec.assembled_bytes)
+        REGISTRY.counter("ledger.block_bytes",
+                         executor=rec.executor).inc(rec.block_bytes)
         REGISTRY.histogram("ledger.measured_over_predicted",
                            executor=rec.executor,
                            workload=rec.workload).observe(ratio)

@@ -8,8 +8,9 @@ a request with parent nesting tracked per thread, so a
     request                      compiles
     ├── upload                   bytes       (the table)
     ├── plan
+    │   ├── schema               family, cached (plan_a2a / plan_x2y)
     │   └── lower                cached      (the reducer plan / sub-plan)
-    └── execute
+    └── execute                  block_bytes (reducer blocks written)
         ├── maps                 what, cached (source map, partition, ...)
         ├── upload               bytes       (plan arrays, source map)
         └── launch               compiles    (the jitted program's call)
@@ -122,6 +123,11 @@ class Tracer:
             self._spans.append(s)
 
     # ------------------------------------------------------------- queries
+    def current(self) -> Optional[Span]:
+        """The calling thread's innermost open span, or None."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
     def spans(self) -> list:
         """Snapshot of the completed-span ring (oldest first)."""
         return list(self._spans)

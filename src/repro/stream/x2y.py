@@ -32,7 +32,9 @@ ceilings fire on real degradation, not on the bound's intrinsic
 looseness.  A full re-plan (through ``repro.core.plan_x2y``, which may
 move the split point ``b`` itself) adopts the fresh schema as planning
 state but emits only a compact *patch* delta: pair values are
-plan-independent, so the served matrix never rebuilds.
+plan-independent, so the served matrix never rebuilds.  As in the
+all-pairs planner, the superseded profile's ``PLAN_CACHE`` entry is
+dropped, and the background re-plan stays off the cache.
 ``PlanDelta.verify_x2y`` is the per-edit coverage proof when
 ``check=True``.
 """
@@ -46,12 +48,15 @@ import numpy as np
 from repro.core.bounds import x2y_comm_lower_bound
 from repro.core.planner import plan_x2y
 from repro.core.schema import InfeasibleError
+from repro.core.strategies import PLAN_CACHE, PlanCache
 from repro.mapreduce.engine import ReducerPlan, build_x2y_plan_arrays
 
 from .base import StreamPlannerBase, _EPS
 from .delta import PlanDelta, compact_x2y_plan
 
 __all__ = ["IncrementalX2YPlanner"]
+
+_SPLITS = 8                     # plan_x2y's split-point grid
 
 
 def _ffd_pack(ids: Sequence[int], weights: Sequence[float],
@@ -105,6 +110,7 @@ class IncrementalX2YPlanner(StreamPlannerBase):
         self.wy: list[float] = [float(w) for w in wy]
         self.active_x: list[bool] = [True] * len(self.wx)
         self.active_y: list[bool] = [True] * len(self.wy)
+        self._cache_key: Optional[tuple] = None
         self._adopt_replan()
 
     # ------------------------------------------------------------ properties
@@ -157,6 +163,7 @@ class IncrementalX2YPlanner(StreamPlannerBase):
         y_ids = self.active_y_ids()
         wx = self.active_x_weights()
         wy = self.active_y_weights()
+        old_key = self._cache_key
         if len(x_ids) == 0 or len(y_ids) == 0:
             algorithm = "empty" if not (len(x_ids) or len(y_ids)) \
                 else "x2y-one-sided"
@@ -167,8 +174,13 @@ class IncrementalX2YPlanner(StreamPlannerBase):
             xbins = _ffd_pack(x_ids, self.wx, self.q) if len(x_ids) else []
             ybins = _ffd_pack(y_ids, self.wy, self.q) if len(y_ids) else []
             reducers: list[tuple[int, int]] = []
+            self._cache_key = None
         else:
-            schema = plan_x2y(wx, wy, self.q)   # may raise InfeasibleError
+            # may raise InfeasibleError
+            schema = plan_x2y(wx, wy, self.q, _SPLITS)
+            self._cache_key = PlanCache.x2y_key(
+                wx[np.argsort(-wx, kind="stable")],
+                wy[np.argsort(-wy, kind="stable")], self.q, _SPLITS)
             algorithm = schema.algorithm
             b = float(schema.meta["b"])
             nxb = int(schema.meta["x_bins"])
@@ -179,6 +191,9 @@ class IncrementalX2YPlanner(StreamPlannerBase):
                      for bin_ in schema.bins[nxb:]]
             reducers = [(int(r[0]), int(r[1]) - nxb)
                         for r in schema.reducers]
+        if old_key is not None and old_key != self._cache_key:
+            # this stream has permanently moved off its previous profile
+            PLAN_CACHE.invalidate(old_key)
         self._adopt_x2y_state(algorithm, b, xbins, ybins, reducers)
         self._recompute_lb()
         self._after_adopt()
@@ -223,7 +238,10 @@ class IncrementalX2YPlanner(StreamPlannerBase):
 
     def _background_plan(self, payload):
         x_ids, wx, y_ids, wy = payload
-        return x_ids, y_ids, plan_x2y(wx, wy, self.q)
+        # no PLAN_CACHE traffic from the daemon thread: the captured
+        # profile is transient and must not evict live serving entries
+        return x_ids, y_ids, plan_x2y(wx, wy, self.q, _SPLITS,
+                                      use_cache=False)
 
     def _swap_in(self, result) -> bool:
         """Adopt a background plan built for a captured profile onto the
@@ -248,6 +266,10 @@ class IncrementalX2YPlanner(StreamPlannerBase):
         if (bwx and max(bwx) > b + _EPS) \
                 or (bwy and max(bwy) > self.q - b + _EPS):
             return False
+        old_key = self._cache_key
+        self._cache_key = None      # planned off-cache for a stale profile
+        if old_key is not None:
+            PLAN_CACHE.invalidate(old_key)
         self._adopt_x2y_state(
             schema.algorithm, b, xbins, ybins,
             [(int(r[0]), int(r[1]) - nxb) for r in schema.reducers])

@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 import repro.obs as obs
-from repro.core import PLAN_CACHE, plan_a2a
+from repro.core import PLAN_CACHE, plan_a2a, plan_x2y
 from repro.mapreduce import engine as mr_engine
 from repro.mapreduce import get_executor, pairwise_similarity
 from repro.obs import EVENTS, LEDGER, REGISTRY, TRACER
@@ -245,12 +245,19 @@ def test_request_span_tree(kind):
     launches = got["request > execute > launch"]
     assert len(launches) == (1 if kind == "similarity" else 2)
     assert all(isinstance(s.attrs["compiles"], int) for s in launches)
-    # every span of the request is in the tree above, and at most ten
-    assert set(got) == {
+    # every span of the request is in the tree above, and at most ten;
+    # a similarity request plans its schema under ``plan`` (a block's
+    # schema was planned when its table was loaded)
+    paths = {
         "request", "request > upload", "request > plan",
         "request > plan > lower", "request > execute",
         "request > execute > maps", "request > execute > upload",
         "request > execute > launch"}
+    if kind == "similarity":
+        (schema,) = got["request > plan > schema"]
+        assert schema.attrs == {"family": "a2a", "cached": False}
+        paths.add("request > plan > schema")
+    assert set(got) == paths
     assert len(TRACER.spans()) <= 10
     # spans time host work: children fit inside their parent
     parent = {s.span_id: s for s in TRACER.spans()}
@@ -297,6 +304,30 @@ def test_repeat_weights_read_cached():
     np.testing.assert_array_equal(answers[1], answers[0])
     x64 = x.astype(np.float64)
     ref = x64 @ x64.T * (1 - np.eye(m))
+    np.testing.assert_allclose(answers[1], ref, rtol=1e-5, atol=1e-5)
+
+
+def test_x2y_repeat_weights_read_cached():
+    """A repeat x2y request with the same weights finds its rect plan and
+    rect source map again: ``schema``, ``lower`` and ``maps`` read
+    ``cached=True``; the answer is the first one's and the float64
+    reference's."""
+    from repro.serve import PairwiseService
+    _cold_plans()
+    x, wx, y, wy = _zipf_pair()
+    svc = PairwiseService(q=1.0, executor="fused")
+    answers = []
+    for want in (False, True):
+        TRACER.clear()
+        sims, _info = svc.x2y(x, y, wx=wx.copy(), wy=wy.copy())
+        answers.append(np.asarray(sims))
+        got = _by_path(TRACER.spans())
+        for path in ("request > plan > schema", "request > plan > lower",
+                     "request > execute > maps"):
+            (s,) = got[path]
+            assert s.attrs["cached"] is want, path
+    np.testing.assert_array_equal(answers[1], answers[0])
+    ref = x.astype(np.float64) @ y.astype(np.float64).T
     np.testing.assert_allclose(answers[1], ref, rtol=1e-5, atol=1e-5)
 
 
@@ -364,6 +395,108 @@ def test_coded_repeat_reads_cached_on_4_devices():
              "HOME": os.environ.get("HOME", "/tmp")},
     )
     assert "CODED_REPEAT_OK" in res.stdout, res.stdout + res.stderr
+
+
+def _zipf_pair(mx=48, my=40, d=8, seed=0):
+    """An X and a Y table with Zipf sizes on both sides (q = 1.0)."""
+    x, wx = _zipf_table(m=mx, d=d, seed=seed)
+    y, wy = _zipf_table(m=my, d=d, seed=seed + 1)
+    return x, wx, y, wy
+
+
+@pytest.mark.parametrize("kind", ["similarity", "x2y"])
+def test_one_schema_span_per_request(kind):
+    """Each request plans its schema under one ``schema`` span nested in
+    ``plan``: ``family`` names the planner, ``cached`` reads False on the
+    first request and True on a repeat of the same weights."""
+    from repro.serve import PairwiseService
+    _cold_plans()
+    svc = PairwiseService(q=1.0, executor="fused")
+    if kind == "similarity":
+        x, w = _zipf_table()
+        serve = lambda: svc.similarity(x, weights=w.copy())  # noqa: E731
+        family = "a2a"
+    else:
+        x, wx, y, wy = _zipf_pair()
+        serve = lambda: svc.x2y(x, y, wx=wx.copy(), wy=wy.copy())  # noqa
+        family = "x2y"
+    for want in (False, True):
+        TRACER.clear()
+        serve()
+        got = _by_path(TRACER.spans())
+        (schema,) = got["request > plan > schema"]
+        assert schema.attrs == {"family": family, "cached": want}
+        assert sum(s.name == "schema" for s in TRACER.spans()) == 1
+
+
+def test_schema_span_records_with_obs_on_only():
+    """The ``schema`` span records with the profiler's annotations off and
+    on, and not at all with observability off."""
+    _cold_plans()
+    _x, wx, _y, wy = _zipf_pair()
+    for annotate in (False, True):
+        TRACER.clear()
+        TRACER.annotate = annotate
+        try:
+            plan_x2y(wx, wy, 1.0)
+        finally:
+            TRACER.annotate = False
+        assert [s.name for s in TRACER.spans()] == ["schema"]
+    obs.configure(enabled=False)
+    TRACER.clear()
+    plan_x2y(wx, wy, 1.0)
+    assert TRACER.spans() == []
+
+
+def _plan_block_bytes(plan) -> int:
+    """Σ over the plan's buckets of reducers x X width x Y width x 4."""
+    return sum(b.idx.shape[0] * b.idx.shape[1]
+               * (b.idx.shape[1] if b.yidx is None else b.yidx.shape[1]) * 4
+               for b in plan.buckets)
+
+
+@pytest.mark.parametrize("kind", ["similarity", "x2y"])
+def test_block_bytes_counter_equals_the_plan(kind):
+    """``ledger.block_bytes`` (the counter, the ledger record and the
+    ``execute`` span's ``block_bytes``) is Σ R·Lx·Ly·4 over the plan's
+    buckets, on a pairs and on an x2y request."""
+    from repro.serve import PairwiseService
+    _cold_plans()
+    svc = PairwiseService(q=1.0, executor="fused")
+    if kind == "similarity":
+        x, w = _zipf_table()
+        svc.similarity(x, weights=w)
+        plan = mr_engine.build_plan(plan_a2a(w, 1.0))
+    else:
+        x, wx, y, wy = _zipf_pair()
+        svc.x2y(x, y, wx=wx, wy=wy)
+        plan = mr_engine.build_x2y_plan(plan_x2y(wx, wy, 1.0), len(wx))
+    want = _plan_block_bytes(plan)
+    assert want > 0
+    assert REGISTRY.counter_total("ledger.block_bytes") == want
+    assert LEDGER.last().block_bytes == want
+    (execute,) = [s for s in TRACER.spans() if s.name == "execute"]
+    assert execute.attrs["block_bytes"] == want
+
+
+@pytest.mark.parametrize("name", ["dense", "sharded", "coded"])
+def test_block_bytes_on_other_executors(name):
+    """The dense executor writes its (R, L, L) rows; the sharded and coded
+    executors their stacked shard groups, every shard's rows counted."""
+    x, w = _zipf_table()
+    ex = get_executor(name)
+    _sims, plan, _ = pairwise_similarity(x, q=1.0, weights=w, executor=ex)
+    if name == "dense":
+        want = plan.R * plan.L * plan.L * 4
+    else:
+        cache = ("_shard_groups_cache" if name == "sharded"
+                 else "_coded_groups_cache")
+        (groups,) = plan.__dict__[cache].values()
+        want = sum(g[0].shape[0] * g[0].shape[1] * g[0].shape[2] ** 2 * 4
+                   for g in groups)
+    assert LEDGER.last().block_bytes == want
+    assert REGISTRY.counter_total("ledger.block_bytes",
+                                  executor=name) == want
 
 
 def test_block_repeat_reads_cached():

@@ -362,6 +362,144 @@ class TestSchemaMemo:
         assert s.algorithm == algorithm and s.meta == meta
 
 
+# ------------------------------------------------------ X2Y schema memo
+class TestX2YSchemaMemo:
+    """``plan_x2y`` keeps the plan-reuse contract of ``plan_a2a``: a
+    repeat of one literal ``(wx, wy, q, num_splits)`` gets the same schema
+    object back, through the same ``PLAN_CACHE`` store and memo."""
+
+    def _w(self, seed=5, mx=30, my=22):
+        rng = np.random.default_rng(seed)
+        return rng.uniform(0.02, 0.4, mx), rng.uniform(0.02, 0.4, my)
+
+    @staticmethod
+    def _valid(schema, wx, wy):
+        mx = len(wx)
+        schema.validate("x2y", x_ids=range(mx),
+                        y_ids=range(mx, mx + len(wy)))
+        np.testing.assert_array_equal(schema.weights,
+                                      np.concatenate([wx, wy]))
+
+    def test_same_weights_same_schema(self):
+        from repro.core import plan_x2y
+        wx, wy = self._w()
+        s1 = plan_x2y(wx, wy, 1.0)
+        hits = PLAN_CACHE.hits
+        assert plan_x2y(wx.copy(), list(wy), 1.0) is s1
+        assert PLAN_CACHE.hits == hits + 1    # the store counts as a2a's
+        assert plan_x2y(wx, wy, 1.0, num_splits=4) is not s1
+        assert plan_x2y(wx, wy, 1.5) is not s1
+        assert plan_x2y(wy, wx, 1.0) is not s1    # the sides swapped
+        assert PLAN_CACHE.stats()["schemas"] == 4
+        self._valid(s1, wx, wy)
+
+    @pytest.mark.parametrize("change", ["permute_x", "permute_y", "value"])
+    def test_other_weights_get_a_fresh_exact_schema(self, change):
+        from repro.core import plan_x2y
+        wx, wy = self._w()
+        s1 = plan_x2y(wx, wy, 1.0)
+        misses = PLAN_CACHE.misses
+        perm = np.random.default_rng(6).permutation
+        if change == "permute_x":
+            wx2, wy2 = wx[perm(len(wx))], wy
+        elif change == "permute_y":
+            wx2, wy2 = wx, wy[perm(len(wy))]
+        else:
+            wx2, wy2 = wx, wy.copy()
+            wy2[3] = 0.3 if wy2[3] != 0.3 else 0.2
+        s2 = plan_x2y(wx2, wy2, 1.0)
+        assert s2 is not s1
+        # a permutation is a canonical hit; a new value plans anew
+        assert PLAN_CACHE.misses == misses + (change == "value")
+        self._valid(s2, wx2, wy2)
+        assert s2.communication_cost() == pytest.approx(
+            s2.meta["estimated_cost"])
+        assert plan_x2y(wx2, wy2, 1.0) is s2
+        assert plan_x2y(wx, wy, 1.0) is s1
+        self._valid(s1, wx, wy)
+
+    def test_matches_the_uncached_plan(self):
+        """Planning in canonical order and remapping gives the schema a
+        direct plan of the caller's order gives: same bins, reducers and
+        split."""
+        from repro.core import plan_x2y
+        from repro.core.binpack import pack
+        wx, wy = self._w(seed=11, mx=40, my=35)
+        s = plan_x2y(wx, wy, 1.0)
+        b = s.meta["b"]
+        mx = len(wx)
+        want = [list(bn) for bn in pack(wx, b, "best")] + \
+            [[mx + i for i in bn] for bn in pack(wy, 1.0 - b, "best")]
+        assert s.bins == want
+        nx = s.meta["x_bins"]
+        assert s.reducers == [[i, nx + j] for i in range(nx)
+                              for j in range(len(want) - nx)]
+
+    def test_kept_schema_does_not_alias_the_caller(self):
+        from repro.core import plan_x2y
+        wx, wy = self._w()
+        s = plan_x2y(wx, wy, 1.0)
+        before = np.concatenate([wx, wy])
+        wx[:] = 0.1
+        wy[:] = 0.1
+        np.testing.assert_array_equal(s.weights, before)
+        assert not s.weights.flags.writeable
+
+    @staticmethod
+    def _with_maps(schema, mx, my):
+        """Lower ``schema`` and build its rect source map, as the fused
+        x2y path does; return the plan and the map."""
+        from repro.mapreduce.allpairs import (
+            _pair_source_map_rect,
+            _x2y_plan_for,
+        )
+        plan = _x2y_plan_for(schema, mx, pad_reducers_to=1, pad_slots_to=1)
+        return plan, _pair_source_map_rect(plan, mx, my)
+
+    def test_memo_bytes_count_the_rect_plan_and_map(self):
+        from repro.core import plan_x2y
+        wx, wy = self._w()
+        s = plan_x2y(wx, wy, 1.0)
+        plan, srcmap = self._with_maps(s, len(wx), len(wy))
+        arrays = [plan.idx, plan.mask, plan.yidx, plan.ymask, srcmap]
+        for b in plan.buckets:
+            arrays += [b.rows, b.idx, b.mask, b.yidx, b.ymask]
+        want = sum(a.nbytes for a in arrays)
+        assert srcmap.shape == (len(wx), len(wy))
+        assert schema_host_bytes(s) == want
+        assert PLAN_CACHE.schema_bytes() == want
+
+    def test_bytes_bound_keeps_the_entry_in_use(self, monkeypatch):
+        """Under a byte bound the x2y and a2a schemas share one memo: the
+        one looked up last stays whatever it holds, the older goes."""
+        import repro.core.strategies as strategies
+        from repro.core import plan_x2y
+        wx, wy = self._w()
+        s0 = plan_x2y(wx, wy, 1.0)
+        self._with_maps(s0, len(wx), len(wy))
+        monkeypatch.setattr(strategies, "SCHEMA_MEMO_BYTES", 1)
+        assert plan_x2y(wx, wy, 1.0) is s0        # alone: kept
+        a = plan_a2a(wx, 1.0)                     # s0's maps pass the bound
+        assert PLAN_CACHE.stats()["schemas"] == 1
+        assert plan_a2a(wx, 1.0) is a
+        s1 = plan_x2y(wx, wy, 1.0)
+        assert s1 is not s0
+        self._valid(s1, wx, wy)
+        self._with_maps(s1, len(wx), len(wy))
+        assert plan_x2y(wx, wy, 1.0) is s1        # in use: kept
+        assert PLAN_CACHE.stats()["schemas"] == 1
+
+    def test_evicts_at_the_count_bound(self):
+        from repro.core import plan_x2y
+        from repro.core.strategies import SCHEMA_MEMO_SIZE
+        profiles = [self._w(seed=200 + i)
+                    for i in range(SCHEMA_MEMO_SIZE + 1)]
+        first = [plan_x2y(wx, wy, 1.0) for wx, wy in profiles]
+        assert PLAN_CACHE.stats()["schemas"] == SCHEMA_MEMO_SIZE
+        assert plan_x2y(*profiles[-1], 1.0) is first[-1]
+        assert plan_x2y(*profiles[0], 1.0) is not first[0]
+
+
 # -------------------------------------------------------------- some pairs
 class TestPlanSomePairs:
     def _random_instance(self, seed, m=30, density=0.2):

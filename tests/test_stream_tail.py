@@ -221,6 +221,52 @@ class TestBackgroundReplan:
         svc.flush_replan()  # drain any still-in-flight plan
         _assert_conformant(planner)
 
+    @staticmethod
+    def _x2y_profile(seed=1, q=4.0):
+        rng = np.random.default_rng(seed)
+        return (rng, np.clip(rng.zipf(1.6, 32) / 8.0, 0.05, 0.45 * q),
+                np.clip(rng.zipf(1.6, 24) / 8.0, 0.05, 0.45 * q))
+
+    def test_x2y_background_replan_stays_off_the_plan_cache(self):
+        """The daemon thread reads and writes no PLAN_CACHE state; the
+        swap then drops the stream's superseded entry."""
+        from repro.core.strategies import PLAN_CACHE
+        PLAN_CACHE.clear()
+        _, wx, wy = self._x2y_profile()
+        inc = st.IncrementalX2YPlanner(4.0, wx=wx, wy=wy, background=True)
+        before = PLAN_CACHE.stats()
+        assert before["size"] == before["schemas"] == 1
+        assert inc._start_background()
+        inc._bg["thread"].join()
+        assert inc._bg["error"] is None
+        assert PLAN_CACHE.stats() == before
+        assert inc.flush_replan()
+        after = PLAN_CACHE.stats()
+        assert (after["size"], after["schemas"]) == (0, 0)
+        assert after["invalidations"] == before["invalidations"] + 1
+        assert inc.stats["swaps"] == 1
+
+    def test_x2y_sync_replans_keep_one_entry(self):
+        """A synchronous re-plan drops the entry of the profile the stream
+        left, so a churning stream holds one canonical entry."""
+        from repro.core.strategies import PLAN_CACHE
+        PLAN_CACHE.clear()
+        rng, wx, wy = self._x2y_profile()
+        inc = st.IncrementalX2YPlanner(4.0, wx=wx, wy=wy,
+                                       replan_drift=1e9, max_gap=1.05)
+        for _ in range(30):
+            ax, ay = inc.active_x_ids(), inc.active_y_ids()
+            if len(ax) > 4 and rng.random() < 0.6:
+                inc.delete_x(int(rng.choice(ax)))
+            elif len(ay) > 4:
+                inc.delete_y(int(rng.choice(ay)))
+            else:
+                break
+            assert PLAN_CACHE.stats()["size"] == 1
+        assert inc.stats["drift_replans"] >= 1
+        assert PLAN_CACHE.stats()["invalidations"] == \
+            inc.stats["drift_replans"]
+
 
 class TestRepack:
     def test_deletion_churn_triggers_repack(self):

@@ -346,3 +346,54 @@ class TestX2YSimilarityExecutors:
         rect = np.asarray(rect)
         off = ~np.eye(10, dtype=bool)
         np.testing.assert_allclose(rect[off], sq[off], **TOL)
+
+
+class TestX2YService:
+    """``PairwiseService(executor="fused").x2y`` with the rect kernel in
+    interpret mode, against a float64 NumPy reference, on Zipf sizes on
+    both sides: the first request, a repeat of the same weights and a
+    permuted profile."""
+
+    @staticmethod
+    def _fp32_bound(a, b):
+        """|fl(a.b) - a.b| <= gamma_d |a| |b| for float32 dot products
+        (Higham, Sec. 3.1), with u = 2^-24: the largest row norms bound
+        every entry's error."""
+        d = a.shape[1]
+        u = 2.0 ** -24
+        gamma = d * u / (1 - d * u)
+        na = np.sqrt(np.einsum("ij,ij->i", a, a).max())
+        nb = np.sqrt(np.einsum("ij,ij->i", b, b).max())
+        return gamma * na * nb
+
+    @pytest.mark.parametrize("side", ["x", "y"])
+    def test_fused_kernel_matches_float64_reference(self, side):
+        from repro.core import PLAN_CACHE
+        from repro.serve import PairwiseService
+        PLAN_CACHE.clear()
+        rng = np.random.default_rng(17)
+        mx, my, d, q = 192, 160, 128, 8.0
+
+        def zipf(n):
+            return np.clip(rng.zipf(1.6, n) / 32.0, 0.01, 0.45 * q)
+
+        x = rng.standard_normal((mx, d), dtype=np.float32)
+        y = rng.standard_normal((my, d), dtype=np.float32)
+        wx, wy = zipf(mx), zipf(my)
+        perm = rng.permutation(mx if side == "x" else my)
+        pwx, pwy = (wx[perm], wy) if side == "x" else (wx, wy[perm])
+        svc = PairwiseService(q=q, executor="fused", use_kernel=True,
+                              interpret=True)
+        ref = x.astype(np.float64) @ y.astype(np.float64).T
+        bound = self._fp32_bound(x.astype(np.float64), y.astype(np.float64))
+        plans = []
+        for wxi, wyi in ((wx, wy), (wx.copy(), wy.copy()), (pwx, pwy)):
+            sims, info = svc.x2y(x, y, wx=wxi, wy=wyi)
+            got = np.asarray(sims, np.float64)
+            assert got.shape == (mx, my)
+            assert np.abs(got - ref).max() <= bound
+            assert info["fused_path"] == "kernel"
+            plans.append(plan_x2y(wxi, wyi, q))
+        assert plans[1] is plans[0]            # the repeat: one schema
+        assert plans[2] is not plans[0]        # the permutation: its own
+        assert plans[0].num_reducers > 1
