@@ -38,10 +38,10 @@ The registry executors:
                 execution"; Afrati et al., arXiv:1206.4377): each
                 reducer's sub-plan is replicated on ``r`` LPT-chosen
                 shards, the output matrix is row-sliced, replica holders
-                serve their slice's cells locally, and only the residual
-                entries cross shards in one batched all-to-all — assembly
-                bytes fall roughly as ``(1 - r/S)`` at the price of
-                ``r×`` input shipping.
+                serve their slice's cells locally, and only the cells
+                with no local holder cross shards, each once, in one
+                batched all-to-all — assembly bytes fall as r grows, at
+                the price of ``r×`` input shipping.
 ``streaming`` — delta execution of maintained plans (DESIGN.md "streaming
                 maintenance"; ``repro.stream``, registered lazily): only
                 the reducers an edit dirtied are recomputed, and the
@@ -50,7 +50,7 @@ The registry executors:
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -333,19 +333,22 @@ def _group_valid_slots(plan, cache_key, groups, count_y: bool) -> int:
     return n
 
 
-def _derived(plan, attr: str, key, what: str, build: Callable):
+def _derived(plan, attr: str, key, what: str, build: Callable,
+             attrs: Optional[Callable] = None):
     """A host artefact derived from ``plan``, built once and kept on the
     plan (dict ``attr``, under ``key``): a static artifact reused across
     waves, like the index matrix.  Looked up and built under a ``maps``
-    span (``what``, ``cached``)."""
+    span (``what``, ``cached``, and whatever ``attrs(value)`` adds)."""
     cache = plan.__dict__.get(attr)
     if cache is None:
         cache = {}
         object.__setattr__(plan, attr, cache)
     value = cache.get(key)
-    with _obs_span("maps", what=what, cached=value is not None):
+    with _obs_span("maps", what=what, cached=value is not None) as s:
         if value is None:
             value = cache[key] = build()
+        if s is not None and attrs is not None:
+            s.attrs.update(attrs(value))
     return value
 
 
@@ -1239,6 +1242,53 @@ class ShardedExecutor(Executor):
 # ---------------------------------------------------------------------------
 # coded (replicated shuffle) executor
 # ---------------------------------------------------------------------------
+# served-cell markers in ``_coded_maps``'s winner grid (>= 0: a block row)
+_SERVED_LOCAL, _SERVED_ZERO = -2, -3
+# block entries ``_coded_maps`` works on at a time (bounds its host memory)
+_CODED_CHUNK = 1 << 22
+
+
+class _SlotClass(NamedTuple):
+    """Live slots of one group with equal valid counts ``(nx, ny)``: a
+    dense ``(K, nx, ny)`` grid of block entries, whatever the masks.  The
+    entry ``(p, q)`` of a slot sits at ``base + p * wy + q`` in its
+    shard's value vector."""
+    shard: np.ndarray       # (K,) shard holding the slot
+    block: np.ndarray       # (K,) reducer row
+    base: np.ndarray        # (K,) offset of the slot's Gram block
+    px: np.ndarray          # (K, nx) valid x positions
+    py: np.ndarray          # (K, ny) valid y positions
+    gx: np.ndarray          # (K, nx) their global rows
+    gy: np.ndarray          # (K, ny) their global columns
+    wy: int
+
+
+def _slot_classes(groups, bases) -> list:
+    """Every live slot of the replica-stacked ``groups`` as
+    :class:`_SlotClass` es, in group, class, shard, slot order."""
+    classes = []
+    for gi, (xidx, xmask, yidx, ymask, rows) in enumerate(groups):
+        wx, wy = xidx.shape[2], yidx.shape[2]
+        nx, ny = xmask.sum(axis=2), ymask.sum(axis=2)
+        s_all, k_all = np.nonzero((nx > 0) & (ny > 0))
+        if not len(s_all):
+            continue
+        key = nx[s_all, k_all] * (wy + 1) + ny[s_all, k_all]
+        order = np.argsort(key, kind="stable")
+        _u, first = np.unique(key[order], return_index=True)
+        for sel in np.split(order, first[1:]):
+            s, k = s_all[sel], k_all[sel]
+            px = np.argsort(~xmask[s, k], axis=1, kind="stable")
+            py = np.argsort(~ymask[s, k], axis=1, kind="stable")
+            px, py = px[:, :nx[s[0], k[0]]], py[:, :ny[s[0], k[0]]]
+            classes.append(_SlotClass(
+                s, rows[s, k].astype(np.int64),
+                bases[gi] + k.astype(np.int64) * (wx * wy), px, py,
+                np.take_along_axis(xidx[s, k], px, 1).astype(np.int64),
+                np.take_along_axis(yidx[s, k], py, 1).astype(np.int64), wy))
+    return classes
+
+
 def _coded_maps(groups, shape: tuple[int, int], row_block: int,
                 zero_diag: bool):
     """Host-side maps for the coded combining stage.
@@ -1249,21 +1299,34 @@ def _coded_maps(groups, shape: tuple[int, int], row_block: int,
     all-false masks and are skipped).  The output ``(mx, my)`` matrix is
     row-sliced: shard ``s`` owns rows ``[s*row_block, (s+1)*row_block)``.
 
-    Per output cell the serving Gram entry is resolved to either a
-    position in the owning shard's *local* value vector (a replica is
-    held: zero traffic) or a slot in the residual exchange: for every
-    (block, destination) pair with no local replica, the block rows whose
-    output rows fall in the destination's slice — never the whole block —
-    are stride-split across ALL replica holders (least-filled lane
-    first), so each holder ships ~1/r of the residual and the exchange
-    lanes shrink as replication grows.  The residual is batched into
-    per-destination lanes and moved by ONE tiled all-to-all sized by the
-    maximum lane.
+    Each covered answer cell has ONE serving source, decided before any
+    entry is placed in a send lane.  Several blocks cover one cell where
+    the schema repeats coverage (the self-join's within-bin cells sit in
+    every reducer that holds the bin); their Gram entries are identical.
+
+    1. Local holder first: a cell covered by some block held on the
+       cell's owning shard is served from that shard's *local* value
+       vector (zero traffic).
+    2. Otherwise one residual block serves it: each block, for each
+       destination slice without a replica of it, claims only its cells
+       there that nothing serves yet, and those are stride-split across
+       ALL the block's holders (least-filled lane first), so each holder
+       ships ~1/r of them and the lanes stay balanced as r grows.
+
+    The residual is therefore exactly the covered cells without a local
+    holder, each shipped once, batched into per-destination lanes and
+    moved by ONE tiled all-to-all sized by the maximum lane.  The cells
+    are worked in bulk over :func:`_slot_classes`, ``_CODED_CHUNK`` block
+    entries at a time.
 
     Returns ``(sendmap (S, S, E) int32`` into the shard-local value
     vector, ``srcmap (S, row_block, my) int32`` into
-    ``[vals_local (Lv), recv (S*E)]``, and a stats dict).  Slot 0 of the
-    value vector is 0.0 (uncovered cells, padding lanes, the diagonal).
+    ``[vals_local (Lv), recv (S*E)]``, and a stats dict:
+    ``local_entries`` / ``residual_entries`` (cells served each way),
+    ``skipped_entries`` (valid block entries that serve no cell:
+    duplicates and, with ``zero_diag``, the diagonal), ``lane_max`` (E),
+    ``lane_fill``, ``vals_len`` (Lv)).  Slot 0 of the value vector is
+    0.0 (uncovered cells, padding lanes, the diagonal).
     """
     mx, my = shape
     S = groups[0][0].shape[0] if groups else 1
@@ -1272,74 +1335,130 @@ def _coded_maps(groups, shape: tuple[int, int], row_block: int,
     for xidx, _xm, yidx, _ym, _rows in groups:
         bases.append(Lv)
         Lv += xidx.shape[1] * xidx.shape[2] * yidx.shape[2]
+    assert Lv < 2 ** 31, "coded value vector outgrows int32 positions"
+    classes = _slot_classes(groups, bases)
 
-    # holders: global row -> [(shard, group, slot), ...] (replica set)
-    holders: dict[int, list] = {}
-    for gi, (_xi, xmask, _yi, ymask, rows) in enumerate(groups):
-        live = xmask.any(axis=2) & ymask.any(axis=2)      # (S, Rw)
-        for s, k in np.argwhere(live):
-            holders.setdefault(int(rows[s, k]), []).append(
-                (int(s), gi, int(k)))
+    # holders of each block: a shard bitmask and each holder's slot base;
+    # the lowest holder's slot stands for the block in the residual pass
+    nb = 1 + max((int(c.block.max()) for c in classes), default=-1)
+    hbits = np.zeros(nb, np.int64)
+    hbase = np.zeros((nb, S), np.int64)
+    for c in classes:
+        np.bitwise_or.at(hbits, c.block, np.int64(1) << c.shard)
+        hbase[c.block, c.shard] = c.base
+    lowest = hbits & -hbits
+    reps = [np.flatnonzero((np.int64(1) << c.shard) == lowest[c.block])
+            for c in classes]
 
-    send: list[list[list]] = [[[] for _ in range(S)] for _ in range(S)]
-    cnt = np.zeros((S, S), dtype=np.int64)
-    recv_fill: list[list] = [[] for _ in range(S)]
-    srcmap = np.zeros((S, row_block, my), dtype=np.int64)
-    local_entries = 0
-    for _b, hl in holders.items():
-        s0, gi, k0 = hl[0]
-        xidx, xmask, yidx, ymask, _rows = groups[gi]
-        wx, wy = xidx.shape[2], yidx.shape[2]
-        pv = np.flatnonzero(xmask[s0, k0])
-        qv = np.flatnonzero(ymask[s0, k0])
-        if not pv.size or not qv.size:
-            continue
-        gx = xidx[s0, k0][pv].astype(np.int64)
-        gy = yidx[s0, k0][qv].astype(np.int64)
-        ds = gx // row_block
-        hpos = {s: bases[g] + k * wx * wy for s, g, k in hl}
-        for s in np.unique(ds):
-            s = int(s)
-            sel = ds == s
-            p_s, gx_s = pv[sel], gx[sel]
-            if s in hpos:                      # local replica: no traffic
-                pos = hpos[s] + (p_s[:, None] * wy + qv[None, :])
-                srcmap[s][np.ix_(gx_s - s * row_block, gy)] = pos
-                local_entries += pos.size
-            else:                              # residual: split over holders
-                hs = sorted(hpos, key=lambda tt: cnt[tt, s])
-                for j, t in enumerate(hs):
-                    p_j, gx_j = p_s[j::len(hs)], gx_s[j::len(hs)]
-                    if not p_j.size:
-                        continue
-                    pos = hpos[t] + (p_j[:, None] * wy + qv[None, :])
-                    send[t][s].append(pos.ravel())
-                    recv_fill[s].append((t, int(cnt[t, s]), gx_j, gy))
-                    cnt[t, s] += pos.size
-    E = max(1, int(cnt.max(initial=0)))
-    sendmap = np.zeros((S, S, E), dtype=np.int64)
-    for t in range(S):
-        for s in range(S):
-            if send[t][s]:
-                v = np.concatenate(send[t][s])
-                sendmap[t, s, :len(v)] = v
-    for s in range(S):
-        for t, e0, gx_s, gy in recv_fill[s]:
-            e = e0 + np.arange(len(gx_s) * len(gy), dtype=np.int64)
-            srcmap[s][np.ix_(gx_s - s * row_block, gy)] = (
-                Lv + t * E + e.reshape(len(gx_s), len(gy)))
+    def chunks(c, slots):
+        step = max(1, _CODED_CHUNK // (c.px.shape[1] * c.py.shape[1]))
+        for i in range(0, len(slots), step):
+            yield slots[i:i + step]
+
+    def cells(c, ks, kk, pp):            # flat (mx, my) cells of rows
+        return c.gx[ks[kk], pp][:, None] * my + c.gy[ks[kk]]
+
+    winner = np.full(S * row_block * my, -1, np.int32)   # -1: unserved
+    src = np.zeros(S * row_block * my, np.int32)         # srcmap, flat
+    for c in classes:                                    # 1. local
+        for ks in chunks(c, np.arange(len(c.shard))):
+            kk, pp = np.nonzero(c.gx[ks] // row_block
+                                == c.shard[ks][:, None])
+            at = cells(c, ks, kk, pp)
+            src[at] = (c.base[ks[kk]] + c.px[ks[kk], pp] * c.wy)[:, None] \
+                + c.py[ks[kk]]
+            winner[at] = _SERVED_LOCAL
+    diag = np.arange(min(mx, my)) * (my + 1)
     if zero_diag:
-        for s in range(S):
-            d = np.arange(s * row_block, min((s + 1) * row_block, mx))
-            srcmap[s, d - s * row_block, d] = 0
+        winner[diag] = _SERVED_ZERO
+    local_entries = int(np.count_nonzero(winner == _SERVED_LOCAL))
+
+    # 2. residual: block by block, rows whose slice holds no replica claim
+    # their unserved cells (in one chunk, one claimant of a cell wins).
+    # Rows go in (block, destination) order, so each such pair's claimed
+    # entries come out contiguous; pair = block ordinal * S + destination
+    w_cell, w_rel = [np.zeros(0, np.int64)], [np.zeros(0, np.int64)]
+    row_pair, row_n = [np.zeros(0, np.int64)], [np.zeros(0, np.int64)]
+    rep_b = [np.zeros(0, np.int64)]
+    valid = nrep = 0
+    for c, rep in zip(classes, reps):
+        valid += len(rep) * c.px.shape[1] * c.py.shape[1]
+        for ks in chunks(c, rep):
+            ds = c.gx[ks] // row_block
+            kk, pp = np.nonzero(
+                ((hbits[c.block[ks]][:, None] >> ds) & 1) == 0)
+            o = np.argsort(kk * S + ds[kk, pp], kind="stable")
+            kk, pp = kk[o], pp[o]
+            at = cells(c, ks, kk, pp)
+            ri, qi = np.nonzero(winner[at] == -1)
+            at, kr = at[ri, qi], ks[kk[ri]]
+            winner[at] = c.block[kr]
+            won = winner[at] == c.block[kr]
+            ri, qi, kr = ri[won], qi[won], kr[won]
+            w_cell.append(at[won])
+            w_rel.append(c.px[kr, pp[ri]] * c.wy + c.py[kr, qi])
+            row_pair.append((nrep + kk) * S + ds[kk, pp])
+            row_n.append(np.bincount(ri, minlength=len(kk)))
+            rep_b.append(c.block[ks])
+            nrep += len(ks)
+    w_cell, w_rel, rep_b = map(np.concatenate, (w_cell, w_rel, rep_b))
+    row_n = np.concatenate(row_n)
+    row_pair = np.concatenate(row_pair)[row_n > 0]
+    heads = np.flatnonzero(np.diff(row_pair, prepend=-1))
+    count = (np.add.reduceat(row_n[row_n > 0], heads) if len(heads)
+             else np.zeros(0, np.int64))
+    starts = np.cumsum(count) - count          # first entry of each pair
+    blk, dest = rep_b[row_pair[heads] // S], row_pair[heads] % S
+
+    # lanes: each pair's cells stride-split over the block's holders,
+    # least-filled lane first: piece j of pair i (its ranks j, j + h, ...)
+    # goes to holder t at lane offset e0
+    cnt = [[0] * S for _ in range(S)]
+    holders: dict[int, list] = {}
+    h_max = max((bin(int(v)).count("1") for v in np.unique(hbits)),
+                default=1)
+    t = np.zeros((len(blk), h_max), np.int64)
+    e0 = np.zeros((len(blk), h_max), np.int64)
+    nh = np.ones(len(blk), np.int64)
+    for i, (bits, s, n) in enumerate(zip(hbits[blk].tolist(),
+                                         dest.tolist(), count.tolist())):
+        hs = holders.get(bits)
+        if hs is None:
+            hs = holders[bits] = [h for h in range(S) if bits >> h & 1]
+        if len(hs) > 1:
+            hs = sorted(hs, key=lambda h: cnt[h][s])
+            nh[i] = len(hs)
+        for j, h in enumerate(hs[:n]):
+            t[i, j], e0[i, j] = h, cnt[h][s]
+            cnt[h][s] += (n - j + len(hs) - 1) // len(hs)
+    E = max(1, max(max(row) for row in cnt))
+    assert Lv + S * E < 2 ** 31, "coded maps outgrow int32 positions"
+    # per piece: the holder's slot base, first send slot and srcmap value
+    base = hbase[blk[:, None], t].ravel()
+    send_at = ((t * S + dest[:, None]) * E + e0).ravel()
+    src_at = (Lv + t * E + e0).ravel()
+    sendmap = np.zeros(S * S * E, np.int32)
+    owner = np.repeat(np.arange(len(blk)), count)
+    for a in range(0, len(w_cell), _CODED_CHUNK):
+        e = slice(a, a + _CODED_CHUNK)
+        i = owner[e]
+        rank = np.arange(a, a + len(i)) - starts[i]
+        k = i * h_max + rank % nh[i]
+        rank //= nh[i]
+        sendmap[send_at[k] + rank] = base[k] + w_rel[e]
+        src[w_cell[e]] = src_at[k] + rank
+    if zero_diag:
+        src[diag] = 0
     stats = {
-        "local_entries": int(local_entries),
-        "residual_entries": int(cnt.sum()),
+        "local_entries": local_entries,
+        "residual_entries": len(w_cell),
+        "skipped_entries": int(valid - local_entries - len(w_cell)),
         "lane_max": E,
-        "lane_fill": float(cnt.sum() / max(S * S * E, 1)),
+        "lane_fill": float(len(w_cell) / (S * S * E)),
         "vals_len": int(Lv),
     }
-    return (sendmap.astype(np.int32), srcmap.astype(np.int32), stats)
+    return (sendmap.reshape(S, S, E), src.reshape(S, row_block, my),
+            stats)
 
 
 def _make_coded_jitted(metric, mesh, axes, use_kernel, interpret, bl):
@@ -1401,13 +1520,19 @@ class CodedExecutor(ShardedExecutor):
     sub-plan on r LPT-chosen shards, the output matrix is row-sliced
     across shards, and assembly becomes a coded combining stage — a shard
     holding a replica serves its slice's cells from local Gram entries
-    (zero traffic), and only the residual entries (block rows owned by a
-    slice with no replica) are exchanged, batched into per-destination
-    lanes and moved by ONE tiled all-to-all.  Per shard the residual is
-    ~``2G/S * (1 - r/S)`` entries (G = total Gram entries) vs ~``G`` for
-    the uncoded all-gather, so measured assembly bytes collapse and keep
-    falling as r grows; ``choose_replication`` picks the knee of the
-    replication-vs-communication frontier.
+    (zero traffic), and only the residual is exchanged, batched into
+    per-destination lanes and moved by ONE tiled all-to-all.  Each answer
+    cell is served from ONE source, a local holder first
+    (``_coded_maps``): the residual is exactly the covered cells without
+    a holder on their owning shard, each shipped once however many blocks
+    cover it (the self-join's within-bin cells sit in every reducer
+    holding the bin), against every Gram stack for the uncoded
+    all-gather — so measured assembly bytes collapse and keep falling as
+    r grows; ``choose_replication`` picks the knee of the
+    replication-vs-communication frontier.  Stats count
+    ``local_entries`` / ``residual_entries`` (cells served each way) and
+    ``skipped_entries`` (block entries no cell is served from: duplicates
+    and the diagonal; gauge ``executor.coded_skipped_entries``).
 
     Same fallback rules as the sharded executor (Gram-block reducers
     only); ``replication`` is clamped to the mesh's shard count.
@@ -1423,7 +1548,7 @@ class CodedExecutor(ShardedExecutor):
         return {"calls": 0, "coded": 0, "fallbacks": 0, "num_shards": 0,
                 "balance_factor": 0.0, "replication": 0,
                 "local_entries": 0, "residual_entries": 0,
-                "local_fraction": 0.0}
+                "skipped_entries": 0, "local_fraction": 0.0}
 
     # -- replication-aware partition plumbing (cached on the plan) --------
     def partition_coded(self, plan: ReducerPlan, num_shards: int,
@@ -1451,13 +1576,15 @@ class CodedExecutor(ShardedExecutor):
             plan, "_coded_maps_cache",
             (part.num_shards, part.replication, tuple(shape), zero_diag),
             "coded_maps",
-            lambda: _coded_maps(groups, tuple(shape), rb, zero_diag))
+            lambda: _coded_maps(groups, tuple(shape), rb, zero_diag),
+            lambda maps: {"skipped_entries": maps[2]["skipped_entries"]})
 
     def _note_coded(self, part: PlanPartition, mstats: dict) -> None:
         self._note(part)
         self._stats["replication"] = int(part.replication)
         self._stats["local_entries"] = mstats["local_entries"]
         self._stats["residual_entries"] = mstats["residual_entries"]
+        self._stats["skipped_entries"] = mstats["skipped_entries"]
         tot = mstats["local_entries"] + mstats["residual_entries"]
         self._stats["local_fraction"] = (
             mstats["local_entries"] / tot if tot else 1.0)
@@ -1466,6 +1593,9 @@ class CodedExecutor(ShardedExecutor):
         _REGISTRY_OBS.gauge("executor.local_fraction",
                             executor=self.name).set(
                                 self._stats["local_fraction"])
+        _REGISTRY_OBS.gauge("executor.coded_skipped_entries",
+                            executor=self.name).set(
+                                mstats["skipped_entries"])
 
     def _coded_dispatch(self, xt, yt, plan, metric, shape, zero_diag,
                         mesh, shard_axes, use_kernel, interpret, bl,
@@ -1613,6 +1743,7 @@ def coded_assembly_model(plan, num_shards: int, replication: int, m: int,
         "num_shards": S,
         "local_entries": st["local_entries"],
         "residual_entries": st["residual_entries"],
+        "skipped_entries": st["skipped_entries"],
         "local_fraction": (
             st["local_entries"]
             / max(st["local_entries"] + st["residual_entries"], 1)),

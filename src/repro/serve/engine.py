@@ -298,6 +298,7 @@ class PairwiseService:
                 "replication": ex_stats["replication"],
                 "local_fraction": ex_stats["local_fraction"],
                 "residual_entries": ex_stats["residual_entries"],
+                "skipped_entries": ex_stats["skipped_entries"],
             }
         return info
 

@@ -23,16 +23,21 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
-from repro.core import partition_plan, plan_a2a
+from repro.core import partition_plan, plan_a2a, plan_x2y
 from repro.mapreduce import (
     build_plan,
+    build_x2y_plan,
     get_executor,
     list_executors,
     make_executor,
     pairwise_similarity,
     x2y_similarity,
 )
+from repro.mapreduce import executors
 from repro.mapreduce.executors import (
+    _coded_maps,
+    _stacked_groups,
+    _stacked_rect_groups,
     choose_replication,
     coded_assembly_model,
 )
@@ -245,6 +250,23 @@ class TestCodedExecutorDifferential:
         assert 0.0 <= stats["local_fraction"] <= 1.0
         assert stats["local_entries"] + stats["residual_entries"] > 0
 
+    def test_skipped_entries_published(self):
+        """``skipped_entries`` reaches the instance stats, the gauge and
+        the ``maps`` span that built the coded maps."""
+        from repro.obs import REGISTRY, TRACER
+        plan, w = _zipf_plan(40)
+        x = _rand(np.random.default_rng(4), (40, 4))
+        ex = make_executor("coded")
+        TRACER.clear()
+        pairwise_similarity(x, q=1.0, weights=w, executor=ex)
+        got = ex.stats()["skipped_entries"]
+        assert got > 0
+        assert REGISTRY.snapshot()["gauges"][
+            "executor.coded_skipped_entries{executor=coded}"] == got
+        (span,) = [sp for sp in TRACER.spans() if sp.name == "maps"
+                   and sp.attrs["what"] == "coded_maps"]
+        assert span.attrs["skipped_entries"] == got
+
 
 # ---------------------------------------------------------- traffic model
 class TestCodedModelAndChooser:
@@ -289,6 +311,169 @@ class TestCodedModelAndChooser:
                 rec["replication"] * plan.comm_cost * 16 * 4)
 
 
+# ------------------------------------------------------- one source per cell
+def _shape(plan, rect=False):
+    if rect:
+        return plan.num_x, plan.num_y
+    m = int(np.asarray(plan.idx)[np.asarray(plan.mask)].max()) + 1
+    return m, m
+
+
+def _maps(plan, S, r, rect=False):
+    """The coded maps of ``plan`` on ``S`` shards at rate ``r``, with the
+    partition and the replica-stacked groups they came from."""
+    part = partition_plan(plan, S, replication=r)
+    if rect:
+        groups = _stacked_rect_groups(plan, part,
+                                      rows_by_shard=part.replica_rows)
+    else:
+        groups = [(i, k, i, k, rows) for i, k, rows in _stacked_groups(
+            plan, part, rows_by_shard=part.replica_rows)]
+    shape = _shape(plan, rect)
+    rb = -(-shape[0] // S)
+    return part, groups, rb, _coded_maps(groups, shape, rb, not rect)
+
+
+def _cover(plan, reducers=None, rect=False):
+    """Brute force over the plan's ``idx``/``mask`` (and ``yidx``/
+    ``ymask``): how many of ``reducers`` (default all) cover each cell,
+    self-pairs left out of a self-join."""
+    xs = np.asarray(plan.idx)
+    xm = np.asarray(plan.mask)
+    ys = np.asarray(plan.yidx) if rect else xs
+    ym = np.asarray(plan.ymask) if rect else xm
+    cover = np.zeros(_shape(plan, rect), np.int64)
+    for r in range(plan.num_reducers) if reducers is None else reducers:
+        cover[np.ix_(xs[r][xm[r]], ys[r][ym[r]])] += 1
+    if not rect:
+        np.fill_diagonal(cover, 0)
+    return cover
+
+
+def _valid_entries(plan, rect=False):
+    xm = np.asarray(plan.mask)[:plan.num_reducers]
+    ym = np.asarray(plan.ymask)[:plan.num_reducers] if rect else xm
+    return int((xm.sum(axis=1) * ym.sum(axis=1)).sum())
+
+
+def _assemble(groups, sendmap, srcmap, x, y):
+    """The device program's combining stage, in NumPy: per shard the
+    value vector of its Gram blocks, the lanes it sends, the lanes it
+    receives, then the source-map gather; rows of every shard stacked."""
+    S = sendmap.shape[0]
+    vals = []
+    for s in range(S):
+        v = [np.zeros(1)]
+        for xi, xm, yi, ym, _rows in groups:
+            g = np.einsum("kpd,kqd->kpq", x[xi[s]], y[yi[s]])
+            v.append((g * xm[s][:, :, None] * ym[s][:, None, :]).ravel())
+        vals.append(np.concatenate(v))
+    send = [vals[t][sendmap[t]] for t in range(S)]
+    return np.concatenate([
+        np.concatenate([vals[s]] + [send[t][s] for t in range(S)])[srcmap[s]]
+        for s in range(S)])
+
+
+def _x2y_plan(seed=11, nx=30, ny=23):
+    rng = np.random.default_rng(seed)
+    schema = plan_x2y(rng.uniform(0.05, 0.3, nx), rng.uniform(0.05, 0.3, ny),
+                      1.0)
+    return build_x2y_plan(schema, nx)
+
+
+RATES = [(4, 1), (4, 2), (4, 4), (8, 1), (8, 2), (8, 8)]
+
+
+class TestOneSourcePerCell:
+    """``_coded_maps`` serves each covered cell from one source, a local
+    holder first, checked against counts made by brute force over the
+    plan's cells."""
+
+    @pytest.mark.parametrize("num_shards,r", RATES)
+    def test_each_covered_cell_served_once(self, num_shards, r):
+        plan, _ = _zipf_plan(64)
+        *_, (_send, _src, stats) = _maps(plan, num_shards, r)
+        covered = int(np.count_nonzero(_cover(plan)))
+        assert stats["local_entries"] + stats["residual_entries"] == covered
+
+    @pytest.mark.parametrize("num_shards", [4, 8])
+    def test_r1_residual_is_cells_without_owner_holder(self, num_shards):
+        plan, _ = _zipf_plan(64)
+        part, _g, rb, (_send, _src, stats) = _maps(plan, num_shards, 1)
+        m = _shape(plan)[0]
+        held = np.stack([_cover(plan, rows) for rows in part.replica_rows])
+        local = held[np.arange(m) // rb, np.arange(m)] > 0
+        want = int(np.count_nonzero((_cover(plan) > 0) & ~local))
+        assert stats["residual_entries"] == want
+        assert stats["local_entries"] == int(np.count_nonzero(local))
+
+    @pytest.mark.parametrize("num_shards,r", RATES)
+    def test_no_cell_in_two_lanes(self, num_shards, r):
+        plan, _ = _zipf_plan(64)
+        *_, (sendmap, srcmap, stats) = _maps(plan, num_shards, r)
+        shipped = int(np.count_nonzero(sendmap))     # slot 0 pads lanes
+        assert shipped == stats["residual_entries"]
+        lv = stats["vals_len"]
+        for s in range(num_shards):
+            recv = srcmap[s][srcmap[s] >= lv] - lv   # lane t, offset e
+            assert len(np.unique(recv)) == len(recv)
+            t, e = recv // stats["lane_max"], recv % stats["lane_max"]
+            assert np.all(sendmap[t, s, e] > 0)
+        assert int((srcmap >= lv).sum()) == shipped
+
+    def test_skipped_entries_count_duplicates(self):
+        """A self-join plan whose bins hold many inputs covers within-bin
+        cells many times: every entry past a cell's first is skipped, with
+        the diagonal.  An X2Y plan covers each cell once and skips none."""
+        plan = build_plan(plan_a2a(np.full(48, 0.06), 1.0))
+        assert _cover(plan).max() > 2
+        for num_shards, r in RATES:
+            *_, (_s, _m, st) = _maps(plan, num_shards, r)
+            want = _valid_entries(plan) - int(np.count_nonzero(_cover(plan)))
+            assert st["skipped_entries"] == want > 0
+        xplan = _x2y_plan()
+        assert _cover(xplan, rect=True).max() == 1
+        for num_shards, r in RATES:
+            *_, (_s, _m, st) = _maps(xplan, num_shards, r, rect=True)
+            assert st["skipped_entries"] == 0
+            assert (st["local_entries"] + st["residual_entries"]
+                    == _valid_entries(xplan, rect=True))
+
+    @pytest.mark.parametrize("num_shards,r", RATES)
+    @pytest.mark.parametrize("chunk", [None, 50])
+    def test_assembled_answer_matches_brute_force(self, num_shards, r, chunk,
+                                                  monkeypatch):
+        """The maps, run through the combining stage in NumPy, give every
+        covered cell its dot product and 0 elsewhere, whether the blocks
+        claim their cells in one chunk or in many."""
+        if chunk is not None:
+            monkeypatch.setattr(executors, "_CODED_CHUNK", chunk)
+        plan = build_plan(plan_a2a(np.full(40, 0.06), 1.0))
+        _p, groups, _rb, (sendmap, srcmap, _st) = _maps(plan, num_shards, r)
+        x = np.random.default_rng(num_shards * 10 + r).normal(size=(40, 5))
+        got = _assemble(groups, sendmap, srcmap, x, x)[:40]
+        want = np.where(_cover(plan) > 0, x @ x.T, 0.0)
+        np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)
+
+    @pytest.mark.parametrize("num_shards,r", [(4, 1), (8, 2)])
+    def test_x2y_answer_matches_brute_force(self, num_shards, r):
+        plan = _x2y_plan()
+        _p, groups, _rb, (sendmap, srcmap, _st) = _maps(
+            plan, num_shards, r, rect=True)
+        rng = np.random.default_rng(r)
+        x, y = rng.normal(size=(30, 5)), rng.normal(size=(23, 5))
+        got = _assemble(groups, sendmap, srcmap, x, y)[:30]
+        want = np.where(_cover(plan, rect=True) > 0, x @ y.T, 0.0)
+        np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)
+
+    def test_lanes_balanced_at_r2(self):
+        """Stride split and least-filled lanes apply to the cells each
+        block serves: the largest lane stays near the mean lane."""
+        plan, _ = _zipf_plan(96)
+        *_, (_s, _m, st) = _maps(plan, 8, 2)
+        assert st["lane_max"] <= 2 * st["residual_entries"] / (8 * 7)
+
+
 # ------------------------------------------------- forced 8-device CPU mesh
 SCRIPT = textwrap.dedent("""
     import os
@@ -331,6 +516,33 @@ SCRIPT = textwrap.dedent("""
     b_c = collective_bytes(hlo_c)["total"]
     assert collective_bytes(hlo_c)["all-to-all"] > 0, hlo_c[:2000]
     assert b_c < b_s, (b_c, b_s)
+
+    # many inputs a bin: each bin's within-bin cells are covered by every
+    # reducer that holds the bin, and those reducers lie on several shards
+    from repro.core import partition_plan
+    from repro.mapreduce import make_executor
+    m = 64
+    w = np.full(m, 0.06)
+    x = jnp.asarray(rng.normal(size=(m, 6)).astype(np.float32))
+    schema = plan_a2a(w, 1.0)
+    s_d, plan, _ = pairwise_similarity(x, q=1.0, weights=w, schema=schema,
+                                       executor="dense")
+    owner = {}
+    for s, rows in enumerate(partition_plan(plan, 8).shard_rows):
+        for r in rows:
+            ids = np.asarray(plan.idx)[r][np.asarray(plan.mask)[r]]
+            for i in ids:
+                for j in ids:
+                    if i != j:
+                        owner.setdefault((int(i), int(j)), set()).add(s)
+    assert max(len(v) for v in owner.values()) > 1
+    for r in (1, 2):
+        ex = make_executor("coded", replication=r)
+        s_c, _, _ = pairwise_similarity(x, q=1.0, weights=w, schema=schema,
+                                        executor=ex)
+        np.testing.assert_allclose(np.asarray(s_c), np.asarray(s_d),
+                                   rtol=1e-4, atol=1e-4)
+        assert ex.stats()["skipped_entries"] > 0, ex.stats()
     print("CODED_OK", b_c / b_s)
 """)
 

@@ -130,6 +130,21 @@ class TestPairwiseService:
         assert info["optimality_gap"] is None or info["optimality_gap"] >= 1.0
         assert svc.stats["requests"] == 1
 
+    def test_coded_info_reports_skipped_entries(self):
+        """``info["coded"]`` carries the coded executor's serving counts,
+        the entries no cell is served from among them."""
+        rng = np.random.default_rng(2)
+        m = 24
+        x = rng.normal(size=(m, 4)).astype(np.float32)
+        svc = PairwiseService(q=1.0, executor="coded")
+        sims, info = svc.similarity(x, weights=np.full(m, 0.1))
+        np.testing.assert_allclose(
+            np.asarray(sims), x @ x.T * (1 - np.eye(m, dtype=np.float32)),
+            rtol=1e-4, atol=1e-4)
+        coded = info["coded"]
+        assert coded["skipped_entries"] > 0
+        assert coded["residual_entries"] >= 0
+
     def test_some_pairs_masked_to_request(self):
         rng = np.random.default_rng(1)
         m = 16
