@@ -188,10 +188,11 @@ def run_fused(m: int = 512, d: int = 64, q: float = 1.0,
     # tiled dataflow check: with bl below the bucket widths, multi-tile
     # buckets must stream (Rb, bl, d) tiles — their full (Rb, Lb, d)
     # gather must not appear anywhere in the lowered program
-    from repro.mapreduce.engine import lower_reducers_fused
+    from repro.mapreduce.executors import get_executor
     tiled_bl = 8
-    tiled_hlo = lower_reducers_fused((m, d), plan, "dot", mesh=None,
-                                     bl=tiled_bl).compile().as_text()
+    tiled_hlo = get_executor("fused").lower(
+        (m, d), plan, metric="dot", mesh=None,
+        bl=tiled_bl).compile().as_text()
     tiled_gathers = {
         f"{b.idx.shape[0]}x{b.idx.shape[1]}x{d}": has_buffer_shape(
             tiled_hlo, (b.idx.shape[0], b.idx.shape[1], d))

@@ -23,23 +23,16 @@ Public API
     padded only to its own power-of-two width (DESIGN.md "bucketed
     shuffle execution").  ``combine='dense'`` reproduces the dense output
     layout; ``combine='buckets'`` keeps per-bucket outputs unpadded.
-``run_reducers_fused(inputs, plan, reducer_fn, mesh=...)``
-    Fused path (DESIGN.md "fused shuffle execution"): Gram-block reducers
-    stream the shuffle straight into the MXU via the fused gather+Gram
-    Pallas kernel (jnp tile-twin off-TPU) — all buckets in one program,
-    the padded gather never written to HBM.  Non-Gram reducers fall back
-    to the bucketed path.
-``run_reducers_sharded(inputs, plan, reducer_fn, mesh=...)``
-    Shard-balanced multi-device path (DESIGN.md "sharded execution"):
-    ``repro.core.planner.partition_plan`` LPT-balances reducers over the
-    mesh's reducer axis; each shard runs the fused tile pipeline under
-    ``shard_map``, with one cross-shard gather for assembly.
 ``get_executor(name)`` / ``make_executor(name)`` / ``register_executor``
     The executor registry (``repro.mapreduce.executors``): executors are
-    classes exposing ``run`` / ``run_pairs`` / ``lower`` / ``stats`` and
-    registered by name ("dense", "bucketed", "fused", "sharded", "coded",
-    "streaming") — the single dispatch point for every application entry
-    below.
+    classes exposing ``run`` / ``run_pairs`` / ``run_x2y`` / ``lower`` /
+    ``stats`` and registered by name ("dense", "bucketed", "fused",
+    "sharded", "coded", "streaming") — the single dispatch point for every
+    application entry below.  The Gram executors ("fused", "sharded",
+    "coded") run one pipeline: a self-join is the X2Y job with X = Y (the
+    plan's ids on both sides) and its diagonal zeroed, so one
+    gather+Gram program and one source-map assembly serve the self-join,
+    X2Y joins and tiles.
 ``pairwise_similarity(x, q=...)``
     A2A application: all-pairs similarity through a planned schema.
 ``some_pairs_similarity(x, pairs, q=...)``
@@ -67,8 +60,6 @@ from .engine import (
     jit_cache_stats,
     run_reducers,
     run_reducers_bucketed,
-    run_reducers_fused,
-    run_reducers_sharded,
     run_reducers_x2y,
     run_reducers_x2y_bucketed,
 )
@@ -93,8 +84,7 @@ from .skewjoin import join, skew_join
 __all__ = [
     "ReducerBucket", "ReducerPlan", "SparsePlan", "build_plan",
     "build_sparse_plan", "block_subplan", "build_x2y_plan",
-    "run_reducers", "run_reducers_bucketed", "run_reducers_fused",
-    "run_reducers_sharded", "run_reducers_x2y",
+    "run_reducers", "run_reducers_bucketed", "run_reducers_x2y",
     "run_reducers_x2y_bucketed",
     "Executor", "get_executor", "make_executor", "register_executor",
     "list_executors",

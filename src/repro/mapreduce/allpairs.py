@@ -28,8 +28,8 @@ from repro.core import (plan_a2a, plan_a2a_hierarchical, plan_some_pairs,
 from repro.core.schema import MappingSchema
 
 from .engine import (ReducerPlan, SparsePlan, build_plan,
-                     build_sparse_plan, build_x2y_plan)
-from .executors import _fill_source_map, get_executor
+                     build_sparse_plan, build_x2y_plan, y_side)
+from .executors import _derived, _fill_source_map, get_executor
 
 __all__ = [
     "pairwise_similarity",
@@ -156,25 +156,30 @@ def _x2y_plan_for(schema, num_x: int, *, pad_reducers_to: int,
     return plan
 
 
-def _pair_source_map_rect(plan: ReducerPlan, mx: int,
-                          my: int) -> np.ndarray:
-    """Rectangular inverse-shuffle map: (mx, my) int32 positions into the
-    concatenation ``[0.0, blocks_0.ravel(), ...]`` of per-bucket cross-Gram
-    stacks.  Like :func:`_pair_source_map` with decoupled axes — rows come
-    from each bucket's X-side ids, columns from its Y-side ids, and there
-    is no diagonal to zero (an (x, y) pair is never a self-pair).
-    Uncovered cells point at slot 0 (-> 0.0).  Cached on the plan; looked
-    up and built under a ``maps`` span."""
-    cached = plan.__dict__.get("_pair_srcmap_rect")
-    hit = cached is not None and cached[0] == (mx, my)
-    with _obs_span("maps", what="srcmap", cached=hit):
-        if hit:
-            return cached[1]
+def _pair_source_map_rect(plan: ReducerPlan, mx: int, my: int,
+                          zero_diag: bool = False) -> np.ndarray:
+    """Inverse-shuffle map of the fused assembly: (mx, my) int32 positions
+    into the concatenation ``[0.0, blocks_0.ravel(), ...]`` of per-bucket
+    Gram stacks (bucket order = ``plan.buckets``).  Rows come from each
+    bucket's X-side ids, columns from its Y side (a self-join's Y side is
+    its X side, ``engine.y_side``).
+
+    A cell covered by several reducers keeps one (deterministic) source —
+    duplicate block values agree exactly, so assembly is a gather instead
+    of the bucketed path's max-combine scatter.  Uncovered cells, and with
+    ``zero_diag`` the diagonal (a self-join's self-pairs), point at slot 0
+    (-> 0.0).  Cached on the plan by (shape, ``zero_diag``): like the
+    index matrix, a static artifact reused across waves; looked up and
+    built under a ``maps`` span."""
+    def build():
         srcmap = np.zeros((mx, my), np.int32)
-        _fill_source_map(srcmap, ((b.idx, b.mask, b.yidx, b.ymask)
+        _fill_source_map(srcmap, ((b.idx, b.mask, *y_side(b))
                                   for b in plan.buckets))
-        object.__setattr__(plan, "_pair_srcmap_rect", ((mx, my), srcmap))
-    return srcmap
+        if zero_diag:
+            np.fill_diagonal(srcmap, 0)
+        return srcmap
+    return _derived(plan, "_pair_srcmap_cache", (mx, my, zero_diag),
+                    "srcmap", build)
 
 
 @jax.jit
@@ -228,39 +233,6 @@ def assemble_x2y_matrix_bucketed(per_bucket, shape: tuple[int, int]):
         out = out.at[rows.reshape(-1), cols.reshape(-1)].set(
             blocks.reshape((-1,) + trailing))
     return out[:mx, :my]
-
-
-def _pair_source_map(plan: ReducerPlan, m: int) -> np.ndarray:
-    """Inverse-shuffle map for fused assembly: (m, m) int32 positions into
-    the concatenation ``[0.0, blocks_0.ravel(), blocks_1.ravel(), ...]`` of
-    per-bucket Gram stacks (bucket order = ``plan.buckets``).
-
-    A pair covered by several reducers keeps one (deterministic) source —
-    duplicate block values agree exactly, so assembly becomes a gather
-    instead of the bucketed path's max-combine scatter.  Uncovered cells
-    and the diagonal point at slot 0 (-> 0.0).  Cached on the plan: like
-    the index matrix itself, it is a static artifact reused across waves;
-    looked up and built under a ``maps`` span.
-    """
-    cached = plan.__dict__.get("_pair_srcmap")
-    hit = cached is not None and cached[0] == m
-    with _obs_span("maps", what="srcmap", cached=hit):
-        if hit:
-            return cached[1]
-        srcmap = np.zeros((m, m), np.int32)
-        _fill_source_map(srcmap, ((b.idx, b.mask, b.idx, b.mask)
-                                  for b in plan.buckets))
-        np.fill_diagonal(srcmap, 0)
-        object.__setattr__(plan, "_pair_srcmap", (m, srcmap))
-    return srcmap
-
-
-def _assemble_from_srcmap(per_bucket, srcmap):
-    """Traced fused-assembly step: gather the (m, m) matrix from the
-    concatenated bucket blocks through the inverse-shuffle map."""
-    vals = [jnp.zeros((1,), jnp.float32)]
-    vals += [g.reshape(-1) for _, g in per_bucket]
-    return jnp.take(jnp.concatenate(vals), srcmap, axis=0)
 
 
 def _run_and_assemble(x, plan, fn, m, mesh, executor,

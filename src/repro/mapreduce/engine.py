@@ -17,8 +17,7 @@ Executors share the plan format and are registered by name in
 ``repro.mapreduce.executors`` (the Executor protocol + registry; DESIGN.md
 "executor registry").  This module is the shared substrate: the plan
 builder, the bounded jit cache, and the dense/bucketed implementations the
-executor classes wrap.  The historical entry points below stay as thin
-shims over the registry so existing callers keep working:
+executor classes wrap:
 
 ``run_reducers``           — the dense path: one gather padded to the global
                              max slot count.  Simple, one XLA program, but a
@@ -32,20 +31,6 @@ shims over the registry so existing callers keep working:
                              one vmapped gather+reduce per bucket, each
                              padded only to its own bucket width, outputs
                              reassembled in original reducer order.
-``run_reducers_fused``     — shim over ``get_executor("fused")`` (DESIGN.md
-                             "fused shuffle execution"): for Gram-block
-                             reducers the shuffle streams straight into the
-                             MXU through the fused gather+Gram Pallas
-                             kernel — the padded gather never round-trips
-                             through HBM, and all buckets run in one
-                             program.  Non-Gram reducers fall back to the
-                             bucketed path.
-``run_reducers_sharded``   — shim over ``get_executor("sharded")`` (DESIGN.md
-                             "sharded execution"): the plan is LPT-balanced
-                             into per-shard sub-plans
-                             (``repro.core.planner.partition_plan``) and the
-                             fused/bucketed pipeline runs per shard under
-                             ``shard_map`` over the mesh's reducer axis.
 """
 
 from __future__ import annotations
@@ -80,11 +65,8 @@ __all__ = [
     "run_reducers_bucketed",
     "run_reducers_x2y",
     "run_reducers_x2y_bucketed",
-    "run_reducers_fused",
-    "run_reducers_sharded",
     "lower_reducers",
     "lower_reducers_bucketed",
-    "lower_reducers_fused",
     "jit_cache_stats",
     "configure_jit_cache",
     "block_cache_stats",
@@ -223,6 +205,14 @@ class ReducerPlan:
 
     def bucket_widths(self) -> list[int]:
         return [b.width for b in self.buckets]
+
+
+def y_side(p) -> tuple[np.ndarray, np.ndarray]:
+    """The Y-side gather rows ``(yidx, ymask)`` of a plan or bucket.  A
+    square (self-join) plan's Y side is its X side, ``(idx, mask)`` — the
+    same arrays, not copies — so every Gram program serves the self-join
+    as the X2Y job with X = Y."""
+    return (p.idx, p.mask) if p.yidx is None else (p.yidx, p.ymask)
 
 
 def _build_buckets(expanded: list[list[int]], *, pad_slots_to: int,
@@ -922,12 +912,12 @@ def _get_jitted_x2y(reducer_fn, mesh, shard_axes):
 
 
 def _as_tables(tables):
-    """(x_table, y_table) from a pair or a single shared table (X == Y)."""
+    """(x_table, y_table) from a pair or a single shared table (X == Y:
+    one array, put on the device once)."""
     if isinstance(tables, (tuple, list)):
-        xt, yt = tables
-    else:
-        xt = yt = tables
-    return jnp.asarray(xt), jnp.asarray(yt)
+        return jnp.asarray(tables[0]), jnp.asarray(tables[1])
+    t = jnp.asarray(tables)
+    return t, t
 
 
 def run_reducers_x2y(
@@ -1011,9 +1001,9 @@ def run_reducers_x2y_bucketed(
 
 
 # ---------------------------------------------------------------------------
-# fused + sharded executors: thin shims over the executor registry
+# fused dispatch counters, aggregated over the executor registry
 # ---------------------------------------------------------------------------
-# The implementations live in ``repro.mapreduce.executors`` as registry
+# The executors live in ``repro.mapreduce.executors`` as registry
 # objects with instance-scoped ``stats()``/``reset()``.  ``fused_stats()``
 # below is the documented *aggregate* view: every ``FusedExecutor``
 # instance publishes its increments into the obs registry's
@@ -1043,30 +1033,6 @@ def reset_fused_stats() -> None:
         _OBS_REGISTRY.reset_counters(f"executor.{k}", executor="fused")
     for k in FUSED_STATS:
         FUSED_STATS[k] = 0
-
-
-def run_reducers_fused(inputs, plan, reducer_fn, **kwargs):
-    """Fused shuffle execution: the gathered block stays out of HBM.
-
-    Shim over ``get_executor("fused").run`` — see
-    :class:`repro.mapreduce.executors.FusedExecutor` for the full contract
-    (per-bucket fused gather+Gram kernel / jnp tile-twin, one jitted program
-    for all buckets, bucketed fallback for non-Gram reducers).
-    """
-    from .executors import get_executor
-    return get_executor("fused").run(inputs, plan, reducer_fn, **kwargs)
-
-
-def run_reducers_sharded(inputs, plan, reducer_fn, **kwargs):
-    """Shard-balanced multi-device execution (DESIGN.md "sharded execution").
-
-    Shim over ``get_executor("sharded").run`` — see
-    :class:`repro.mapreduce.executors.ShardedExecutor`: the plan is
-    LPT-partitioned into per-shard sub-plans and the fused/bucketed pipeline
-    runs per shard under ``shard_map`` over the mesh's reducer axis.
-    """
-    from .executors import get_executor
-    return get_executor("sharded").run(inputs, plan, reducer_fn, **kwargs)
 
 
 def lower_reducers(
@@ -1125,25 +1091,3 @@ def lower_reducers_bucketed(
         mask = jax.ShapeDtypeStruct(b.mask.shape, jnp.bool_)
         out.append((b, fn.lower(x, idx, mask)))
     return out
-
-
-def lower_reducers_fused(
-    input_shape: tuple[int, int],
-    plan: ReducerPlan,
-    metric: str,
-    mesh: Optional[jax.sharding.Mesh] = None,
-    dtype=jnp.float32,
-    shard_axes: Optional[tuple[str, ...]] = None,
-    combine: str = "buckets",
-    use_kernel: bool = False,
-    bl: int = 128,
-):
-    """Lower the fused executor's single all-bucket program (no execution).
-
-    Shim over ``get_executor("fused").lower``; defaults to the streamed
-    (jnp) lowering so the dry-run works on any backend.  Returns one
-    ``Lowered``."""
-    from .executors import get_executor
-    return get_executor("fused").lower(
-        input_shape, plan, metric=metric, mesh=mesh, dtype=dtype,
-        shard_axes=shard_axes, combine=combine, use_kernel=use_kernel, bl=bl)

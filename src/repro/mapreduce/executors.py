@@ -67,7 +67,6 @@ from repro.obs import span as _obs_span
 
 from . import engine as _engine
 from .engine import (
-    ReducerBucket,
     ReducerPlan,
     _as_tables,
     _cache_get,
@@ -78,6 +77,7 @@ from .engine import (
     run_reducers_x2y,
     run_reducers_x2y_bucketed,
     upload_span,
+    y_side,
 )
 
 __all__ = [
@@ -212,6 +212,24 @@ class Executor:
         self._count("fallbacks")
         _EVENTS.emit("executor_fallback", executor=self.name, reason=reason)
 
+    def _bucketed_answer(self, tables, plan, reducer_fn, shape, workload,
+                         mesh, reason: str):
+        """Serve an answer the Gram programs cannot (a reducer without a
+        ``fused_metric`` tag, or nothing to run) on the bucketed path:
+        identical outputs, counted as a fallback."""
+        from .allpairs import (assemble_pair_matrix_bucketed,
+                               assemble_x2y_matrix_bucketed)
+        self._count_fallback(reason)
+        self._reconcile(plan, workload, _as_tables(tables)[0],
+                        measured_slots=_bucket_valid_slots(plan))
+        if workload == "x2y":
+            return assemble_x2y_matrix_bucketed(run_reducers_x2y_bucketed(
+                tables, plan, reducer_fn, mesh=mesh, combine="buckets"),
+                shape)
+        return assemble_pair_matrix_bucketed(run_reducers_bucketed(
+            tables, plan, reducer_fn, mesh=mesh, combine="buckets"),
+            shape[0])
+
     def _reconcile(self, plan, workload: str, table, *,
                    measured_slots: int, replication: float = 1.0,
                    assembled_bytes: int = 0, local_bytes: int = 0,
@@ -247,18 +265,15 @@ class Executor:
 
 def _plan_stacks(plan, dense: bool) -> list:
     """(X-side, Y-side) gather rows of each stack of reducer blocks: the
-    plan's capacity buckets, or its dense rows (``dense``).  A square
-    stack's rows serve both sides."""
-    if dense:
-        return [(plan.idx, plan.idx if plan.yidx is None else plan.yidx)]
-    return [(b.idx, b.idx if b.yidx is None else b.yidx)
-            for b in plan.buckets]
+    plan's capacity buckets, or its dense rows (``dense``)."""
+    return [(p.idx, y_side(p)[0])
+            for p in ([plan] if dense else plan.buckets)]
 
 
 def _group_stacks(groups) -> list:
-    """(X-side, Y-side) gather rows of stacked shard groups: 5-tuples
-    (xi, xm, yi, ym, rows) or square 3-tuples (idx, mask, rows)."""
-    return [(g[0], g[2] if len(g) >= 5 else g[0]) for g in groups]
+    """(X-side, Y-side) gather rows of stacked shard groups
+    (xi, xm, yi, ym, rows)."""
+    return [(g[0], g[2]) for g in groups]
 
 
 def _block_entries(stacks) -> int:
@@ -309,26 +324,19 @@ def _bucket_valid_slots(plan) -> int:
     return n
 
 
-def _group_valid_slots(plan, cache_key, groups, count_y: bool) -> int:
-    """Valid gather slots in stacked shard groups (the sharded/coded
-    executors' measured side).  5-tuple groups carry (xi, xm, yi, ym,
-    rows); ``count_y=False`` for the square coded path, where xm and ym
-    are the same gather and copies must be counted once.  Cached on the
-    plan per (shards, replication, rect) key."""
+def _group_valid_slots(plan, cache_key, groups) -> int:
+    """Valid gather slots in stacked shard groups (xi, xm, yi, ym, rows),
+    the sharded/coded executors' measured side.  A self-join's Y side is
+    the same gather as its X side and is counted once.  Cached on the plan
+    per (executor, shards[, replication]) key."""
     cache = plan.__dict__.get("_obs_group_slots")
     if cache is None:
         cache = {}
         object.__setattr__(plan, "_obs_group_slots", cache)
     n = cache.get(cache_key)
     if n is None:
-        n = 0
-        for grp in groups:
-            if len(grp) >= 5:
-                n += int(np.asarray(grp[1]).sum())
-                if count_y:
-                    n += int(np.asarray(grp[3]).sum())
-            else:                       # (idx, mask, rows) square stack
-                n += int(np.asarray(grp[1]).sum())
+        n = sum(int(g[1].sum()) + (int(g[3].sum()) if plan.is_rect else 0)
+                for g in groups)
         cache[cache_key] = n
     return n
 
@@ -483,33 +491,41 @@ class BucketedExecutor(Executor):
 # ---------------------------------------------------------------------------
 # fused (gather+Gram megakernel) executor
 # ---------------------------------------------------------------------------
-def _finish_fused_blocks(g, mask, metric: str):
-    """Metric post-processing of a masked per-reducer Gram stack.
+def _kernel_for(use_kernel) -> bool:
+    """The Gram executors' one kernel rule: the Pallas kernel on TPU or
+    where the caller asks for it (``use_kernel=True``), else its streamed
+    jnp twin."""
+    return bool(use_kernel) or jax.default_backend() == "tpu"
 
-    Mirrors ``allpairs.block_similarity`` exactly: norms are the Gram
-    diagonal (masked rows were zeroed at gather time, so their norms are 0),
-    invalid pairs -> 0.
-    """
-    if metric != "dot":
-        n2 = jnp.diagonal(g, axis1=1, axis2=2)            # (Rb, Lb)
-        if metric == "l2":
-            g = n2[:, :, None] + n2[:, None, :] - 2.0 * g
-        elif metric == "cosine":
-            nrm = jnp.sqrt(n2 + 1e-9)
-            g = g / (nrm[:, :, None] * nrm[:, None, :])
-        else:
-            raise ValueError(metric)
-    valid = mask[:, :, None] & mask[:, None, :]
-    return jnp.where(valid, g, 0.0)
+
+def _specs(tree):
+    """ShapeDtypeStructs of a tree of host arrays, for AOT lowering."""
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jax.dtypes.canonicalize_dtype(a.dtype)), tree)
+
+
+def _scatter_dense(stacks, R: int, shape) -> jax.Array:
+    """Gram blocks back in plan-row order, zero-padded to the dense
+    ``shape`` (the ``combine='dense'`` layout of ``run_reducers``).
+    ``stacks`` pairs host row ids with their block stacks; padding rows
+    (-1, or ``R`` and above) land in a scratch row."""
+    acc = jnp.zeros((R + 1, *shape), jnp.float32)
+    for rows, g in stacks:
+        rows = np.asarray(rows).reshape(-1)
+        g = g.reshape(-1, *g.shape[-2:])
+        acc = acc.at[np.where((rows >= 0) & (rows < R), rows, R)].set(
+            jnp.pad(g, ((0, 0), (0, shape[0] - g.shape[1]),
+                        (0, shape[1] - g.shape[2]))))
+    return acc[:R]
 
 
 def _finish_rect_blocks(g, xidx, xmask, yidx, ymask, n2x, n2y, metric: str):
-    """Metric post-processing of a masked rectangular cross-Gram stack.
+    """Metric post-processing of a masked cross-Gram stack.
 
-    Mirrors ``allpairs.block_similarity_x2y`` exactly.  Cross blocks carry
-    no Gram diagonal, so per-row squared norms are gathered from the
-    table-level vectors ``n2x``/``n2y`` (masked slots -> 0, matching the
-    zero-masked gathers of the reference path); invalid pairs -> 0.
+    Mirrors ``allpairs.block_similarity_x2y`` exactly.  Per-row squared
+    norms are gathered from the table-level vectors ``n2x``/``n2y``
+    (masked slots -> 0, matching the zero-masked gathers of the reference
+    path); invalid pairs -> 0.
     """
     if metric != "dot":
         gx = jnp.where(xmask, jnp.take(n2x, xidx, axis=0), 0.0)  # (Rb, Lx)
@@ -525,49 +541,6 @@ def _finish_rect_blocks(g, xidx, xmask, yidx, ymask, n2x, n2y, metric: str):
     return jnp.where(valid, g, 0.0)
 
 
-def _scatter_rows(bucket: ReducerBucket, R: int) -> np.ndarray:
-    """Bucket rows for drop-style scatter: padding rows (-1) -> row R."""
-    return np.where(bucket.rows >= 0, bucket.rows, R).astype(np.int32)
-
-
-def _make_fused_jitted(metric, combine, mesh, shard_axes, use_kernel,
-                       interpret, bl, postprocess):
-    from repro.kernels.pairwise.fused_gather_gram import (
-        fused_gather_gram,
-        fused_gather_gram_streamed,
-    )
-
-    def run(x, buckets, pp_arg, R, L):
-        per_bucket = []
-        for idx, msk, rows in buckets:
-            if use_kernel:
-                g = fused_gather_gram(x, idx, msk, bl=bl,
-                                      interpret=interpret)
-            else:
-                g = fused_gather_gram_streamed(x, idx, msk, bl=bl)
-            mb = msk.astype(bool)
-            per_bucket.append(((idx, mb, rows),
-                               _finish_fused_blocks(g, mb, metric)))
-        if postprocess is not None:
-            return postprocess(per_bucket, pp_arg)
-        if combine == "buckets":
-            return [g for _, g in per_bucket]
-        # dense combine: scatter bucket blocks (padded to the dense width)
-        # into original reducer order; padding rows land in the extra row R
-        acc = jnp.zeros((R + 1, L, L), jnp.float32)
-        for (idx, msk, rows), g in per_bucket:
-            Lb = g.shape[1]
-            gp = jnp.pad(g, ((0, 0), (0, L - Lb), (0, L - Lb)))
-            acc = acc.at[rows].set(gp)
-        return acc[:R]
-
-    if mesh is None:
-        return jax.jit(run, static_argnums=(3, 4))
-    red_sharding, rep = _shardings(mesh, shard_axes)
-    return jax.jit(run, in_shardings=(rep, red_sharding, rep),
-                   static_argnums=(3, 4))
-
-
 def _make_fused_rect_jitted(metric, mesh, shard_axes, use_kernel,
                             interpret, bl):
     from repro.kernels.pairwise.fused_gather_gram import (
@@ -576,22 +549,29 @@ def _make_fused_rect_jitted(metric, mesh, shard_axes, use_kernel,
     )
 
     def run(xt, yt, buckets, srcmap):
+        # a self-join passes no Y side (None): its table and ids are X's
+        yt = xt if yt is None else yt
         n2x = jnp.sum(xt.astype(jnp.float32) ** 2, axis=-1)   # (mx,)
         n2y = jnp.sum(yt.astype(jnp.float32) ** 2, axis=-1)   # (my,)
-        vals = [jnp.zeros((1,), jnp.float32)]
+        blocks = []
         for xidx, xmsk, yidx, ymsk in buckets:
+            if yidx is None:
+                yidx, ymsk = xidx, xmsk
             if use_kernel:
                 g = fused_gather_gram_rect(xt, yt, xidx, xmsk, yidx, ymsk,
                                            bl=bl, interpret=interpret)
             else:
                 g = fused_gather_gram_rect_streamed(xt, yt, xidx, xmsk,
                                                     yidx, ymsk, bl=bl)
-            g = _finish_rect_blocks(g, xidx, xmsk.astype(bool),
-                                    yidx, ymsk.astype(bool), n2x, n2y,
-                                    metric)
-            vals.append(g.reshape(-1))
-        # rectangular inverse shuffle: ONE assembly gather through the
-        # host-precomputed source map (slot 0 -> 0.0 for uncovered cells)
+            blocks.append(_finish_rect_blocks(
+                g, xidx, xmsk.astype(bool), yidx, ymsk.astype(bool), n2x,
+                n2y, metric))
+        if srcmap is None:                     # the blocks themselves
+            return blocks
+        # inverse shuffle: ONE assembly gather through the host-precomputed
+        # source map (slot 0 -> 0.0 for uncovered cells)
+        vals = [jnp.zeros((1,), jnp.float32)]
+        vals += [g.reshape(-1) for g in blocks]
         return jnp.take(jnp.concatenate(vals), srcmap, axis=0)
 
     if mesh is None:
@@ -603,16 +583,18 @@ def _make_fused_rect_jitted(metric, mesh, shard_axes, use_kernel,
 class FusedExecutor(Executor):
     """Fused shuffle execution: the gathered block stays out of HBM.
 
-    Per capacity bucket, the plan's ``idx``/``mask`` rows drive the fused
-    gather+Gram Pallas kernel (``use_kernel=True``; scalar-prefetched rows,
-    table rows DMA'd HBM->VMEM, fp32 MXU accumulation — gathered rows live
-    only in VMEM scratch) or its jnp twin with the same tile dataflow
-    (``use_kernel=False``, the non-TPU default) — the twin still gathers
-    ``(Rb, bl, d)`` tiles as XLA intermediates, but a multi-tile bucket
-    never materializes its full ``(Rb, Lb, d)`` block and no bucket ever
-    materializes the dense ``(R, L, d)`` one.  *All* buckets execute
-    inside ONE jitted program, so a request pays a single dispatch instead
-    of one per bucket.
+    Per capacity bucket, the plan's X and Y gather rows drive the fused
+    gather+Gram Pallas kernel (TPU or ``use_kernel=True``; scalar-
+    prefetched rows, table rows DMA'd HBM->VMEM, fp32 MXU accumulation —
+    gathered rows live only in VMEM scratch) or its jnp twin with the same
+    tile dataflow (elsewhere) — the twin still gathers ``(Rb, bl, d)``
+    tiles as XLA intermediates, but a multi-tile bucket never materializes
+    its full ``(Rb, Lb, d)`` block and no bucket ever materializes the
+    dense ``(R, L, d)`` one.  *All* buckets execute inside ONE jitted
+    program, so a request pays a single dispatch instead of one per
+    bucket.  The program is the X2Y one: a self-join passes its table and
+    each bucket's ids once, with no Y side, the program reads them for
+    both sides, and its source map zeroes the diagonal.
 
     Only Gram-block reducers are fusable: ``reducer_fn`` must carry a
     ``fused_metric`` attribute (see ``allpairs._block_fn``).  Any other
@@ -626,106 +608,90 @@ class FusedExecutor(Executor):
     def _fresh_stats(self) -> dict:
         return {"calls": 0, "kernel": 0, "streamed": 0, "fallbacks": 0}
 
+    def _launch(self, xt, yt, plan, metric, srcmap, mesh, shard_axes,
+                use_kernel, interpret, bl):
+        """Every bucket through the one program, assembled through
+        ``srcmap`` (``None``: the blocks).  A self-join passes its table
+        and each bucket's ids once, with no Y side (``None``): the program
+        reads X's for both, so XLA sees one array where X = Y."""
+        uk = _kernel_for(use_kernel)
+        self._count("kernel" if uk else "streamed")
+        fn = _cache_get(
+            ("fused-x2y", metric, mesh, shard_axes, uk, bool(interpret),
+             bl),
+            lambda: _make_fused_rect_jitted(metric, mesh, shard_axes, uk,
+                                            interpret, bl))
+        # the source map goes first: its transfer, the largest, overlaps
+        # the rest of the host work
+        host = (srcmap, tuple((b.idx, b.mask, b.yidx, b.ymask)
+                              for b in plan.buckets))
+        with upload_span(host):
+            dev_srcmap, buckets = jax.tree.map(jnp.asarray, host)
+        return launch(fn, xt, None if yt is xt else yt, buckets, dev_srcmap)
+
     def run(self, inputs, plan, reducer_fn, *, mesh=None, shard_axes=None,
-            combine: str = "dense", postprocess: Optional[Callable] = None,
-            postprocess_arg=None, use_kernel: Optional[bool] = None,
+            combine: str = "dense", use_kernel: Optional[bool] = None,
             interpret: bool = False, bl: int = 128):
-        """``combine`` follows the bucketed executor ('dense' / 'buckets');
-        ``postprocess(per_bucket, postprocess_arg)`` — a *stable* function
-        object, traced into the same program — lets applications fuse their
-        assembly step too (allpairs passes its inverse-shuffle gather map).
-        ``use_kernel=None`` auto-selects: Pallas on TPU, streamed jnp
-        elsewhere."""
+        """Per-reducer Gram blocks from the same program, without the
+        assembly gather.  ``combine`` follows the bucketed executor:
+        'dense' puts them in plan-row order at the dense widths, 'buckets'
+        returns ``[(bucket, blocks), ...]``."""
         assert combine in ("dense", "buckets"), combine
         self._count("calls")
         metric = getattr(reducer_fn, "fused_metric", None)
         if metric is None or not plan.buckets:
             self._count_fallback(
                 "non_gram_reducer" if metric is None else "no_buckets")
-            out = run_reducers_bucketed(
-                inputs, plan, reducer_fn, mesh=mesh, shard_axes=shard_axes,
-                combine="buckets" if postprocess is not None else combine)
-            if postprocess is not None:
-                # honor the postprocess contract on the fallback path (eager)
-                per_bucket = [((jnp.asarray(b.idx), jnp.asarray(b.mask),
-                                jnp.asarray(_scatter_rows(b, plan.R))),
-                               blocks)
-                              for b, blocks in out]
-                return postprocess(per_bucket, postprocess_arg)
-            return out
-
-        if use_kernel is None:
-            use_kernel = jax.default_backend() == "tpu"
-        self._count("kernel" if use_kernel else "streamed")
+            return run_reducers_bucketed(inputs, plan, reducer_fn, mesh=mesh,
+                                         shard_axes=shard_axes,
+                                         combine=combine)
         shard_axes = tuple(shard_axes) if shard_axes is not None else None
-        fn = _cache_get(
-            ("fused", metric, combine, postprocess, mesh, shard_axes,
-             bool(use_kernel), bool(interpret), bl),
-            lambda: _make_fused_jitted(metric, combine, mesh, shard_axes,
-                                       use_kernel, interpret, bl,
-                                       postprocess))
-        host = tuple((b.idx, b.mask, _scatter_rows(b, plan.R))
-                     for b in plan.buckets)
-        with upload_span(host):
-            buckets = jax.tree.map(jnp.asarray, host)
-        return launch(fn, inputs, buckets, postprocess_arg, plan.R, plan.L)
+        blocks = self._launch(*_as_tables(inputs), plan, metric, None, mesh,
+                              shard_axes, use_kernel, interpret, bl)
+        if combine == "buckets":
+            return list(zip(plan.buckets, blocks))
+        return _scatter_dense(
+            [(b.rows, g) for b, g in zip(plan.buckets, blocks)], plan.R,
+            (plan.L, plan.Ly))
 
     def run_pairs(self, x, plan, reducer_fn, m, *, mesh=None,
                   use_kernel=False, interpret=False):
-        from .allpairs import _assemble_from_srcmap, _pair_source_map
-        # reconcile here, not in run(): the delegation below must not
-        # double-record the request
-        self._reconcile(plan, "pairs", x,
-                        measured_slots=_bucket_valid_slots(plan))
-        host = _pair_source_map(plan, m)
-        with upload_span(host):
-            srcmap = jnp.asarray(host)
-        return self.run(
-            x, plan, reducer_fn, mesh=mesh,
-            postprocess=_assemble_from_srcmap, postprocess_arg=srcmap,
-            use_kernel=(True if use_kernel else None), interpret=interpret)
+        """The self-join: :meth:`run_x2y` with X = Y, diagonal zeroed."""
+        return self._assembled(x, plan, reducer_fn, (m, m), "pairs", mesh,
+                               use_kernel, interpret, 128)
 
     def run_x2y(self, tables, plan, reducer_fn, shape, *, mesh=None,
                 use_kernel=False, interpret=False, bl: int = 128):
-        """Rectangular fused path: per rect bucket, independent X/Y gather
-        maps drive the rectangular gather+Gram kernel (streamed jnp twin
-        off-TPU), and ONE inverse-shuffle gather assembles the (mx, my)
-        matrix.  Non-Gram reducers fall back to the rect-bucketed path
-        (identical outputs; counted)."""
-        from .allpairs import (
-            _pair_source_map_rect,
-            assemble_x2y_matrix_bucketed,
-        )
+        """Per bucket, independent X/Y gather maps drive the rectangular
+        gather+Gram kernel (streamed jnp twin off-TPU), and ONE
+        inverse-shuffle gather assembles the (mx, my) matrix.  Non-Gram
+        reducers fall back to the rect-bucketed path (identical outputs;
+        counted)."""
+        return self._assembled(tables, plan, reducer_fn, tuple(shape), "x2y",
+                               mesh, use_kernel, interpret, bl)
+
+    def _assembled(self, tables, plan, reducer_fn, shape, workload, mesh,
+                   use_kernel, interpret, bl):
+        from .allpairs import _pair_source_map_rect
         self._count("calls")
-        self._reconcile(plan, "x2y", _as_tables(tables)[0],
-                        measured_slots=_bucket_valid_slots(plan))
         metric = getattr(reducer_fn, "fused_metric", None)
         if metric is None or not plan.buckets:
-            self._count_fallback(
+            return self._bucketed_answer(
+                tables, plan, reducer_fn, shape, workload, mesh,
                 "non_gram_reducer" if metric is None else "no_buckets")
-            per_bucket = run_reducers_x2y_bucketed(
-                tables, plan, reducer_fn, mesh=mesh, combine="buckets")
-            return assemble_x2y_matrix_bucketed(per_bucket, shape)
-        uk = True if use_kernel else jax.default_backend() == "tpu"
-        self._count("kernel" if uk else "streamed")
-        host_srcmap = _pair_source_map_rect(plan, *shape)
-        fn = _cache_get(
-            ("fused-x2y", metric, mesh, None, bool(uk), bool(interpret),
-             bl),
-            lambda: _make_fused_rect_jitted(metric, mesh, None, uk,
-                                            interpret, bl))
-        host = (tuple((b.idx, b.mask, b.yidx, b.ymask)
-                      for b in plan.buckets), host_srcmap)
-        with upload_span(host):
-            buckets, srcmap = jax.tree.map(jnp.asarray, host)
         xt, yt = _as_tables(tables)
-        return launch(fn, xt, yt, buckets, srcmap)
+        self._reconcile(plan, workload, xt,
+                        measured_slots=_bucket_valid_slots(plan))
+        srcmap = _pair_source_map_rect(plan, *shape,
+                                       zero_diag=workload == "pairs")
+        return self._launch(xt, yt, plan, metric, srcmap, mesh, None,
+                            use_kernel, interpret, bl)
 
     def lower(self, input_shape, plan, *, reducer_fn=None, metric=None,
               mesh=None, dtype=jnp.float32, shard_axes=None,
-              combine: str = "buckets", use_kernel: bool = False,
-              bl: int = 128, **kwargs):
-        """Lower the single all-bucket program (no execution).  Defaults to
+              use_kernel: bool = False, bl: int = 128, **kwargs):
+        """Lower the one all-bucket program (no execution) on a self-join
+        table of ``input_shape``, up to the per-bucket blocks.  Defaults to
         the streamed (jnp) lowering so the dry-run works on any backend; on
         this path the program is directly comparable with the bucketed
         lowering — same math, one program, no materialized gather for
@@ -734,15 +700,12 @@ class FusedExecutor(Executor):
             metric = getattr(reducer_fn, "fused_metric", None)
         assert metric is not None, "fused lowering needs a Gram metric"
         shard_axes = tuple(shard_axes) if shard_axes is not None else None
-        fn = _make_fused_jitted(metric, combine, mesh, shard_axes,
-                                use_kernel, False, bl, None)
+        fn = _make_fused_rect_jitted(metric, mesh, shard_axes, use_kernel,
+                                     False, bl)
         x = jax.ShapeDtypeStruct(input_shape, dtype)
-        buckets = tuple(
-            (jax.ShapeDtypeStruct(b.idx.shape, jnp.int32),
-             jax.ShapeDtypeStruct(b.mask.shape, jnp.bool_),
-             jax.ShapeDtypeStruct((b.R,), jnp.int32))
-            for b in plan.buckets)
-        return fn.lower(x, buckets, None, plan.R, plan.L)
+        buckets = _specs(tuple((b.idx, b.mask, b.yidx, b.ymask)
+                               for b in plan.buckets))
+        return fn.lower(x, None, buckets, None)
 
 
 # ---------------------------------------------------------------------------
@@ -770,61 +733,6 @@ def _put(a, mesh, spec) -> jax.Array:
     return jax.device_put(a, jax.sharding.NamedSharding(mesh, spec))
 
 
-def _stacked_groups(plan: ReducerPlan, part: PlanPartition,
-                    rows_by_shard=None):
-    """Stack the partition into uniform per-width device arrays.
-
-    For every execution width ``w`` appearing in the partition, build
-    ``idx (S, Rw, w)`` / ``mask (S, Rw, w)`` / ``rows (S, Rw)`` where
-    ``Rw = max_s |shard s's width-w reducers|`` — each shard's rows padded
-    (masked, rows -> plan.R) to the common count so ``shard_map`` can split
-    the leading axis across the mesh.  LPT balances total work, so the
-    cross-shard padding this stacking adds is small exactly when the
-    balance factor is small.  Returns ``[(idx, mask, rows), ...]`` with
-    widths ascending (numpy; the executor converts once per plan).
-
-    ``rows_by_shard`` overrides the per-shard row sets (default: the
-    partition's primary ``shard_rows``) — the coded executor passes
-    ``part.replica_rows`` so every shard's stack holds all of its
-    replicas, not just its primary assignment.
-    """
-    S = part.num_shards
-    R0 = plan.num_reducers
-    widths = part.widths
-    if rows_by_shard is None:
-        rows_by_shard = part.shard_rows
-    # per-global-row source arrays at the row's execution width
-    if plan.buckets:
-        src_idx = {}
-        src_mask = {}
-        for b in plan.buckets:
-            rows = np.asarray(b.rows)
-            for i, g in enumerate(rows):
-                if 0 <= g < R0:
-                    src_idx[int(g)] = np.asarray(b.idx)[i]
-                    src_mask[int(g)] = np.asarray(b.mask)[i]
-    else:
-        src_idx = {r: np.asarray(plan.idx)[r] for r in range(R0)}
-        src_mask = {r: np.asarray(plan.mask)[r] for r in range(R0)}
-
-    groups = []
-    for w in sorted(set(int(x) for x in widths)) if R0 else []:
-        per_shard = [rows[widths[rows] == w] for rows in rows_by_shard]
-        Rw = max((len(p) for p in per_shard), default=0)
-        if Rw == 0:
-            continue
-        idx = np.zeros((S, Rw, w), np.int32)
-        mask = np.zeros((S, Rw, w), bool)
-        rows_out = np.full((S, Rw), plan.R, np.int32)   # padding -> row R
-        for s, p in enumerate(per_shard):
-            for k, g in enumerate(p):
-                idx[s, k, :] = src_idx[int(g)][:w]
-                mask[s, k, :] = src_mask[int(g)][:w]
-                rows_out[s, k] = int(g)
-        groups.append((idx, mask, rows_out))
-    return groups
-
-
 _SRCMAP_CHUNK = 1 << 24          # block cells expanded at once on the host
 # Per-shard bodies call the Pallas kernels, whose outputs carry no
 # varying-mesh-axis type: shard_map's vma check cannot trace them
@@ -836,7 +744,7 @@ def _fill_source_map(srcmap: np.ndarray, blocks) -> None:
     concatenation ``[0.0, blocks_0.ravel(), blocks_1.ravel(), ...]``.
 
     ``blocks`` yields ``(xidx (R, Lx), xmask, yidx (R, Ly), ymask)`` per
-    stack of reducer blocks (a square plan passes its idx/mask twice).
+    stack of reducer blocks (a self-join's Y side is its X side).
     Reducers are expanded a chunk at a time, so host memory stays bounded
     however many cells the plan has; a cell covered twice keeps its last
     writer, exactly as one whole-stack assignment would."""
@@ -857,46 +765,36 @@ def _fill_source_map(srcmap: np.ndarray, blocks) -> None:
         base += R * cells
 
 
-def _sharded_srcmap(groups, m: int) -> np.ndarray:
-    """Inverse-shuffle map for the cross-shard assembly gather: (m, m)
-    int32 positions into ``[0.0, group_0.ravel(), group_1.ravel(), ...]``
-    of the stacked per-width Gram outputs (each ``(S, Rw, w, w)``).
-    Uncovered cells and the diagonal point at slot 0 (-> 0.0)."""
-    srcmap = np.zeros((m, m), np.int32)
-    flat = [(idx.reshape(-1, idx.shape[2]), mask.reshape(-1, idx.shape[2]))
-            for idx, mask, _rows in groups]
-    _fill_source_map(srcmap, ((i, k, i, k) for i, k in flat))
-    np.fill_diagonal(srcmap, 0)
-    return srcmap
-
-
 def _stacked_rect_groups(plan: ReducerPlan, part: PlanPartition,
                          rows_by_shard=None):
-    """Rectangular analogue of :func:`_stacked_groups`: groups keyed by the
-    (wx, wy) execution-width *pair*, each stacked into
-    ``xidx/xmask (S, Rw, wx)``, ``yidx/ymask (S, Rw, wy)``, ``rows (S, Rw)``
-    device arrays (padding rows masked, rows -> plan.R).  ``rows_by_shard``
-    overrides the per-shard row sets as in :func:`_stacked_groups`."""
+    """Stack the partition into uniform device arrays, one group per
+    (wx, wy) execution-width pair: ``xidx/xmask (S, Rw, wx)``,
+    ``yidx/ymask (S, Rw, wy)``, ``rows (S, Rw)``, where ``Rw = max_s
+    |shard s's reducers of that pair|`` — each shard's rows padded
+    (masked, rows -> plan.R) to the common count so ``shard_map`` can
+    split the leading axis across the mesh.  LPT balances total work, so
+    the cross-shard padding this stacking adds is small exactly when the
+    balance factor is small.  A self-join's Y side is its X side
+    (``y_side``): the same arrays.  Groups come in ascending width order
+    (numpy; the executor converts once per plan).
+
+    ``rows_by_shard`` overrides the per-shard row sets (default: the
+    partition's primary ``shard_rows``) — the coded executor passes
+    ``part.replica_rows`` so every shard's stack holds all of its
+    replicas, not just its primary assignment."""
     S = part.num_shards
     R0 = plan.num_reducers
     widths = part.widths
-    ywidths = part.ywidths
+    ywidths = widths if part.ywidths is None else part.ywidths
     if rows_by_shard is None:
         rows_by_shard = part.shard_rows
     src = {}
-    if plan.buckets:
-        for b in plan.buckets:
-            rows = np.asarray(b.rows)
-            for i, g in enumerate(rows):
-                if 0 <= g < R0:
-                    src[int(g)] = (np.asarray(b.idx)[i],
-                                   np.asarray(b.mask)[i],
-                                   np.asarray(b.yidx)[i],
-                                   np.asarray(b.ymask)[i])
-    else:
-        for r in range(R0):
-            src[r] = (np.asarray(plan.idx)[r], np.asarray(plan.mask)[r],
-                      np.asarray(plan.yidx)[r], np.asarray(plan.ymask)[r])
+    for p in plan.buckets or (plan,):
+        yidx, ymask = y_side(p)
+        rows = p.rows if plan.buckets else np.arange(R0)
+        for i, g in enumerate(rows):
+            if 0 <= g < R0:
+                src[int(g)] = (p.idx[i], p.mask[i], yidx[i], ymask[i])
 
     keys = sorted({(int(widths[r]), int(ywidths[r]))
                    for r in range(R0)}) if R0 else []
@@ -909,8 +807,10 @@ def _stacked_rect_groups(plan: ReducerPlan, part: PlanPartition,
             continue
         xidx = np.zeros((S, Rw, wx), np.int32)
         xmask = np.zeros((S, Rw, wx), bool)
-        yidx = np.zeros((S, Rw, wy), np.int32)
-        ymask = np.zeros((S, Rw, wy), bool)
+        # a self-join's Y side is its X side's arrays (written twice below)
+        yidx, ymask = ((np.zeros((S, Rw, wy), np.int32),
+                        np.zeros((S, Rw, wy), bool)) if plan.is_rect
+                       else (xidx, xmask))
         rows_out = np.full((S, Rw), plan.R, np.int32)   # padding -> row R
         for s, p in enumerate(per_shard):
             for k, g in enumerate(p):
@@ -924,63 +824,26 @@ def _stacked_rect_groups(plan: ReducerPlan, part: PlanPartition,
     return groups
 
 
-def _sharded_rect_srcmap(groups, shape: tuple[int, int]) -> np.ndarray:
-    """Rectangular cross-shard assembly map: (mx, my) int32 positions into
-    ``[0.0, group_0.ravel(), ...]`` of the stacked per-(wx, wy) cross-Gram
-    outputs (each ``(S, Rw, wx, wy)``).  No diagonal to zero — an (x, y)
-    pair is never a self-pair; uncovered cells point at slot 0."""
+def _group_args(plan, groups) -> tuple:
+    """Stacked groups as the sharded program takes them: a self-join's Y
+    side (its X side's arrays) is left out (``None``), so the program
+    reads one array for both."""
+    return tuple(g[:4] if plan.is_rect else (*g[:2], None, None)
+                 for g in groups)
+
+
+def _sharded_rect_srcmap(groups, shape: tuple[int, int],
+                         zero_diag: bool = False) -> np.ndarray:
+    """Cross-shard assembly map: ``shape`` int32 positions into
+    ``[0.0, group_0.ravel(), ...]`` of the stacked per-(wx, wy) Gram
+    outputs (each ``(S, Rw, wx, wy)``).  Uncovered cells, and with
+    ``zero_diag`` a self-join's diagonal, point at slot 0 (-> 0.0)."""
     srcmap = np.zeros(shape, np.int32)
-    _fill_source_map(srcmap, (
-        tuple(a.reshape(-1, a.shape[2]) for a in (xi, xm, yi, ym))
-        for xi, xm, yi, ym, _rows in groups))
+    _fill_source_map(srcmap, (tuple(a.reshape(-1, a.shape[2])
+                                    for a in g[:4]) for g in groups))
+    if zero_diag:
+        np.fill_diagonal(srcmap, 0)
     return srcmap
-
-
-def _make_sharded_jitted(metric, combine, mesh, axes, use_kernel,
-                         interpret, bl):
-    from repro.kernels.pairwise.fused_gather_gram import (
-        fused_gather_gram,
-        fused_gather_gram_streamed,
-    )
-
-    P = jax.sharding.PartitionSpec
-
-    def per_shard_fn(x, idx, msk):
-        # local shapes: x (m, d) replicated, idx/msk (1, Rw, w)
-        if use_kernel:
-            g = fused_gather_gram(x, idx[0], msk[0], bl=bl,
-                                  interpret=interpret)
-        else:
-            g = fused_gather_gram_streamed(x, idx[0], msk[0], bl=bl)
-        mb = msk[0].astype(bool)
-        return _finish_fused_blocks(g, mb, metric)[None]   # (1, Rw, w, w)
-
-    def run(x, groups, srcmap, R, L):
-        outs = []
-        for idx, msk, rows in groups:
-            g = jax.shard_map(per_shard_fn, mesh=mesh,
-                              in_specs=(P(), P(axes), P(axes)),
-                              out_specs=P(axes),
-                              check_vma=_CHECK_VMA)(x, idx, msk)
-            outs.append((rows, g))
-        if combine == "pairs":
-            # ONE cross-shard assembly gather: concatenate the sharded
-            # Gram stacks and gather the replicated (m, m) matrix through
-            # the host-precomputed source map (XLA inserts the all-gather
-            # here — the only cross-shard communication in the program)
-            vals = [jnp.zeros((1,), jnp.float32)]
-            vals += [g.reshape(-1) for _, g in outs]
-            return jnp.take(jnp.concatenate(vals), srcmap, axis=0)
-        # dense combine: scatter shard blocks (padded to the dense width)
-        # back into original reducer order; padding rows drop into row R
-        acc = jnp.zeros((R + 1, L, L), jnp.float32)
-        for rows, g in outs:
-            w = g.shape[-1]
-            gp = jnp.pad(g, ((0, 0), (0, 0), (0, L - w), (0, L - w)))
-            acc = acc.at[rows.reshape(-1)].set(gp.reshape(-1, L, L))
-        return acc[:R]
-
-    return jax.jit(run, static_argnums=(3, 4))
 
 
 def _make_sharded_rect_jitted(metric, mesh, axes, use_kernel, interpret,
@@ -993,7 +856,10 @@ def _make_sharded_rect_jitted(metric, mesh, axes, use_kernel, interpret,
     P = jax.sharding.PartitionSpec
 
     def per_shard_fn(xt, yt, n2x, n2y, xidx, xmsk, yidx, ymsk):
-        # local shapes: xt/yt/n2x/n2y replicated, idx/msk (1, Rw, w)
+        # local shapes: xt/yt/n2x/n2y replicated, idx/msk (1, Rw, w); a
+        # self-join passes no Y-side ids (None): they are X's
+        if yidx is None:
+            yidx, ymsk = xidx, xmsk
         if use_kernel:
             g = fused_gather_gram_rect(xt, yt, xidx[0], xmsk[0], yidx[0],
                                        ymsk[0], bl=bl, interpret=interpret)
@@ -1005,18 +871,21 @@ def _make_sharded_rect_jitted(metric, mesh, axes, use_kernel, interpret,
                                    n2x, n2y, metric)[None]  # (1, Rw, wx, wy)
 
     def run(xt, yt, groups, srcmap):
+        yt = xt if yt is None else yt          # a self-join: one table
         n2x = jnp.sum(xt.astype(jnp.float32) ** 2, axis=-1)
         n2y = jnp.sum(yt.astype(jnp.float32) ** 2, axis=-1)
-        vals = [jnp.zeros((1,), jnp.float32)]
-        for xidx, xmsk, yidx, ymsk, _rows in groups:
-            g = jax.shard_map(per_shard_fn, mesh=mesh,
+        outs = [jax.shard_map(per_shard_fn, mesh=mesh,
                               in_specs=(P(), P(), P(), P(), P(axes),
                                         P(axes), P(axes), P(axes)),
                               out_specs=P(axes), check_vma=_CHECK_VMA)(
-                xt, yt, n2x, n2y, xidx, xmsk, yidx, ymsk)
-            vals.append(g.reshape(-1))
+                    xt, yt, n2x, n2y, *g) for g in groups]
+        if srcmap is None:                     # the group stacks themselves
+            return outs
         # ONE cross-shard assembly gather of the (mx, my) matrix — the
-        # only cross-shard communication in the program
+        # only cross-shard communication in the program (XLA inserts the
+        # all-gather here)
+        vals = [jnp.zeros((1,), jnp.float32)]
+        vals += [g.reshape(-1) for g in outs]
         return jnp.take(jnp.concatenate(vals), srcmap, axis=0)
 
     return jax.jit(run)
@@ -1028,13 +897,14 @@ class ShardedExecutor(Executor):
     ``repro.core.planner.partition_plan`` LPT-balances the plan's reducers
     (weighted by per-reducer gather+FLOP work at their capacity-bucket
     width) into one compact sub-plan per shard of the mesh's reducer axis.
-    The sub-plans are stacked into uniform per-width arrays and executed
+    The sub-plans are stacked into uniform per-(wx, wy) arrays and executed
     under ``shard_map``: every device runs the fused gather+Gram tile
     pipeline (streamed jnp twin off-TPU) over exactly its LPT-assigned
     reducers — instead of XLA's blind even row-split of a skew-ordered
     plan — and the only cross-shard communication is the single assembly
-    gather of the (m, m) pair matrix at the end (``run_pairs``) or the
-    dense scatter (``run``).
+    gather of the answer at the end (``run_pairs``, ``run_x2y``) or the
+    dense scatter (``run``).  One program serves both: a self-join is the
+    X2Y job with X = Y, its diagonal zeroed.
 
     ``mesh=None`` builds a 1-D mesh over all local devices — on CPU, run
     tests/benches under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
@@ -1059,19 +929,14 @@ class ShardedExecutor(Executor):
 
     def _groups_for(self, plan, part):
         return _derived(plan, "_shard_groups_cache", part.num_shards,
-                        "groups", lambda: _stacked_groups(plan, part))
-
-    def _srcmap_for(self, plan, groups, num_shards: int, m: int):
-        return _derived(plan, "_shard_srcmap_cache", (num_shards, m),
-                        "srcmap", lambda: _sharded_srcmap(groups, m))
-
-    def _rect_groups_for(self, plan, part):
-        return _derived(plan, "_shard_rect_groups_cache", part.num_shards,
                         "groups", lambda: _stacked_rect_groups(plan, part))
 
-    def _rect_srcmap_for(self, plan, groups, num_shards: int, shape):
-        return _derived(plan, "_shard_rect_srcmap_cache", (num_shards, shape),
-                        "srcmap", lambda: _sharded_rect_srcmap(groups, shape))
+    def _srcmap_for(self, plan, groups, num_shards: int, shape,
+                    zero_diag: bool):
+        return _derived(plan, "_shard_srcmap_cache",
+                        (num_shards, shape, zero_diag), "srcmap",
+                        lambda: _sharded_rect_srcmap(groups, shape,
+                                                     zero_diag))
 
     def _note(self, part: PlanPartition) -> None:
         self._stats["num_shards"] = part.num_shards
@@ -1081,44 +946,64 @@ class ShardedExecutor(Executor):
         _REGISTRY_OBS.gauge("executor.balance_factor",
                             executor=self.name).set(part.balance_factor)
 
-    def _dispatch(self, x, plan, metric, combine, srcmap_m, mesh,
-                  shard_axes, use_kernel, interpret, bl,
-                  workload: str = "reduce"):
+    def _dispatch(self, xt, yt, plan, metric, shape, zero_diag, mesh,
+                  shard_axes, use_kernel, interpret, bl, workload):
+        """The plan's shard groups through the one sharded program:
+        assembled into ``shape`` (``zero_diag``: a self-join's diagonal
+        zeroed), or with ``shape=None`` scattered into the dense
+        per-reducer layout."""
         mesh, axes, S = _shard_mesh(mesh, shard_axes)
         part = self.partition(plan, S)
         groups = self._groups_for(plan, part)
         self._count("sharded")
         self._note(part)
         if _obs_config.ENABLED:
-            assembled = 0
-            meta = {"num_shards": S, "combine": combine}
             stacks = _group_stacks(groups)
-            if combine == "pairs":
-                _d, isz = _row_bytes(x)
+            meta = {"num_shards": S}
+            assembled = 0
+            if shape is not None:
+                _d, isz = _row_bytes(xt)
                 per_shard = int(_block_entries(stacks) * isz * (S - 1) / S)
                 assembled = S * per_shard
                 meta["assembly_bytes_per_shard"] = per_shard
             self._reconcile(
-                plan, workload, x,
-                measured_slots=_group_valid_slots(
-                    plan, ("sharded", S), groups, count_y=False),
+                plan, workload, xt,
+                measured_slots=_group_valid_slots(plan, ("sharded", S),
+                                                  groups),
                 assembled_bytes=assembled, meta=meta, stacks=stacks)
-        P = jax.sharding.PartitionSpec
-        host_srcmap = (self._srcmap_for(plan, groups, S, srcmap_m)
-                       if combine == "pairs" else None)
-        if use_kernel is None:
-            use_kernel = jax.default_backend() == "tpu"
+        host_srcmap = (None if shape is None else
+                       self._srcmap_for(plan, groups, S, shape, zero_diag))
+        uk = _kernel_for(use_kernel)
         fn = _cache_get(
-            ("sharded", metric, combine, mesh, axes, bool(use_kernel),
-             bool(interpret), bl),
-            lambda: _make_sharded_jitted(metric, combine, mesh, axes,
-                                         use_kernel, interpret, bl))
-        with upload_span((groups, host_srcmap)):
-            jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in g)
-                            for g in groups)
-            srcmap = (_put(host_srcmap, mesh, P()) if combine == "pairs"
-                      else jnp.zeros((1,), jnp.int32))  # unused placeholder
-        return launch(fn, x, jgroups, srcmap, plan.R, plan.L)
+            ("sharded-x2y", metric, mesh, axes, uk, bool(interpret), bl),
+            lambda: _make_sharded_rect_jitted(metric, mesh, axes, uk,
+                                              interpret, bl))
+        P = jax.sharding.PartitionSpec
+        args = _group_args(plan, groups)
+        with upload_span((args, host_srcmap)):
+            jgroups = jax.tree.map(lambda a: _put(a, mesh, P(axes)), args)
+            srcmap = (None if shape is None
+                      else _put(host_srcmap, mesh, P()))
+        out = launch(fn, xt, None if yt is xt else yt, jgroups, srcmap)
+        if shape is not None:
+            return out
+        return _scatter_dense([(g[4], o) for g, o in zip(groups, out)],
+                              plan.R, (plan.L, plan.Ly))
+
+    # the coded executor answers through its combining stage instead
+    _answer = _dispatch
+
+    def _assembled(self, tables, plan, reducer_fn, shape, workload, mesh,
+                   use_kernel, interpret, bl):
+        self._count("calls")
+        metric = getattr(reducer_fn, "fused_metric", None)
+        if metric is None or plan.num_reducers == 0:
+            return self._bucketed_answer(
+                tables, plan, reducer_fn, shape, workload, mesh,
+                "non_gram_reducer" if metric is None else "empty_plan")
+        xt, yt = _as_tables(tables)
+        return self._answer(xt, yt, plan, metric, shape, workload == "pairs",
+                            mesh, None, use_kernel, interpret, bl, workload)
 
     # -- protocol ----------------------------------------------------------
     def run(self, inputs, plan, reducer_fn, *, mesh=None, shard_axes=None,
@@ -1135,25 +1020,14 @@ class ShardedExecutor(Executor):
                 "non_gram_reducer" if metric is None else "empty_plan")
             return run_reducers_bucketed(inputs, plan, reducer_fn,
                                          mesh=mesh, combine=combine)
-        return self._dispatch(inputs, plan, metric, "dense", None, mesh,
-                              shard_axes, use_kernel, interpret, bl)
+        return self._dispatch(*_as_tables(inputs), plan, metric, None, False,
+                              mesh, shard_axes, use_kernel, interpret, bl,
+                              "reduce")
 
     def run_pairs(self, x, plan, reducer_fn, m, *, mesh=None,
                   use_kernel=False, interpret=False):
-        from .allpairs import assemble_pair_matrix_bucketed
-        self._count("calls")
-        metric = getattr(reducer_fn, "fused_metric", None)
-        if metric is None or plan.num_reducers == 0:
-            self._count_fallback(
-                "non_gram_reducer" if metric is None else "empty_plan")
-            self._reconcile(plan, "pairs", x,
-                            measured_slots=_bucket_valid_slots(plan))
-            per_bucket = run_reducers_bucketed(x, plan, reducer_fn,
-                                               mesh=mesh, combine="buckets")
-            return assemble_pair_matrix_bucketed(per_bucket, m)
-        return self._dispatch(x, plan, metric, "pairs", m, mesh, None,
-                              (True if use_kernel else None), interpret,
-                              128, workload="pairs")
+        return self._assembled(x, plan, reducer_fn, (m, m), "pairs", mesh,
+                               use_kernel, interpret, 128)
 
     def run_x2y(self, tables, plan, reducer_fn, shape, *, mesh=None,
                 use_kernel=False, interpret=False, bl: int = 128):
@@ -1162,81 +1036,33 @@ class ShardedExecutor(Executor):
         pipeline per shard under ``shard_map``, and assemble the (mx, my)
         matrix with ONE cross-shard gather.  Non-Gram reducers fall back
         to the rect-bucketed path (counted)."""
-        from .allpairs import assemble_x2y_matrix_bucketed
-        self._count("calls")
-        metric = getattr(reducer_fn, "fused_metric", None)
-        if metric is None or plan.num_reducers == 0:
-            self._count_fallback(
-                "non_gram_reducer" if metric is None else "empty_plan")
-            self._reconcile(plan, "x2y", _as_tables(tables)[0],
-                            measured_slots=_bucket_valid_slots(plan))
-            per_bucket = run_reducers_x2y_bucketed(
-                tables, plan, reducer_fn, mesh=mesh, combine="buckets")
-            return assemble_x2y_matrix_bucketed(per_bucket, shape)
-        mesh, axes, S = _shard_mesh(mesh, None)
-        part = self.partition(plan, S)
-        groups = self._rect_groups_for(plan, part)
-        self._count("sharded")
-        self._note(part)
-        if _obs_config.ENABLED:
-            xt0 = _as_tables(tables)[0]
-            _d, isz = _row_bytes(xt0)
-            stacks = _group_stacks(groups)
-            per_shard = int(_block_entries(stacks) * isz * (S - 1) / S)
-            self._reconcile(
-                plan, "x2y", xt0,
-                measured_slots=_group_valid_slots(
-                    plan, ("sharded_rect", S), groups, count_y=True),
-                assembled_bytes=S * per_shard,
-                meta={"num_shards": S,
-                      "assembly_bytes_per_shard": per_shard},
-                stacks=stacks)
-        P = jax.sharding.PartitionSpec
-        host_srcmap = self._rect_srcmap_for(plan, groups, S, tuple(shape))
-        uk = True if use_kernel else jax.default_backend() == "tpu"
-        fn = _cache_get(
-            ("sharded-x2y", metric, mesh, axes, bool(uk), bool(interpret),
-             bl),
-            lambda: _make_sharded_rect_jitted(metric, mesh, axes, uk,
-                                              interpret, bl))
-        with upload_span((groups, host_srcmap)):
-            srcmap = _put(host_srcmap, mesh, P())
-            jgroups = tuple(tuple(_put(a, mesh, P(axes)) for a in grp)
-                            for grp in groups)
-        xt, yt = _as_tables(tables)
-        return launch(fn, xt, yt, jgroups, srcmap)
+        return self._assembled(tables, plan, reducer_fn, tuple(shape), "x2y",
+                               mesh, use_kernel, interpret, bl)
 
     def lower(self, input_shape, plan, *, reducer_fn=None, metric=None,
               mesh=None, dtype=jnp.float32, shard_axes=None,
               combine: str = "pairs", m: Optional[int] = None,
               use_kernel: bool = False, bl: int = 128, **kwargs):
-        """Lower the sharded program (no execution) for dry-run/roofline.
+        """Lower the sharded program (no execution) for dry-run/roofline,
+        on a self-join table of ``input_shape``.
 
         ``combine='pairs'`` (default) lowers the full pipeline including
         the cross-shard assembly gather of the ``(m, m)`` matrix
         (``m`` defaults to ``input_shape[0]``); ``combine='dense'`` lowers
-        the dense-combine scatter form.  Returns one ``Lowered``.
+        the group stacks alone.  Returns one ``Lowered``.
         """
         if metric is None:
             metric = getattr(reducer_fn, "fused_metric", None)
         assert metric is not None, "sharded lowering needs a Gram metric"
         mesh, axes, S = _shard_mesh(mesh, shard_axes)
-        part = self.partition(plan, S)
-        groups = self._groups_for(plan, part)
-        if combine == "pairs":
-            mm = m if m is not None else input_shape[0]
-            srcmap = jax.ShapeDtypeStruct((mm, mm), jnp.int32)
-        else:
-            srcmap = jax.ShapeDtypeStruct((1,), jnp.int32)
-        fn = _make_sharded_jitted(metric, combine, mesh, axes,
-                                  use_kernel, False, bl)
+        groups = self._groups_for(plan, self.partition(plan, S))
+        mm = m if m is not None else input_shape[0]
+        srcmap = (jax.ShapeDtypeStruct((mm, mm), jnp.int32)
+                  if combine == "pairs" else None)
+        fn = _make_sharded_rect_jitted(metric, mesh, axes, use_kernel, False,
+                                       bl)
         x = jax.ShapeDtypeStruct(input_shape, dtype)
-        sgroups = tuple(
-            (jax.ShapeDtypeStruct(i.shape, jnp.int32),
-             jax.ShapeDtypeStruct(k.shape, jnp.bool_),
-             jax.ShapeDtypeStruct(r.shape, jnp.int32))
-            for i, k, r in groups)
-        return fn.lower(x, sgroups, srcmap, plan.R, plan.L)
+        return fn.lower(x, None, _specs(_group_args(plan, groups)), srcmap)
 
 
 # ---------------------------------------------------------------------------
@@ -1559,16 +1385,11 @@ class CodedExecutor(ShardedExecutor):
             plan, "_coded_partition_cache", (num_shards, r), "partition",
             lambda: partition_plan(plan, num_shards, replication=r))
 
-    def _coded_groups_for(self, plan, part, rect: bool):
-        def build():
-            if rect:
-                return _stacked_rect_groups(
-                    plan, part, rows_by_shard=part.replica_rows)
-            return [(i, k, i, k, r) for i, k, r in _stacked_groups(
-                plan, part, rows_by_shard=part.replica_rows)]
+    def _coded_groups_for(self, plan, part):
         return _derived(plan, "_coded_groups_cache",
-                        (part.num_shards, part.replication, rect), "groups",
-                        build)
+                        (part.num_shards, part.replication), "groups",
+                        lambda: _stacked_rect_groups(
+                            plan, part, rows_by_shard=part.replica_rows))
 
     def _coded_maps_for(self, plan, groups, part, shape, zero_diag: bool):
         rb = -(-shape[0] // part.num_shards)
@@ -1597,12 +1418,14 @@ class CodedExecutor(ShardedExecutor):
                             executor=self.name).set(
                                 mstats["skipped_entries"])
 
-    def _coded_dispatch(self, xt, yt, plan, metric, shape, zero_diag,
-                        mesh, shard_axes, use_kernel, interpret, bl,
-                        rect: bool, workload: str = "pairs"):
+    def _answer(self, xt, yt, plan, metric, shape, zero_diag, mesh,
+                shard_axes, use_kernel, interpret, bl, workload):
+        """The answer through the coded combining stage: the replica-stacked
+        Gram blocks serve their slices' cells locally, the residual crosses
+        shards in one all-to-all."""
         mesh, axes, S = _shard_mesh(mesh, shard_axes)
         part = self.partition_coded(plan, S)
-        groups = self._coded_groups_for(plan, part, rect)
+        groups = self._coded_groups_for(plan, part)
         sendmap, srcmap, mstats = self._coded_maps_for(
             plan, groups, part, shape, zero_diag)
         self._count("coded")
@@ -1617,8 +1440,7 @@ class CodedExecutor(ShardedExecutor):
             self._reconcile(
                 plan, workload, xt,
                 measured_slots=_group_valid_slots(
-                    plan, ("coded", S, part.replication, rect), groups,
-                    count_y=rect),
+                    plan, ("coded", S, part.replication), groups),
                 replication=float(part.replication),
                 assembled_bytes=S * per_shard,
                 local_bytes=int(mstats["local_entries"]) * isz,
@@ -1628,13 +1450,11 @@ class CodedExecutor(ShardedExecutor):
                       "assembly_bytes_per_shard": per_shard,
                       "lane_max": mstats["lane_max"]},
                 stacks=_group_stacks(groups))
-        if use_kernel is None:
-            use_kernel = jax.default_backend() == "tpu"
+        uk = _kernel_for(use_kernel)
         fn = _cache_get(
-            ("coded", metric, mesh, axes, bool(use_kernel),
-             bool(interpret), bl),
-            lambda: _make_coded_jitted(metric, mesh, axes, use_kernel,
-                                       interpret, bl))
+            ("coded", metric, mesh, axes, uk, bool(interpret), bl),
+            lambda: _make_coded_jitted(metric, mesh, axes, uk, interpret,
+                                       bl))
         P = jax.sharding.PartitionSpec
         host = (tuple(g[:4] for g in groups), sendmap, srcmap)
         with upload_span(host):
@@ -1646,44 +1466,6 @@ class CodedExecutor(ShardedExecutor):
         return out[:shape[0]]
 
     # -- protocol ----------------------------------------------------------
-    def run_pairs(self, x, plan, reducer_fn, m, *, mesh=None,
-                  use_kernel=False, interpret=False):
-        from .allpairs import assemble_pair_matrix_bucketed
-        self._count("calls")
-        metric = getattr(reducer_fn, "fused_metric", None)
-        if metric is None or plan.num_reducers == 0:
-            self._count_fallback(
-                "non_gram_reducer" if metric is None else "empty_plan")
-            self._reconcile(plan, "pairs", x,
-                            measured_slots=_bucket_valid_slots(plan))
-            per_bucket = run_reducers_bucketed(x, plan, reducer_fn,
-                                               mesh=mesh, combine="buckets")
-            return assemble_pair_matrix_bucketed(per_bucket, m)
-        x = jnp.asarray(x)
-        return self._coded_dispatch(
-            x, x, plan, metric, (m, m), True, mesh, None,
-            (True if use_kernel else None), interpret, 128, rect=False,
-            workload="pairs")
-
-    def run_x2y(self, tables, plan, reducer_fn, shape, *, mesh=None,
-                use_kernel=False, interpret=False, bl: int = 128):
-        from .allpairs import assemble_x2y_matrix_bucketed
-        self._count("calls")
-        metric = getattr(reducer_fn, "fused_metric", None)
-        if metric is None or plan.num_reducers == 0:
-            self._count_fallback(
-                "non_gram_reducer" if metric is None else "empty_plan")
-            self._reconcile(plan, "x2y", _as_tables(tables)[0],
-                            measured_slots=_bucket_valid_slots(plan))
-            per_bucket = run_reducers_x2y_bucketed(
-                tables, plan, reducer_fn, mesh=mesh, combine="buckets")
-            return assemble_x2y_matrix_bucketed(per_bucket, shape)
-        uk = True if use_kernel else None
-        xt, yt = _as_tables(tables)
-        return self._coded_dispatch(
-            xt, yt, plan, metric, tuple(shape), False, mesh, None, uk,
-            interpret, bl, rect=True, workload="x2y")
-
     def lower(self, input_shape, plan, *, reducer_fn=None, metric=None,
               mesh=None, dtype=jnp.float32, shard_axes=None,
               m: Optional[int] = None, replication: Optional[int] = None,
@@ -1699,21 +1481,14 @@ class CodedExecutor(ShardedExecutor):
         assert metric is not None, "coded lowering needs a Gram metric"
         mesh, axes, S = _shard_mesh(mesh, shard_axes)
         part = self.partition_coded(plan, S, replication)
-        groups = self._coded_groups_for(plan, part, rect=False)
+        groups = self._coded_groups_for(plan, part)
         mm = m if m is not None else input_shape[0]
         sendmap, srcmap, _ = self._coded_maps_for(
             plan, groups, part, (mm, mm), True)
         fn = _make_coded_jitted(metric, mesh, axes, use_kernel, False, bl)
         x = jax.ShapeDtypeStruct(input_shape, dtype)
-        sgroups = tuple(
-            (jax.ShapeDtypeStruct(xi.shape, jnp.int32),
-             jax.ShapeDtypeStruct(xm.shape, jnp.bool_),
-             jax.ShapeDtypeStruct(yi.shape, jnp.int32),
-             jax.ShapeDtypeStruct(ym.shape, jnp.bool_))
-            for xi, xm, yi, ym, _rows in groups)
-        return fn.lower(x, x, sgroups,
-                        jax.ShapeDtypeStruct(sendmap.shape, jnp.int32),
-                        jax.ShapeDtypeStruct(srcmap.shape, jnp.int32))
+        return fn.lower(x, x, *_specs((tuple(g[:4] for g in groups),
+                                       sendmap, srcmap)))
 
 
 def coded_assembly_model(plan, num_shards: int, replication: int, m: int,
@@ -1730,14 +1505,12 @@ def coded_assembly_model(plan, num_shards: int, replication: int, m: int,
     S = int(num_shards)
     r = min(int(replication), S)
     part = partition_plan(plan, S, replication=r)
-    sq = _stacked_groups(plan, part, rows_by_shard=part.replica_rows)
-    groups = [(i, k, i, k, rows) for i, k, rows in sq]
+    groups = _stacked_rect_groups(plan, part, rows_by_shard=part.replica_rows)
     rb = -(-int(m) // S)
     sendmap, _srcmap, st = _coded_maps(groups, (int(m), int(m)), rb, True)
     frac = (S - 1) / S if S > 1 else 0.0
-    primary = _stacked_groups(plan, part)
-    gram_entries = sum(int(np.prod(i.shape[:2])) * i.shape[2] ** 2
-                       for i, _k, _r in primary)
+    gram_entries = _block_entries(_group_stacks(
+        _stacked_rect_groups(plan, part)))
     return {
         "replication": r,
         "num_shards": S,

@@ -36,7 +36,6 @@ from repro.mapreduce import (
 from repro.mapreduce import executors
 from repro.mapreduce.executors import (
     _coded_maps,
-    _stacked_groups,
     _stacked_rect_groups,
     choose_replication,
     coded_assembly_model,
@@ -323,12 +322,7 @@ def _maps(plan, S, r, rect=False):
     """The coded maps of ``plan`` on ``S`` shards at rate ``r``, with the
     partition and the replica-stacked groups they came from."""
     part = partition_plan(plan, S, replication=r)
-    if rect:
-        groups = _stacked_rect_groups(plan, part,
-                                      rows_by_shard=part.replica_rows)
-    else:
-        groups = [(i, k, i, k, rows) for i, k, rows in _stacked_groups(
-            plan, part, rows_by_shard=part.replica_rows)]
+    groups = _stacked_rect_groups(plan, part, rows_by_shard=part.replica_rows)
     shape = _shape(plan, rect)
     rb = -(-shape[0] // S)
     return part, groups, rb, _coded_maps(groups, shape, rb, not rect)

@@ -27,8 +27,8 @@ from repro.mapreduce import (
     build_plan,
     pairwise_similarity,
     run_reducers,
+    get_executor,
     run_reducers_bucketed,
-    run_reducers_fused,
     some_pairs_similarity,
 )
 from repro.mapreduce import engine as engine_mod
@@ -134,7 +134,7 @@ class TestFusedExecutorDifferential:
         fn = _block_fn("dot", False)
         dense = run_reducers(x, plan, fn)
         buck = run_reducers_bucketed(x, plan, fn)
-        fused = run_reducers_fused(x, plan, fn, use_kernel=False)
+        fused = get_executor("fused").run(x, plan, fn, use_kernel=False)
         assert fused.shape == dense.shape
         np.testing.assert_allclose(np.asarray(fused), np.asarray(dense),
                                    rtol=1e-5, atol=1e-5)
@@ -150,8 +150,8 @@ class TestFusedExecutorDifferential:
         x = _rand(rng, (m, 8))
         fn = _block_fn("dot", False)
         dense = run_reducers(x, plan, fn)
-        fused = run_reducers_fused(x, plan, fn, use_kernel=True,
-                                   interpret=True)
+        fused = get_executor("fused").run(x, plan, fn, use_kernel=True,
+                                          interpret=True)
         np.testing.assert_allclose(np.asarray(fused), np.asarray(dense),
                                    rtol=1e-5, atol=1e-5)
 
@@ -206,7 +206,7 @@ class TestFusedExecutorDifferential:
                                    idx=idx, mask=mask),))
         x = jnp.ones((4, 5), jnp.float32)
         fn = _block_fn("dot", False)
-        fused = run_reducers_fused(x, plan, fn, use_kernel=False)
+        fused = get_executor("fused").run(x, plan, fn, use_kernel=False)
         buck = run_reducers_bucketed(x, plan, fn)
         np.testing.assert_allclose(np.asarray(fused), np.asarray(buck))
         assert float(jnp.abs(fused).max()) == 0.0
@@ -222,7 +222,7 @@ class TestFusedExecutorDifferential:
             return jnp.sum(blk * msk[:, None], axis=0)
 
         before = engine_mod.fused_stats()
-        fused = run_reducers_fused(x, plan, colsum)
+        fused = get_executor("fused").run(x, plan, colsum)
         after = engine_mod.fused_stats()
         buck = run_reducers_bucketed(x, plan, colsum)
         np.testing.assert_allclose(np.asarray(fused), np.asarray(buck),

@@ -513,9 +513,10 @@ def test_block_repeat_reads_cached():
 
 def test_upload_bytes_equal_the_arrays_put():
     """The ``upload`` spans' ``bytes`` add up to the host arrays a request
-    puts, counted here from the table and the plan: the table, each
-    bucket's slot ids, masks and scatter rows (int32, bool, int32), and
-    the (m, m) int32 source map."""
+    puts, counted here from the table and the plan: the table, then at
+    one site each bucket's slot ids and masks (int32, bool; once, though
+    the self-join reads them on both sides) and the (m, m) int32 source
+    map."""
     from repro.serve import PairwiseService
     x, w = _zipf_table()
     m, d = x.shape
@@ -524,10 +525,9 @@ def test_upload_bytes_equal_the_arrays_put():
     _sims, info = svc.similarity(x, weights=w)
     plan = mr_engine.build_plan(plan_a2a(w, 1.0))
     want = m * d * 4 + m * m * 4 + sum(
-        b.idx.shape[0] * b.idx.shape[1] * (4 + 1) + b.idx.shape[0] * 4
-        for b in plan.buckets)
+        b.idx.shape[0] * b.idx.shape[1] * (4 + 1) for b in plan.buckets)
     got = [s.attrs["bytes"] for s in TRACER.spans() if s.name == "upload"]
-    assert len(got) == 3
+    assert len(got) == 2
     assert sum(got) == want
 
     # a block: the table, each rect bucket's X and Y ids and masks, and

@@ -267,9 +267,9 @@ class TestSchemaMemo:
     def _with_maps(self, schema, pad=1):
         """Lower ``schema`` and build its source map, as the fused path
         does; return the host bytes now memoized on it."""
-        from repro.mapreduce.allpairs import _pair_source_map, _plan_for
+        from repro.mapreduce.allpairs import _pair_source_map_rect, _plan_for
         plan = _plan_for(schema, pad_reducers_to=pad, pad_slots_to=pad)
-        _pair_source_map(plan, schema.m)
+        _pair_source_map_rect(plan, schema.m, schema.m, zero_diag=True)
         return schema_host_bytes(schema)
 
     def _perms(self, n):

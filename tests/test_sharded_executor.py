@@ -28,7 +28,6 @@ from repro.mapreduce import (
     make_executor,
     pairwise_similarity,
     run_reducers,
-    run_reducers_sharded,
     some_pairs_similarity,
 )
 from repro.mapreduce.allpairs import _block_fn
@@ -239,7 +238,7 @@ class TestShardedExecutorDifferential:
         x = _rand(rng, (m, 8))
         fn = _block_fn("dot", False)
         dense = run_reducers(x, plan, fn)
-        sharded = run_reducers_sharded(x, plan, fn)
+        sharded = get_executor("sharded").run(x, plan, fn)
         assert sharded.shape == dense.shape
         np.testing.assert_allclose(np.asarray(sharded), np.asarray(dense),
                                    rtol=1e-5, atol=1e-5)
