@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import itertools
 import math
 from typing import Optional, Sequence
 
@@ -75,6 +76,7 @@ __all__ = [
     "naive_pairs",
     "compute_buckets",
     "compute_rect_buckets",
+    "rect_width_pairs",
     "bucket_summary",
     "PlanPartition",
     "partition_plan",
@@ -590,6 +592,28 @@ def compute_buckets(slot_counts: Sequence[int], *, pad_slots_to: int = 1,
     return [(int(w), np.flatnonzero(widths == w)) for w in uniq]
 
 
+def _side_widths(counts: np.ndarray, pad_slots_to: int) -> np.ndarray:
+    """Per-reducer side width: the smallest ``pad_slots_to * 2^j`` that
+    holds the slot count, clamped to the side's dense width."""
+    dense = -(-max(int(counts.max()), 1) // pad_slots_to) * pad_slots_to
+    tiles = np.maximum(-(-counts // pad_slots_to), 1)
+    w = pad_slots_to * (2 ** np.ceil(np.log2(tiles)).astype(np.int64))
+    return np.minimum(w, dense)
+
+
+def rect_width_pairs(x_counts: Sequence[int], y_counts: Sequence[int], *,
+                     pad_slots_to: int = 1) -> int:
+    """Distinct ``(wx, wy)`` width pairs of a rect plan's reducers before
+    :func:`compute_rect_buckets` merges them."""
+    xc = np.asarray(list(x_counts), dtype=np.int64)
+    yc = np.asarray(list(y_counts), dtype=np.int64)
+    if xc.size == 0:
+        return 0
+    wx = _side_widths(xc, pad_slots_to)
+    wy = _side_widths(yc, pad_slots_to)
+    return int(np.unique(wx * (int(wy.max()) + 1) + wy).size)
+
+
 def compute_rect_buckets(x_counts: Sequence[int], y_counts: Sequence[int],
                          *, pad_slots_to: int = 1,
                          max_buckets: int = 8
@@ -600,10 +624,22 @@ def compute_rect_buckets(x_counts: Sequence[int], y_counts: Sequence[int],
     reducer ``r`` holds ``x_counts[r]`` X-side and ``y_counts[r]`` Y-side
     slots; each side is padded to the smallest ``pad_slots_to * 2^j``
     (clamped to its dense width) and reducers sharing a ``(wx, wy)`` pair
-    execute as one vmapped batch.  When more than ``max_buckets`` distinct
-    pairs appear, the two smallest-area pairs are merged into their
-    component-wise max (a reducer never lands in a bucket narrower than its
-    slot counts on either side).
+    execute as one vmapped batch.
+
+    When more than ``max_buckets`` distinct pairs appear, buckets are
+    merged greedily: each step joins the two buckets ``a``, ``b`` whose
+    union ``m = (max(a.wx, b.wx), max(a.wy, b.wy))`` adds the fewest padded
+    block entries, ``Ra·(|m| - |a|) + Rb·(|m| - |b|)`` with ``R`` a
+    bucket's reducer count and ``|.|`` its area (an existing bucket ``m``
+    absorbs both at no further cost).  Merging by area alone cascades on
+    two-sided widths: the component-wise max of two small pairs of
+    opposite shape (4 x 128 and 128 x 4) is a wide square that the next
+    merges keep growing, until thousands of 3 x 5 reducers sit in a
+    32 x 128 bucket.  Weighting by reducer count leaves the crowded small
+    pairs alone and merges the sparse wide ones.  O(p^3) over the ``p``
+    distinct pairs (a few dozen); ties break on ``(added, m, a, b)``, so
+    equal inputs give equal buckets.  A reducer never lands in a bucket
+    narrower than its slot counts on either side.
 
     Returns ``[(wx, wy, reducer_ids), ...]`` ordered by ascending area with
     ``reducer_ids`` sorted original indices.  Empty input -> empty list.
@@ -615,25 +651,29 @@ def compute_rect_buckets(x_counts: Sequence[int], y_counts: Sequence[int],
         return []
     assert pad_slots_to >= 1 and max_buckets >= 1
 
-    def _side_widths(counts: np.ndarray) -> np.ndarray:
-        dense = -(-max(int(counts.max()), 1) // pad_slots_to) * pad_slots_to
-        tiles = np.maximum(-(-counts // pad_slots_to), 1)
-        w = pad_slots_to * (2 ** np.ceil(np.log2(tiles)).astype(np.int64))
-        return np.minimum(w, dense)
-
-    wx = _side_widths(xc)
-    wy = _side_widths(yc)
-    pairs = {(int(a), int(b)) for a, b in zip(wx, wy)}
-    while len(pairs) > max_buckets:
-        by_area = sorted(pairs, key=lambda p: (p[0] * p[1], p))
-        a, b = by_area[0], by_area[1]
-        merged = (max(a[0], b[0]), max(a[1], b[1]))
-        sel = ((wx == a[0]) & (wy == a[1])) | ((wx == b[0]) & (wy == b[1]))
-        wx[sel], wy[sel] = merged
-        pairs = (pairs - {a, b}) | {merged}
-    out = [(px, py, np.flatnonzero((wx == px) & (wy == py)))
-           for px, py in sorted(pairs, key=lambda p: (p[0] * p[1], p))]
-    return out
+    wx = _side_widths(xc, pad_slots_to)
+    wy = _side_widths(yc, pad_slots_to)
+    keys, inv, counts = np.unique(np.stack([wx, wy], axis=1), axis=0,
+                                  return_inverse=True, return_counts=True)
+    pairs = [(int(a), int(b)) for a, b in keys]
+    size = dict(zip(pairs, counts.tolist()))     # bucket -> reducers
+    home = dict(zip(pairs, pairs))               # width pair -> bucket
+    while len(size) > max_buckets:
+        best = None
+        for a, b in itertools.combinations(sorted(size), 2):
+            m = (max(a[0], b[0]), max(a[1], b[1]))
+            added = (size[a] * (m[0] * m[1] - a[0] * a[1])
+                     + size[b] * (m[0] * m[1] - b[0] * b[1]))
+            if best is None or (added, m, a, b) < best:
+                best = (added, m, a, b)
+        _, m, a, b = best
+        size[m] = size.pop(a) + size.pop(b) + size.get(m, 0)
+        home = {p: (m if h in (a, b) else h) for p, h in home.items()}
+    final = np.array([home[p] for p in pairs], dtype=np.int64)[
+        np.asarray(inv).reshape(-1)]
+    return [(px, py, np.flatnonzero((final[:, 0] == px)
+                                    & (final[:, 1] == py)))
+            for px, py in sorted(size, key=lambda p: (p[0] * p[1], p))]
 
 
 def bucket_summary(schema: MappingSchema, *, pad_slots_to: int = 1,

@@ -27,8 +27,8 @@ from repro.core import (plan_a2a, plan_a2a_hierarchical, plan_some_pairs,
                         plan_x2y)
 from repro.core.schema import MappingSchema
 
-from .engine import (ReducerPlan, SparsePlan, build_plan,
-                     build_sparse_plan, build_x2y_plan, y_side)
+from .engine import (ReducerPlan, SparsePlan, _rect_bucket_attrs,
+                     build_plan, build_sparse_plan, build_x2y_plan, y_side)
 from .executors import _derived, _fill_source_map, get_executor
 
 __all__ = [
@@ -144,15 +144,18 @@ def _plan_for(schema, *, pad_reducers_to: int, pad_slots_to: int):
 def _x2y_plan_for(schema, num_x: int, *, pad_reducers_to: int,
                   pad_slots_to: int):
     """``build_x2y_plan`` memoized on the schema object (same contract as
-    ``_plan_for``)."""
+    ``_plan_for``); a build records its bucket merge on the span
+    (``width_pairs``, ``padded_entries``: ``engine._rect_bucket_attrs``)."""
     key = ("x2y", num_x, pad_reducers_to, pad_slots_to)
     cache = schema.__dict__.setdefault("_reducer_plan_cache", {})
     plan = cache.get(key)
-    with _obs_span("lower", cached=plan is not None):
+    with _obs_span("lower", cached=plan is not None) as s:
         if plan is None:
             plan = cache[key] = build_x2y_plan(
                 schema, num_x, pad_reducers_to=pad_reducers_to,
                 pad_slots_to=pad_slots_to)
+            if s is not None:
+                s.attrs.update(_rect_bucket_attrs(plan, pad_slots_to))
     return plan
 
 

@@ -46,7 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.planner import compute_buckets, compute_rect_buckets
+from repro.core.planner import (compute_buckets, compute_rect_buckets,
+                                rect_width_pairs)
 from repro.core.schema import MappingSchema
 from repro.obs import EVENTS as _OBS_EVENTS
 from repro.obs import REGISTRY as _OBS_REGISTRY
@@ -299,6 +300,19 @@ def _build_rect_buckets(xs: list[list[int]], ys: list[list[int]], *,
     return tuple(out)
 
 
+def _rect_bucket_attrs(plan: ReducerPlan, pad_slots_to: int) -> dict:
+    """What a ``lower`` span records of a rect plan it built:
+    ``width_pairs``, the distinct (wx, wy) pairs before the bucket merge,
+    and ``padded_entries``, the block entries its buckets write
+    (Σ R·wx·wy, padding rows included)."""
+    n = plan.num_reducers
+    return {"width_pairs": rect_width_pairs(
+                plan.mask[:n].sum(axis=1), plan.ymask[:n].sum(axis=1),
+                pad_slots_to=pad_slots_to),
+            "padded_entries": sum(b.R * b.width * b.ywidth
+                                  for b in plan.buckets)}
+
+
 def build_x2y_plan_arrays(
     xs: list[list[int]],               # per-reducer X-table row ids
     ys: list[list[int]],               # per-reducer Y-table row ids
@@ -489,7 +503,7 @@ def block_subplan(sparse: SparsePlan, i0: int, i1: int, j0: int, j1: int,
         cache = OrderedDict()
         object.__setattr__(sparse, "_block_cache", cache)
     hit = key in cache
-    with _obs_span("lower", cached=hit):
+    with _obs_span("lower", cached=hit) as s:
         if hit:
             cache.move_to_end(key)
             _BLOCK_CACHE_STATS["hits"] += 1
@@ -499,6 +513,8 @@ def block_subplan(sparse: SparsePlan, i0: int, i1: int, j0: int, j1: int,
         _OBS_REGISTRY.counter("cache.misses", cache="block").inc()
         plan = _build_block_subplan(sparse, i0, i1, j0, j1, pad_reducers_to,
                                     pad_slots_to, max_buckets)
+        if s is not None and plan is not None:
+            s.attrs.update(_rect_bucket_attrs(plan, pad_slots_to))
         cache[key] = plan
         while len(cache) > cache_size:
             evicted, _ = cache.popitem(last=False)

@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core import bucket_summary, compute_buckets, plan_a2a
+from repro.core import (bucket_summary, compute_buckets,
+                        compute_rect_buckets, plan_a2a)
 from repro.core.planner import naive_pairs
 from repro.mapreduce import (
     build_plan,
@@ -81,6 +82,92 @@ class TestComputeBuckets:
         assert len(buckets) <= max_buckets
         for width, ids in buckets:
             assert all(counts[r] <= width for r in ids)
+
+
+# ------------------------------------------------------- compute_rect_buckets
+def _rect_counts(seed: int, n: int):
+    """Per-reducer X and Y slot counts: mostly small, a skewed tail up to
+    300 on either side."""
+    rng = np.random.default_rng(seed)
+    xc = np.minimum(rng.zipf(1.5, n), 300)
+    yc = np.minimum(rng.zipf(1.5, n), 300)
+    return xc.tolist(), yc.tolist()
+
+
+class TestComputeRectBuckets:
+    @pytest.mark.parametrize("pad_slots_to", [1, 8])
+    @pytest.mark.parametrize("max_buckets", [1, 3, 8])
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    def test_contract(self, seed, max_buckets, pad_slots_to):
+        """Every reducer in exactly one bucket, never narrower than its
+        counts on either side; widths ``pad_slots_to * 2^j`` clamped to the
+        dense width; at most ``max_buckets``; ascending area; sorted ids;
+        the same buckets on a second call."""
+        xc, yc = _rect_counts(seed, 400 + 100 * seed)
+        buckets = compute_rect_buckets(xc, yc, pad_slots_to=pad_slots_to,
+                                       max_buckets=max_buckets)
+        ids = np.concatenate([r for _, _, r in buckets])
+        assert sorted(ids.tolist()) == list(range(len(xc)))
+        assert len(buckets) <= max_buckets
+        dense = [-(-max(c) // pad_slots_to) * pad_slots_to for c in (xc, yc)]
+        for wx, wy, rows in buckets:
+            assert list(rows) == sorted(rows)
+            assert all(xc[r] <= wx and yc[r] <= wy for r in rows)
+            for w, dw in ((wx, dense[0]), (wy, dense[1])):
+                t = w // pad_slots_to
+                assert w == dw or (w % pad_slots_to == 0 and t & (t - 1) == 0)
+                assert w <= dw
+        areas = [wx * wy for wx, wy, _ in buckets]
+        assert areas == sorted(areas)
+        again = compute_rect_buckets(xc, yc, pad_slots_to=pad_slots_to,
+                                     max_buckets=max_buckets)
+        assert [(a, b, r.tolist()) for a, b, r in again] == \
+            [(a, b, r.tolist()) for a, b, r in buckets]
+
+    @pytest.mark.parametrize("seed", [0, 1])
+    def test_shapes_do_not_depend_on_reducer_order(self, seed):
+        xc, yc = _rect_counts(seed, 500)
+        perm = np.random.default_rng(seed).permutation(len(xc))
+        a = compute_rect_buckets(xc, yc)
+        b = compute_rect_buckets([xc[i] for i in perm], [yc[i] for i in perm])
+        assert [(wx, wy, len(r)) for wx, wy, r in a] == \
+            [(wx, wy, len(r)) for wx, wy, r in b]
+        for (_, _, ra), (_, _, rb) in zip(a, b):
+            assert sorted(perm[rb].tolist()) == ra.tolist()
+
+    def test_merges_the_sparse_wide_pairs_not_the_crowded_small_one(self):
+        """1000 reducers of 4 x 4 and one each of 4 x 128 and 128 x 4, cut
+        to two buckets.  Joining the two wide ones into 128 x 128 adds
+        2·(16384 - 512) = 31,744 entries; folding the 4 x 4 crowd into
+        4 x 128 would add 1000·(512 - 16) = 496,000."""
+        xc = [4] * 1000 + [4, 128]
+        yc = [4] * 1000 + [128, 4]
+        got = compute_rect_buckets(xc, yc, max_buckets=2)
+        assert [(wx, wy, len(r)) for wx, wy, r in got] == \
+            [(4, 4, 1000), (128, 128, 2)]
+
+    def test_small_reducers_stay_out_of_wide_buckets(self):
+        """4000 reducers of 2-8 slots a side, beside 10 of 128 x w and 10 of
+        w x 128 with w in 2-128: over 8 width pairs.  Each small reducer
+        needs at most 8 x 8 entries and each wide one at most 128 x 128, so
+        a merge that keeps them apart writes at most 4000·64 + 20·16384 =
+        583,680 entries.  Merging by area alone cascades the small ones
+        into 128 x 128 (over 65 M entries)."""
+        rng = np.random.default_rng(7)
+        small = rng.integers(2, 9, size=(4000, 2))
+        other = 2 ** rng.integers(1, 8, size=20)
+        xc = small[:, 0].tolist() + [128] * 10 + other[10:].tolist()
+        yc = small[:, 1].tolist() + other[:10].tolist() + [128] * 10
+        buckets = compute_rect_buckets(xc, yc)
+        assert len(buckets) <= 8
+        for wx, wy, rows in buckets:
+            if (rows < 4000).any():
+                assert wx * wy < 32 * 128, (wx, wy)
+        padded = sum(wx * wy * len(r) for wx, wy, r in buckets)
+        assert padded <= 4000 * 64 + 20 * 128 * 128
+
+    def test_empty(self):
+        assert compute_rect_buckets([], []) == []
 
 
 # ----------------------------------------------------------- executor parity

@@ -5,7 +5,8 @@ for a topology that is only described, and refuses what the chip would
 refuse (SMEM overflow, unaligned DMA slices, packed-dtype stores) — what
 interpret mode cannot show.  The shapes are the capacity buckets of the
 served similarity plan (m=8192 Zipf profile at q=8: widths 4-256 at their
-reducer counts) and the largest bucket of its X2Y counterpart.
+reducer counts), the largest bucket of its X2Y counterpart, and the
+narrow buckets of the x2y cell's plan (4 x 4, 128 x 4, 4 x 128).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and a decision taken
@@ -72,7 +73,10 @@ def test_square_kernel_compiles(one_chip, R, L, d, dtype):
 @pytest.mark.parametrize("R,Lx,Ly,d", [
     (98428, 32, 128, 128),
     (300, 64, 32, 64),
-], ids=["P2-widest", "d64"])
+    (73948, 4, 4, 128),
+    (16226, 128, 4, 128),
+    (8062, 4, 128, 128),
+], ids=["P2-widest", "d64", "x2y-4x4", "x2y-128x4", "x2y-4x128"])
 def test_rect_kernel_compiles(one_chip, R, Lx, Ly, d):
     compiled = jax.jit(fused_gather_gram_rect).lower(
         _spec((M // 2, d), jnp.float32, one_chip),

@@ -397,3 +397,120 @@ class TestX2YService:
         assert plans[1] is plans[0]            # the repeat: one schema
         assert plans[2] is not plans[0]        # the permutation: its own
         assert plans[0].num_reducers > 1
+
+
+class TestMergedRectPlan:
+    """Rect plans whose width pairs outnumber ``max_buckets``, so the
+    bucket merge runs before any executor sees them."""
+
+    @staticmethod
+    def _cross_plan(seed):
+        """Every X bin meets every Y bin (the Section 10 layout) over bins
+        of 1-33 X rows and 1-20 Y rows, shuffled ids: 35 reducers in 35
+        distinct width pairs, cut to 8 buckets."""
+        from repro.mapreduce.engine import build_x2y_plan_arrays
+        rng = np.random.default_rng(seed)
+        xsz, ysz = [1, 2, 3, 5, 9, 17, 33], [1, 3, 6, 12, 20]
+        xb = np.split(rng.permutation(sum(xsz)), np.cumsum(xsz)[:-1])
+        yb = np.split(rng.permutation(sum(ysz)), np.cumsum(ysz)[:-1])
+        xs = [a.tolist() for a in xb for _ in yb]
+        ys = [b.tolist() for _ in xb for b in yb]
+        return build_x2y_plan_arrays(xs, ys, num_x=sum(xsz), num_y=sum(ysz))
+
+    @pytest.mark.parametrize("seed", [0, 1])
+    def test_fused_matches_bucketed_and_float64(self, seed):
+        from repro.core.planner import rect_width_pairs
+        from repro.mapreduce import make_executor
+        from repro.mapreduce.allpairs import assemble_x2y_matrix_bucketed
+        from repro.mapreduce.engine import run_reducers_x2y_bucketed
+        plan = self._cross_plan(seed)
+        n = plan.num_reducers
+        assert rect_width_pairs(plan.mask[:n].sum(1),
+                                plan.ymask[:n].sum(1)) == 35
+        assert len(plan.buckets) == 8
+        mx, my = plan.num_x, plan.num_y
+        rng = np.random.default_rng(seed + 10)
+        xv = rng.standard_normal((mx, 16), dtype=np.float32)
+        yv = rng.standard_normal((my, 16), dtype=np.float32)
+        x, y = jnp.asarray(xv), jnp.asarray(yv)
+        fn = _block_fn_x2y("dot")
+        ref = xv.astype(np.float64) @ yv.astype(np.float64).T
+        bucketed = np.asarray(assemble_x2y_matrix_bucketed(
+            run_reducers_x2y_bucketed((x, y), plan, fn, combine="buckets"),
+            (mx, my)))
+        np.testing.assert_allclose(bucketed, ref, **TOL)
+        for use_kernel in (False, True):
+            got = make_executor("fused").run_x2y(
+                (x, y), plan, fn, (mx, my), use_kernel=use_kernel,
+                interpret=use_kernel)
+            np.testing.assert_allclose(np.asarray(got), bucketed, **TOL)
+            np.testing.assert_allclose(np.asarray(got), ref, **TOL)
+
+
+    def test_block_subplan_span_records_the_merge(self):
+        """A tile's sub-plan build records its bucket merge on the ``lower``
+        span; a cache hit builds nothing and records nothing."""
+        from repro import obs
+        from repro.core import plan_a2a
+        from repro.core.planner import rect_width_pairs
+        from repro.mapreduce import block_subplan, build_sparse_plan
+        w = np.random.default_rng(16).uniform(0.02, 0.3, 80)
+        sparse = build_sparse_plan(plan_a2a(w, 1.0))
+        prior = obs.configure()
+        obs.configure(enabled=True)
+        try:
+            before = {s.span_id for s in obs.TRACER.spans()}
+            plans = [block_subplan(sparse, 0, 40, 40, 80) for _ in range(2)]
+            lower = [s for s in obs.TRACER.spans()
+                     if s.name == "lower" and s.span_id not in before]
+        finally:
+            obs.configure(enabled=prior)
+        plan = plans[0]
+        assert plans[1] is plan
+        n = plan.num_reducers
+        assert [s.attrs["cached"] for s in lower] == [False, True]
+        assert lower[0].attrs["width_pairs"] == rect_width_pairs(
+            plan.mask[:n].sum(1), plan.ymask[:n].sum(1))
+        assert lower[0].attrs["padded_entries"] == sum(
+            b.R * b.width * b.ywidth for b in plan.buckets)
+        assert "width_pairs" not in lower[1].attrs
+
+
+class TestCellPlan:
+    """The ``x2y.sift128-4k`` cell's own plan, from its configuration's
+    size profiles (``bench/configs/sift128-x2y-4k.json``)."""
+
+    def test_sift128_x2y_4k_buckets_pad_little(self):
+        """133,125 reducers (375 X bins x 355 Y bins) in 49 width pairs, cut
+        to 8 buckets.  Merging the two smallest-area pairs put 102,738 of
+        them, 3.5 x 5.1 slots on average, in one 32 x 128 bucket: 717,037,568
+        block entries.  The least-added-padding merge writes ~50.8 M."""
+        import json
+        import os
+        import runpy
+
+        from repro import obs
+        from repro.mapreduce.allpairs import _x2y_plan_for
+        bench = os.path.join(os.path.dirname(__file__), "..", "bench")
+        sizes = runpy.run_path(os.path.join(bench, "traffic.py"))["sizes"]
+        with open(os.path.join(bench, "configs", "sift128-x2y-4k.json")) as f:
+            c = json.load(f)
+        wx = sizes(c["sizes_x"], c["mx"], c["q"])
+        wy = sizes(c["sizes_y"], c["my"], c["q"])
+        schema = plan_x2y(wx, wy, c["q"])
+        prior = obs.configure()
+        obs.configure(enabled=True)
+        try:
+            before = {s.span_id for s in obs.TRACER.spans()}
+            plan = _x2y_plan_for(schema, c["mx"], pad_reducers_to=1,
+                                 pad_slots_to=1)
+            lower = [s for s in obs.TRACER.spans()
+                     if s.name == "lower" and s.span_id not in before]
+        finally:
+            obs.configure(enabled=prior)
+        assert plan.num_reducers == 133_125 and len(plan.buckets) == 8
+        padded = sum(b.R * b.width * b.ywidth for b in plan.buckets)
+        assert padded <= 60_000_000
+        assert len(lower) == 1 and lower[0].attrs["cached"] is False
+        assert lower[0].attrs["width_pairs"] == 49
+        assert lower[0].attrs["padded_entries"] == padded
